@@ -251,6 +251,8 @@ def run(*, smoke: bool = False, seed: int = 7, verbose: bool = True) -> dict:
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="1 scenario, 1 overload point, 1 fault family "

@@ -166,6 +166,8 @@ def bench_greedy_reuse(*, repeat: int, verbose: bool) -> dict:
 
 
 def main(argv=None) -> int:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes, kernels forced on (CI gate)")

@@ -303,6 +303,8 @@ def run(*, smoke: bool = False, seed: int = 5, verbose: bool = True) -> dict:
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny streams, 2 scenarios (the CI parity gate)")

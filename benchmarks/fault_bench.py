@@ -212,6 +212,8 @@ def run(*, smoke: bool = False, seed: int = 7,
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="1 small scenario (the CI gate: replay parity + "

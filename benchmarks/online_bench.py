@@ -229,6 +229,8 @@ def run_fidelity(*, smoke: bool = False, arrivals: int = 40, seed: int = 7,
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny streams, 2 scenarios (the CI gate)")

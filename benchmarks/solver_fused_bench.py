@@ -312,6 +312,8 @@ def run(*, smoke: bool = False, seed: int = 5, repeat: int = 3,
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small catalog + short end-to-end pair (the CI "

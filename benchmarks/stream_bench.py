@@ -280,6 +280,8 @@ def run(*, smoke: bool = False, seed: int = 9, repeat: int = 2,
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="2 small scenarios, 1 grid point (the CI gate: "

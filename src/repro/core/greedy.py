@@ -105,8 +105,8 @@ def reset_fused_dispatch_count() -> None:
     _n_fused_dispatches = 0
 
 
-def _bump_dispatch(fn) -> bool:
-    """Count one dispatch; report whether it will trigger a compile.
+def _bump_dispatch(fn) -> int:
+    """Count one dispatch; return ``fn``'s jit cache size before the call.
 
     jax caches compiled executables per abstract signature; a growing
     cache size after the call means this signature was new.  The *pre*
@@ -114,19 +114,11 @@ def _bump_dispatch(fn) -> bool:
     """
     global _n_fused_dispatches
     _n_fused_dispatches += 1
-    try:
-        return fn._cache_size()
-    except AttributeError:      # jit cache introspection unavailable
-        return -1
+    return fn._cache_size()
 
 
 def _took_compile(fn, size_before: int) -> bool:
-    if size_before < 0:
-        return False
-    try:
-        return fn._cache_size() > size_before
-    except AttributeError:
-        return False
+    return fn._cache_size() > size_before
 
 
 def _fused_rounds(net0: ComputeNetwork, batch: JobBatch,
@@ -248,15 +240,25 @@ def _paths_post(net0: ComputeNetwork, batch: JobBatch, order, assigns,
     contractible pattern) is taken from the scan and matches the
     reference's jit-built closures bit-for-bit.
     """
-    v = net0.num_nodes
     order = np.asarray(order)
     if order.size == 0:
         return {}
+    L_sel = np.asarray(num_layers_h)[order]
+    hops = jax.device_get(_walk_paths(
+        *_walk_operands(net0, batch, order, assigns, ql_pre, t_sel, L_sel),
+        max_hops=net0.num_nodes))
+    return {int(j): routing.hops_to_paths(hops[p], int(L_sel[p]))
+            for p, j in enumerate(order)}
+
+
+def _walk_operands(net0: ComputeNetwork, batch: JobBatch, order, assigns,
+                   ql_pre, t_sel, L_sel) -> tuple:
+    """:func:`_walk_paths`'s operands for the rounds in ``order`` (whose
+    jobs have ``L_sel`` layers), staged on the device."""
     assigns = np.asarray(assigns)
     lmax = batch.max_layers
     src_h, dst_h, data_h = (np.asarray(jax.device_get(x))
                             for x in (batch.src, batch.dst, batch.data))
-    L_sel = np.asarray(num_layers_h)[order]
     src_sel, dst_sel = src_h[order], dst_h[order]
     # Per-layer walk endpoints: node_l -> node_{l+1} with node_0 = src and
     # dst from layer num_layers on (layers past num_layers are dropped by
@@ -269,12 +271,9 @@ def _paths_post(net0: ComputeNetwork, batch: JobBatch, order, assigns,
     # device_put (not jnp.asarray): staging is an *explicit* transfer so
     # the solver path stays clean under jax.transfer_guard("disallow")
     # (the runtime complement of lint rule RL003; see tests/test_fused.py).
-    hops = jax.device_get(_walk_paths(
-        jax.device_put(data_h[order]), jax.device_put(ql_pre),
-        link_invrate(net0), jax.device_put(t_sel), jax.device_put(starts),
-        jax.device_put(ends), max_hops=v))
-    return {int(j): routing.hops_to_paths(hops[p], int(L_sel[p]))
-            for p, j in enumerate(order)}
+    return (jax.device_put(data_h[order]), jax.device_put(ql_pre),
+            link_invrate(net0), jax.device_put(t_sel), jax.device_put(starts),
+            jax.device_put(ends))
 
 
 def _assemble_plan(batch: JobBatch, net: ComputeNetwork, order, costs,
@@ -312,10 +311,7 @@ def greedy_route(net: ComputeNetwork, batch: JobBatch,
                                 lazy=lazy, share_closures=share_closures,
                                 extract_paths=extract_paths)
     J = batch.num_jobs
-    j_pad = _next_pow2(J)
-    padded = _pad_batch(batch, j_pad)
-    dplan = _bucket_dplan(SP.dedupe_plan(padded))
-    routed0 = jax.device_put(np.arange(j_pad) >= J)  # dummies pre-routed
+    padded, dplan, routed0 = _stage_window(batch)
     size0 = _bump_dispatch(_fused_solve)
     out = _fused_solve(net, padded, dplan, routed0, use_pallas=use_pallas)
     compiled = _took_compile(_fused_solve, size0)
@@ -336,8 +332,37 @@ def greedy_route(net: ComputeNetwork, batch: JobBatch,
     return _assemble_plan(
         batch, net.with_queues(q_node, q_link), order[keep], costs[keep],
         assigns[keep], paths,
-        meta=_fused_meta(J, rounds=j_pad, compiled=compiled,
+        meta=_fused_meta(J, rounds=padded.num_jobs, compiled=compiled,
                          paths=extract_paths))
+
+
+def _stage_window(batch: JobBatch) -> tuple:
+    """:func:`_fused_solve`'s operands for one window: the batch padded to
+    a power of two, its bucketed dedupe plan, and the pre-routed mask that
+    keeps the dummy jobs out of every round."""
+    J = batch.num_jobs
+    j_pad = _next_pow2(J)
+    padded = _pad_batch(batch, j_pad)
+    return (padded, _bucket_dplan(SP.dedupe_plan(padded)),
+            jax.device_put(np.arange(j_pad) >= J))
+
+
+def _stage_windows(batches: list[JobBatch]) -> tuple:
+    """:func:`_fused_solve_many`'s operands for W windows: the per-window
+    padded batches (unstacked), then the stacked batches, stacked dedupe
+    plans and the [W, J] mask of real jobs."""
+    j_max = _next_pow2(max(b.num_jobs for b in batches))
+    padded = [_pad_batch(b, j_max) for b in batches]
+    dplans = [SP.dedupe_plan(b) for b in padded]
+    u_max = _next_pow2(max(np.asarray(d.uniq).shape[0] for d in dplans))
+    d_max = _next_pow2(max(np.asarray(d.d_vals).shape[0] for d in dplans))
+    dplans = [_pad_dplan(d, u_max, d_max) for d in dplans]
+    stack = lambda xs: jax.tree_util.tree_map(
+        lambda *leaves: jnp.stack(leaves), *xs)
+    valid = jax.device_put(np.array(
+        [[1] * b.num_jobs + [0] * (j_max - b.num_jobs) for b in batches],
+        bool))
+    return padded, stack(padded), stack(dplans), valid
 
 
 def _next_pow2(n: int) -> int:
@@ -420,19 +445,10 @@ def greedy_route_windows(net: ComputeNetwork, batches: list[JobBatch],
         raise ValueError(
             f"windows must share a padded layer width (batch_jobs(pad_to=)); "
             f"got {sorted(lmax)}")
-    j_max = _next_pow2(max(b.num_jobs for b in batches))
-    padded = [_pad_batch(b, j_max) for b in batches]
-    dplans = [SP.dedupe_plan(b) for b in padded]
-    u_max = _next_pow2(max(np.asarray(d.uniq).shape[0] for d in dplans))
-    d_max = _next_pow2(max(np.asarray(d.d_vals).shape[0] for d in dplans))
-    dplans = [_pad_dplan(d, u_max, d_max) for d in dplans]
-    stack = lambda xs: jax.tree_util.tree_map(
-        lambda *leaves: jnp.stack(leaves), *xs)
-    valid = jax.device_put(np.array(
-        [[1] * b.num_jobs + [0] * (j_max - b.num_jobs) for b in batches],
-        bool))
+    padded, stacked, dplans, valid = _stage_windows(batches)
+    j_max = padded[0].num_jobs
     size0 = _bump_dispatch(_fused_solve_many)
-    outs = _fused_solve_many(net, stack(padded), stack(dplans), valid,
+    outs = _fused_solve_many(net, stacked, dplans, valid,
                              use_pallas=use_pallas)
     compiled = _took_compile(_fused_solve_many, size0)
     (orders, costs, assigns, ql_pre, t_sel), q_nodes, q_links = outs

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30  # python float: jnp constants must not be closure-captured
 
 
@@ -108,7 +106,7 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -281,7 +279,7 @@ def flash_fwd_lse(q, k, v, *, scale, causal=True, bq=512, bk=512,
         scratch_shapes=[pltpu.VMEM((bq, dv), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -312,7 +310,7 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal=True, bq=512, bk=512,
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -337,7 +335,7 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal=True, bq=512, bk=512,
                    jax.ShapeDtypeStruct((bh, s, dv), q.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, dv), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)

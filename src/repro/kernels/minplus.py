@@ -37,7 +37,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
+
+def _contract_tile(acc, a_cols, b_rows, *, bk: int, inner_chunk: int):
+    """Fold one (bm, bk) x (bk, bn) tile pair into the running-min ``acc``.
+
+    bk is contracted in chunks: each chunk materializes a [bm, chunk, bn]
+    broadcast-sum in VREGs/VMEM and folds it into the accumulator with a
+    running min (chunk=8 keeps the intermediate at 512 KiB for 128-blocks).
+    The chunk loop is unrolled at trace time so every K-chunk is read from
+    the refs at a static offset: Mosaic lowers static ref slices, but not a
+    ``dynamic_slice`` of a loaded value.  ``a_cols(s)`` / ``b_rows(s)``
+    read the [bm, chunk] columns / [chunk, bn] rows at slice ``s``.
+    """
+    for c in range(bk // inner_chunk):
+        s = slice(c * inner_chunk, (c + 1) * inner_chunk)
+        a_c = a_cols(s).astype(jnp.float32)
+        b_c = b_rows(s).astype(jnp.float32)
+        part = jnp.min(a_c[:, :, None] + b_c[None, :, :], axis=1)  # [bm, bn]
+        acc = jnp.minimum(acc, part)
+    return acc
 
 
 def _minplus_kernel(a_ref, b_ref, o_ref, acc_ref, *, bk: int, k_steps: int,
@@ -49,21 +67,9 @@ def _minplus_kernel(a_ref, b_ref, o_ref, acc_ref, *, bk: int, k_steps: int,
     def _init():
         acc_ref[...] = jnp.full_like(acc_ref, jnp.float32(3.0e38) / 2)
 
-    a = a_ref[...].astype(jnp.float32)  # [bm, bk]
-    b = b_ref[...].astype(jnp.float32)  # [bk, bn]
-
-    # Contract bk in chunks: each chunk materializes a [bm, chunk, bn]
-    # broadcast-sum in VREGs/VMEM and folds it into the accumulator with a
-    # running min.  chunk is chosen so the intermediate stays ~1 MiB.
-    def body(c, acc):
-        a_c = jax.lax.dynamic_slice_in_dim(a, c * inner_chunk, inner_chunk, 1)
-        b_c = jax.lax.dynamic_slice_in_dim(b, c * inner_chunk, inner_chunk, 0)
-        part = jnp.min(a_c[:, :, None] + b_c[None, :, :], axis=1)  # [bm, bn]
-        return jnp.minimum(acc, part)
-
-    acc = acc_ref[...]
-    acc = jax.lax.fori_loop(0, bk // inner_chunk, body, acc)
-    acc_ref[...] = acc
+    acc_ref[...] = _contract_tile(
+        acc_ref[...], lambda s: a_ref[:, s], lambda s: b_ref[s, :],
+        bk=bk, inner_chunk=inner_chunk)
 
     @pl.when(k_idx == k_steps - 1)
     def _done():
@@ -106,7 +112,7 @@ def minplus_matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -122,18 +128,9 @@ def _minplus_kernel_batched(a_ref, b_ref, o_ref, acc_ref, *, bk: int,
     def _init():
         acc_ref[...] = jnp.full_like(acc_ref, jnp.float32(3.0e38) / 2)
 
-    a = a_ref[0].astype(jnp.float32)  # [bm, bk]
-    b = b_ref[0].astype(jnp.float32)  # [bk, bn]
-
-    def body(c, acc):
-        a_c = jax.lax.dynamic_slice_in_dim(a, c * inner_chunk, inner_chunk, 1)
-        b_c = jax.lax.dynamic_slice_in_dim(b, c * inner_chunk, inner_chunk, 0)
-        part = jnp.min(a_c[:, :, None] + b_c[None, :, :], axis=1)  # [bm, bn]
-        return jnp.minimum(acc, part)
-
-    acc = acc_ref[...]
-    acc = jax.lax.fori_loop(0, bk // inner_chunk, body, acc)
-    acc_ref[...] = acc
+    acc_ref[...] = _contract_tile(
+        acc_ref[...], lambda s: a_ref[0, :, s], lambda s: b_ref[0, s, :],
+        bk=bk, inner_chunk=inner_chunk)
 
     @pl.when(k_idx == k_steps - 1)
     def _done():
@@ -181,7 +178,7 @@ def minplus_matmul_pallas_batched(
         out_specs=pl.BlockSpec((1, bm, bn), lambda bb, i, j, kk: (bb, i, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

@@ -53,7 +53,7 @@ def dispatch_counts() -> dict[str, int]:
     would bake the stale snapshot into the compiled program as a
     constant).
     """
-    if not jax.core.trace_state_clean():
+    if not jax.core.trace_ctx.is_top_level():
         raise RuntimeError(
             "dispatch_counts() called under an active jax trace: the "
             "trace-time tally is mid-update, and a traced reader would "

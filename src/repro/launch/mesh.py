@@ -10,6 +10,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -22,11 +23,13 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             f"mesh {shape} needs {need} devices, found {len(devices)} — the "
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count"
             "=512 before importing jax (see launch/dryrun.py)")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return jax.make_mesh(shape, axes, devices=devices[:need],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_debug_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over however many (host) devices exist — tests only."""
     need = data * model
     return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[:need])
+                         devices=jax.devices()[:need],
+                         axis_types=(AxisType.Auto,) * 2)

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.core import jobs as J, network as N, solvers
 from repro.configs import registry
+from repro.launch import compile_cache
 
 _SA_DEFAULTS = dict(num_chains=4)
 
@@ -66,6 +67,7 @@ def run(topology: str, jobs_spec: str, scale: float, methods: str, seed: int,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--topology", default="small", choices=["small", "us"])
     ap.add_argument("--jobs", default="vgg19:2,resnet34:6")

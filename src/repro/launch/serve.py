@@ -15,6 +15,7 @@ from repro.core import network as N
 from repro.models import model as M
 from repro.serving.engine import DecodeEngine
 from repro.serving.scheduler import Request, RoutedScheduler
+from repro.launch import compile_cache
 
 
 def default_cluster() -> N.ComputeNetwork:
@@ -27,6 +28,7 @@ def default_cluster() -> N.ComputeNetwork:
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm_135m")
     ap.add_argument("--requests", type=int, default=4)

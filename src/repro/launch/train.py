@@ -24,6 +24,7 @@ from repro.data.pipeline import DataConfig, SyntheticStream
 from repro.distributed.fault import FailureInjector, StragglerMonitor
 from repro.launch.steps import make_train_step, default_optimizer
 from repro.models import model as M
+from repro.launch import compile_cache
 
 
 @dataclasses.dataclass
@@ -103,6 +104,7 @@ def train(arch: str, *, preset: str = "smoke", steps: int = 100,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm_135m")
     ap.add_argument("--preset", default="smoke", choices=["smoke", "full"])

@@ -16,12 +16,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
-def get_abstract_mesh():
-    """Ambient abstract mesh, or None on jax versions without the API."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    return get() if get is not None else None
-
-
 def maybe_shard(x, *axes):
     """Activation-sharding anchor: constrain ``x`` to PartitionSpec(*axes).
 
@@ -30,8 +24,8 @@ def maybe_shard(x, *axes):
     single CPU device while the production-mesh dry-run gets explicit
     batch/tensor sharding anchors (GSPMD propagates the rest).
     """
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     names = set(mesh.axis_names)
 
@@ -239,8 +233,8 @@ def _flash_bshd(q, k, v, *, scale=None):
                                    scale=scale)
         return jnp.moveaxis(out.reshape(b_, h_, s_, -1), 1, 2)
 
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return local(q, k, v)
     names = set(mesh.axis_names)
     dp = tuple(a for a in ("pod", "data") if a in names)

@@ -56,8 +56,8 @@ def moe_block(p, x, cfg):
     to the expert-weight gathers.
     """
     if cfg.moe_local_dispatch:
-        mesh = cm.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
+        mesh = jax.sharding.get_abstract_mesh()
+        if not mesh.empty:
             names = set(mesh.axis_names)
             # dispatch over ALL mesh axes (batch over data *and* model) —
             # restricting to the data axes replicates the dispatch across

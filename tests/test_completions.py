@@ -4,7 +4,7 @@ fidelity invariants the benchmark gates on."""
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import completions as C, jobs as J, schedule, solve
 from repro.scenarios import make_scenario

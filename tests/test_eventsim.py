@@ -13,7 +13,7 @@ import copy
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import completions as C, eventsim, jobs as J, schedule
 from repro.scenarios import make_scenario

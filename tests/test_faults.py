@@ -16,7 +16,7 @@ import copy
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from test_eventsim import _assert_same_outcome, _random_system
 
 from repro.core import eventsim, jobs as J, solvers

@@ -78,17 +78,14 @@ def test_mla_full_config_cache_ratio():
 
 def test_moe_local_dispatch_trivial_mesh():
     """shard_map'ed per-shard dispatch == global dispatch on a 1-dev mesh."""
-    import jax
-    import pytest
-    if not hasattr(jax, "set_mesh"):
-        pytest.skip("ambient-mesh API (jax.set_mesh) not in this jax version")
     cfg = dataclasses.replace(registry.smoke_config("olmoe_1b_7b"),
                               dtype=jnp.float32, moe_capacity_factor=8.0)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     batch = {"tokens": jnp.arange(32).reshape(2, 16) % cfg.vocab_size}
     a = M.prefill_logits(cfg, params, batch)
     mesh = jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+                         devices=jax.devices()[:1],
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with jax.set_mesh(mesh):
         b = M.prefill_logits(
             dataclasses.replace(cfg, moe_local_dispatch=True), params, batch)

@@ -2,7 +2,7 @@
 relationships: evaluator consistency, bound >= simulation, SA quality."""
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (annealing, greedy, jobs as J, network as N,
                         schedule)

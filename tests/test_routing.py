@@ -2,7 +2,7 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import exact, jobs as J, routing
 from util import random_instance

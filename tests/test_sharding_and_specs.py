@@ -12,16 +12,8 @@ from repro.distributed import sharding as sh
 from repro.launch import hlo_analysis
 from repro.models import model as M
 
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across jax versions: (sizes, names) vs ((name, size),...)."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH1 = _abstract_mesh((16, 16), ("data", "model"))
-MESH2 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH1 = AbstractMesh((16, 16), ("data", "model"))
+MESH2 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 @pytest.mark.parametrize("arch", registry.ARCH_IDS)

@@ -4,7 +4,7 @@ properties, constructor validation, and static-path bit-identity."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import jobs as J, network as N, schedule, solve
 from repro.core.state import QueueState, Topology, advance, backlog_seconds

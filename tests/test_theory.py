@@ -1,6 +1,6 @@
 """Theorem 1 (total unimodularity) and Theorem 2 (approximation ratio)."""
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (bounds, exact, greedy, jobs as J, layered_graph,
                         network as N, schedule)
