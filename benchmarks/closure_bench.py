@@ -137,9 +137,9 @@ def bench_greedy_reuse(*, repeat: int, verbose: bool) -> dict:
     rebuild_s = _time(
         lambda: greedy.greedy_route(net, batch, share_closures=False), repeat)
 
-    SP.reset_closure_build_count()
+    builds0 = SP.closure_build_count()
     plan = greedy.greedy_route(net, batch)
-    builds = SP.closure_build_count()
+    builds = SP.closure_build_count() - builds0
     lazy = greedy.greedy_route(net, batch, lazy=True)
 
     rec = dict(

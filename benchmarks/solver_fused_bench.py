@@ -123,7 +123,7 @@ def _solve_scaling(name: str, sizes, *, seed: int, repeat: int,
             lambda: np.asarray(greedy.greedy_route_ref(net, batch).bounds),
             repeat)
         plan = greedy.greedy_route(net, batch)
-        SP.reset_closure_build_count()
+        builds0 = SP.closure_build_count()
         greedy.greedy_route_ref(net, batch)
         row = {
             "scenario": name,
@@ -133,7 +133,7 @@ def _solve_scaling(name: str, sizes, *, seed: int, repeat: int,
             "speedup": ref_s / fused_s,
             "dispatches": plan.meta["dispatches"],
             "rounds_per_dispatch": plan.meta["rounds_per_dispatch"],
-            "ref_closure_builds": SP.closure_build_count(),
+            "ref_closure_builds": SP.closure_build_count() - builds0,
         }
         rows.append(row)
         if verbose:

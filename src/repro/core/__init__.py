@@ -12,7 +12,7 @@ from . import arrivals
 from .routing import (Route, route_single, route_batch,
                       cost_given_assignment, commit_assignment)
 from .shortest_path import (Closures, build_closures, build_closures_batch,
-                            closure_build_count, reset_closure_build_count)
+                            closure_build_count)
 from .plan import Plan
 from .solvers import Solver, solve, register as register_solver, \
     available as available_solvers
@@ -24,7 +24,7 @@ from .completions import (CommittedWork, LedgerJob, drain_exact,
                           exact_backlog_trace, replay_piecewise,
                           run_to_completion)
 from . import (bounds, completions, eventsim, exact, layered_graph,
-               shortest_path, solvers)
+               shortest_path, solvers, telemetry)
 
 __all__ = [
     "ComputeNetwork", "INF", "make_network", "small_topology", "us_backbone",
@@ -34,7 +34,7 @@ __all__ = [
     "Route", "route_single", "route_batch", "cost_given_assignment",
     "commit_assignment",
     "Closures", "build_closures", "build_closures_batch",
-    "closure_build_count", "reset_closure_build_count",
+    "closure_build_count",
     "Plan", "Solver", "solve", "register_solver", "available_solvers",
     "GreedySolution", "greedy_route",  # deprecated alias + legacy name
     "SAResult", "anneal", "evaluate_solution",
@@ -42,5 +42,5 @@ __all__ = [
     "CommittedWork", "LedgerJob", "drain_exact", "exact_backlog_trace",
     "replay_piecewise", "run_to_completion",
     "bounds", "completions", "eventsim", "exact", "layered_graph",
-    "shortest_path", "solvers",
+    "shortest_path", "solvers", "telemetry",
 ]

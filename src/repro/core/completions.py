@@ -60,7 +60,7 @@ import dataclasses
 
 import numpy as np
 
-from . import eventsim, schedule
+from . import eventsim, schedule, telemetry
 from .state import QueueState, Topology, effective_topology
 
 
@@ -443,6 +443,12 @@ def _live_engine(ledger: CommittedWork, mu_node: np.ndarray,
     return eng
 
 
+def _host_rates(topo: Topology) -> tuple[np.ndarray, np.ndarray]:
+    """The topology's rates as float64 host arrays (one counted fetch)."""
+    mu_node, mu_link = telemetry.to_host((topo.mu_node, topo.mu_link))
+    return np.asarray(mu_node, np.float64), np.asarray(mu_link, np.float64)
+
+
 def warm_engine(topo: Topology, ledger: CommittedWork) -> CommittedWork:
     """Attach a live indexed engine to ``ledger`` if it lacks one.
 
@@ -452,8 +458,7 @@ def warm_engine(topo: Topology, ledger: CommittedWork) -> CommittedWork:
     later commit extends it in place.
     """
     if _engine_of(ledger) is None:
-        mu_node = np.asarray(topo.mu_node, np.float64)
-        mu_link = np.asarray(topo.mu_link, np.float64)
+        mu_node, mu_link = _host_rates(topo)
         _attach(ledger, _LedgerEngine(ledger, mu_node, mu_link))
     return ledger
 
@@ -514,8 +519,7 @@ def drain_exact(topo: Topology, ledger: CommittedWork, dt, *,
             eng.eng.now = t_end
             _attach(new, eng)
         return new
-    mu_node = np.asarray(topo.mu_node, np.float64)
-    mu_link = np.asarray(topo.mu_link, np.float64)
+    mu_node, mu_link = _host_rates(topo)
     if engine == "ref":
         tasks = _tasks_of(ledger)
         schedule.run_event_loop_ref(tasks, mu_node, mu_link, t=ledger.clock,
@@ -553,8 +557,7 @@ def run_to_completion(topo: Topology, ledger: CommittedWork, *,
     completions = dict(ledger.completed)
     if not ledger.jobs:
         return completions, ledger
-    mu_node = np.asarray(topo.mu_node, np.float64)
-    mu_link = np.asarray(topo.mu_link, np.float64)
+    mu_node, mu_link = _host_rates(topo)
     if engine == "ref":
         tasks = _tasks_of(ledger)
         t = schedule.run_event_loop_ref(tasks, mu_node, mu_link,
@@ -575,6 +578,7 @@ def run_to_completion(topo: Topology, ledger: CommittedWork, *,
     return completions, out
 
 
+@telemetry.spanned("completions.predict")
 def predict_completions(topo: Topology, ledger: CommittedWork, *,
                         extra_plans=(), at: float | None = None,
                         down: tuple = (), horizon: float = np.inf,
@@ -610,8 +614,7 @@ def predict_completions(topo: Topology, ledger: CommittedWork, *,
         raise ValueError(
             f"cannot score candidates at t={at} behind the ledger clock "
             f"{ledger.clock}")
-    mu_node = np.asarray(topo.mu_node, np.float64)
-    mu_link = np.asarray(topo.mu_link, np.float64)
+    mu_node, mu_link = _host_rates(topo)
     seen = set(ledger.names_seen)
     next_prio = ledger.next_prio
     extras: list[LedgerJob] = []
@@ -756,8 +759,7 @@ def exact_backlog_trace(topo: Topology, log: CommittedWork, times, *,
                               engine="ref")
             out.append(cur.backlog_seconds(topo))
         return np.asarray(out, np.float64)
-    mu_node = np.asarray(topo.mu_node, np.float64)
-    mu_link = np.asarray(topo.mu_link, np.float64)
+    mu_node, mu_link = _host_rates(topo)
     eng = eventsim.EventEngine(mu_node, mu_link, clock=log.clock)
     out = []
     k = 0

@@ -39,6 +39,7 @@ from .jobs import JobBatch
 from .plan import Plan
 from . import routing
 from . import shortest_path as SP
+from . import telemetry
 
 # Deprecated alias (one release): greedy now returns the canonical Plan.
 GreedySolution = Plan
@@ -84,42 +85,12 @@ def _job_paths(pre_net: ComputeNetwork, batch: JobBatch, j: int, assign_row,
 # Fused single-dispatch solver
 # ---------------------------------------------------------------------------
 
-# Host-level dispatch telemetry for the fused path: one increment per
-# ``_fused_solve``/``_fused_solve_many`` *execution* (unlike trace-time
-# counters — see kernels/ops.dispatch_counts — these count real dispatches,
-# so the one-dispatch-per-solve property is directly assertable).  Two
-# lint rules guard this contract statically: RL003 (host-sync-in-device)
-# keeps syncs out of the scanned round loop, and RL006
-# (dispatch-accounting) makes every solver thread these numbers into
-# plan.meta; tests/test_fused.py adds the runtime transfer_guard check.
-_n_fused_dispatches = 0
-
-
-def fused_dispatch_count() -> int:
-    """Fused-solver dispatches since the last reset (one per solve)."""
-    return _n_fused_dispatches
-
-
-def reset_fused_dispatch_count() -> None:
-    global _n_fused_dispatches
-    _n_fused_dispatches = 0
-
-
-def _bump_dispatch(fn) -> int:
-    """Count one dispatch; return ``fn``'s jit cache size before the call.
-
-    jax caches compiled executables per abstract signature; a growing
-    cache size after the call means this signature was new.  The *pre*
-    -call size is recorded here and compared by :func:`_took_compile`.
-    """
-    global _n_fused_dispatches
-    _n_fused_dispatches += 1
-    return fn._cache_size()
-
-
-def _took_compile(fn, size_before: int) -> bool:
-    return fn._cache_size() > size_before
-
+# Each execution counts one ``fused_dispatches`` (``telemetry.call_counted``,
+# which also reports the compile behind ``plan.meta["jit_compiled"]``), so
+# the one-dispatch-per-solve property is directly assertable.  Lint rule
+# RL003 (host-sync-in-device) keeps syncs out of the scanned round loop
+# and RL006 (dispatch-accounting) makes every solver thread the accounting
+# into plan.meta.
 
 def _fused_rounds(net0: ComputeNetwork, batch: JobBatch,
                   dplan: SP.DedupePlan, routed0: jax.Array,
@@ -143,20 +114,25 @@ def _fused_rounds(net0: ComputeNetwork, batch: JobBatch,
     def body(carry, _):
         q_node, q_link, routed = carry
         cur = net0.with_queues(q_node, q_link)
-        cl = SP.closures_for_dedup(cur, dplan, use_pallas=use_pallas)
+        with jax.named_scope("closure"):
+            cl = SP.closures_for_dedup(cur, dplan, use_pallas=use_pallas)
         # Forward DP only: the sequential backpointer walk is the one
         # non-vectorizable piece of the routing, and the round commits a
         # single job — so walk exactly one table, not all J (the walk is
         # pure integer gathers, bit-identical to route_batch's row).
-        cost, total, bps = routing.route_batch_fwd(cur, batch, closures=cl)
+        with jax.named_scope("route_fwd"):
+            cost, total, bps = routing.route_batch_fwd(cur, batch,
+                                                       closures=cl)
         # True inf mask (not the finite INF sentinel): see _round above.
         costs = jnp.where(routed, jnp.inf, cost)
         j = jnp.argmin(costs).astype(jnp.int32)
         assign_j = routing.assign_from_backpointers(total[j], bps[j])
         any_left = jnp.any(~routed)
-        net2 = routing.commit_assignment(
-            cur, batch.comp[j], batch.data[j], batch.src[j], batch.dst[j],
-            batch.num_layers[j], assign_j, closures=cl.job(j))
+        with jax.named_scope("commit"):
+            net2 = routing.commit_assignment(
+                cur, batch.comp[j], batch.data[j], batch.src[j],
+                batch.dst[j], batch.num_layers[j], assign_j,
+                closures=cl.job(j))
         qn2 = jnp.where(any_left, net2.q_node, q_node)
         ql2 = jnp.where(any_left, net2.q_link, q_link)
         out_j = jnp.where(any_left, j, jnp.int32(-1))
@@ -244,9 +220,11 @@ def _paths_post(net0: ComputeNetwork, batch: JobBatch, order, assigns,
     if order.size == 0:
         return {}
     L_sel = np.asarray(num_layers_h)[order]
-    hops = jax.device_get(_walk_paths(
-        *_walk_operands(net0, batch, order, assigns, ql_pre, t_sel, L_sel),
-        max_hops=net0.num_nodes))
+    operands = _walk_operands(net0, batch, order, assigns, ql_pre, t_sel,
+                              L_sel)
+    telemetry.count("walk_dispatches")
+    hops = telemetry.to_host(_walk_paths(*operands,
+                                         max_hops=net0.num_nodes))
     return {int(j): routing.hops_to_paths(hops[p], int(L_sel[p]))
             for p, j in enumerate(order)}
 
@@ -257,8 +235,8 @@ def _walk_operands(net0: ComputeNetwork, batch: JobBatch, order, assigns,
     jobs have ``L_sel`` layers), staged on the device."""
     assigns = np.asarray(assigns)
     lmax = batch.max_layers
-    src_h, dst_h, data_h = (np.asarray(jax.device_get(x))
-                            for x in (batch.src, batch.dst, batch.data))
+    src_h, dst_h, data_h = telemetry.to_host((batch.src, batch.dst,
+                                              batch.data))
     src_sel, dst_sel = src_h[order], dst_h[order]
     # Per-layer walk endpoints: node_l -> node_{l+1} with node_0 = src and
     # dst from layer num_layers on (layers past num_layers are dropped by
@@ -268,12 +246,12 @@ def _walk_operands(net0: ComputeNetwork, batch: JobBatch, order, assigns,
     ends = np.where(np.arange(lmax + 1)[None, :] >= L_sel[:, None],
                     dst_sel[:, None], ends).astype(np.int32)
 
-    # device_put (not jnp.asarray): staging is an *explicit* transfer so
-    # the solver path stays clean under jax.transfer_guard("disallow")
-    # (the runtime complement of lint rule RL003; see tests/test_fused.py).
-    return (jax.device_put(data_h[order]), jax.device_put(ql_pre),
-            link_invrate(net0), jax.device_put(t_sel), jax.device_put(starts),
-            jax.device_put(ends))
+    # An explicit, counted transfer (not jnp.asarray): the solver path
+    # stays clean under jax.transfer_guard("disallow") (the runtime
+    # complement of lint rule RL003; see tests/test_telemetry.py).
+    data_d, ql_d, t_d, starts_d, ends_d = telemetry.to_device(
+        (data_h[order], ql_pre, t_sel, starts, ends))
+    return data_d, ql_d, link_invrate(net0), t_d, starts_d, ends_d
 
 
 def _assemble_plan(batch: JobBatch, net: ComputeNetwork, order, costs,
@@ -311,29 +289,34 @@ def greedy_route(net: ComputeNetwork, batch: JobBatch,
                                 lazy=lazy, share_closures=share_closures,
                                 extract_paths=extract_paths)
     J = batch.num_jobs
-    padded, dplan, routed0 = _stage_window(batch)
-    size0 = _bump_dispatch(_fused_solve)
-    out = _fused_solve(net, padded, dplan, routed0, use_pallas=use_pallas)
-    compiled = _took_compile(_fused_solve, size0)
+    with telemetry.span("greedy.stage"):
+        padded, dplan, routed0 = _stage_window(batch)
+    with telemetry.span("greedy.dispatch"):
+        out, compiled = telemetry.call_counted(
+            "fused_dispatches", _fused_solve, net, padded, dplan, routed0,
+            use_pallas=use_pallas)
     (order, costs, assigns, ql_pre, t_sel), q_node, q_link = out
-    order, costs, assigns, num_layers_h = jax.device_get(
-        (order, costs, assigns, batch.num_layers))
+    with telemetry.span("greedy.fetch"):
+        order, costs, assigns, num_layers_h = telemetry.to_host(
+            (order, costs, assigns, batch.num_layers))
+        if extract_paths:
+            # host copies before mask-slicing: indexing a device array
+            # with a numpy mask is an implicit h2d of the indices
+            ql_h, t_h = telemetry.to_host((ql_pre, t_sel))
     # drop padding rounds; every round is real in the common unpadded
     # serving case, where the mask gathers would be pure eager overhead
     keep = slice(None) if (order >= 0).all() else order >= 0
     paths = None
     if extract_paths:
-        # host copies before mask-slicing: indexing a device array with a
-        # numpy mask is an implicit h2d of the indices (trips the
-        # transfer_guard("disallow") the parity tests run under)
-        ql_h, t_h = jax.device_get((ql_pre, t_sel))
-        paths = _paths_post(net, batch, order[keep], assigns[keep],
-                            ql_h[keep], t_h[keep], num_layers_h)
-    return _assemble_plan(
-        batch, net.with_queues(q_node, q_link), order[keep], costs[keep],
-        assigns[keep], paths,
-        meta=_fused_meta(J, rounds=padded.num_jobs, compiled=compiled,
-                         paths=extract_paths))
+        with telemetry.span("greedy.paths"):
+            paths = _paths_post(net, batch, order[keep], assigns[keep],
+                                ql_h[keep], t_h[keep], num_layers_h)
+    with telemetry.span("greedy.assemble"):
+        return _assemble_plan(
+            batch, net.with_queues(q_node, q_link), order[keep],
+            costs[keep], assigns[keep], paths,
+            meta=_fused_meta(J, rounds=padded.num_jobs, compiled=compiled,
+                             paths=extract_paths))
 
 
 def _stage_window(batch: JobBatch) -> tuple:
@@ -343,8 +326,9 @@ def _stage_window(batch: JobBatch) -> tuple:
     J = batch.num_jobs
     j_pad = _next_pow2(J)
     padded = _pad_batch(batch, j_pad)
-    return (padded, _bucket_dplan(SP.dedupe_plan(padded)),
-            jax.device_put(np.arange(j_pad) >= J))
+    dplan, routed0 = telemetry.to_device(
+        (_bucket_dplan(SP.dedupe_plan_host(padded)), np.arange(j_pad) >= J))
+    return padded, dplan, routed0
 
 
 def _stage_windows(batches: list[JobBatch]) -> tuple:
@@ -353,16 +337,18 @@ def _stage_windows(batches: list[JobBatch]) -> tuple:
     plans and the [W, J] mask of real jobs."""
     j_max = _next_pow2(max(b.num_jobs for b in batches))
     padded = [_pad_batch(b, j_max) for b in batches]
-    dplans = [SP.dedupe_plan(b) for b in padded]
-    u_max = _next_pow2(max(np.asarray(d.uniq).shape[0] for d in dplans))
-    d_max = _next_pow2(max(np.asarray(d.d_vals).shape[0] for d in dplans))
+    dplans = [SP.dedupe_plan_host(b) for b in padded]
+    u_max = _next_pow2(max(d.uniq.shape[0] for d in dplans))
+    d_max = _next_pow2(max(d.d_vals.shape[0] for d in dplans))
     dplans = [_pad_dplan(d, u_max, d_max) for d in dplans]
-    stack = lambda xs: jax.tree_util.tree_map(
-        lambda *leaves: jnp.stack(leaves), *xs)
-    valid = jax.device_put(np.array(
+    dplans = jax.tree_util.tree_map(lambda *leaves: np.stack(leaves),
+                                    *dplans)
+    valid = np.array(
         [[1] * b.num_jobs + [0] * (j_max - b.num_jobs) for b in batches],
-        bool))
-    return padded, stack(padded), stack(dplans), valid
+        bool)
+    stacked = jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves),
+                                     *padded)
+    return (padded, stacked) + telemetry.to_device((dplans, valid))
 
 
 def _next_pow2(n: int) -> int:
@@ -386,40 +372,38 @@ def _pad_batch(batch: JobBatch, j_to: int) -> JobBatch:
     if J == j_to:
         return batch
     pad = j_to - J
+    host = telemetry.to_host(batch)
 
     def pad0(x):
-        width = [(0, pad)] + [(0, 0)] * (x.ndim - 1)
-        return jax.device_put(np.pad(np.asarray(x), width))
+        return np.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
 
-    return JobBatch(src=pad0(batch.src), dst=pad0(batch.dst),
-                    comp=pad0(batch.comp), data=pad0(batch.data),
-                    num_layers=pad0(batch.num_layers) + jax.device_put(
-                        np.array([0] * J + [1] * pad, np.int32)))
+    return telemetry.to_device(JobBatch(
+        src=pad0(host.src), dst=pad0(host.dst), comp=pad0(host.comp),
+        data=pad0(host.data),
+        num_layers=pad0(host.num_layers) + np.array([0] * J + [1] * pad,
+                                                    np.int32)))
 
 
 def _pad_dplan(dplan: SP.DedupePlan, u_to: int, d_to: int) -> SP.DedupePlan:
-    """Pad a dedupe plan to common unique-row/-scalar counts.  Padding rows
-    duplicate existing entries, so the closure work grows but every real
-    gather lands on the same values — bit-identical results."""
-    uniq, inv = np.asarray(dplan.uniq), np.asarray(dplan.inv)
-    d_vals, d_idx = np.asarray(dplan.d_vals), np.asarray(dplan.d_idx)
+    """Pad a host dedupe plan to common unique-row/-scalar counts.  Padding
+    rows duplicate existing entries, so the closure work grows but every
+    real gather lands on the same values — bit-identical results."""
+    uniq, inv, d_vals, d_idx = dplan.uniq, dplan.inv, dplan.d_vals, dplan.d_idx
     u_pad, d_pad = u_to - uniq.shape[0], d_to - d_vals.shape[0]
     if u_pad:
         uniq = np.concatenate([uniq, np.repeat(uniq[:1], u_pad, axis=0)])
         d_idx = np.concatenate([d_idx, np.repeat(d_idx[:1], u_pad, axis=0)])
     if d_pad:
         d_vals = np.concatenate([d_vals, np.repeat(d_vals[:1], d_pad)])
-    return SP.DedupePlan(uniq=jax.device_put(uniq),
-                         inv=jax.device_put(inv),
-                         d_vals=jax.device_put(d_vals),
-                         d_idx=jax.device_put(d_idx.astype(np.int32)))
+    return SP.DedupePlan(uniq=uniq, inv=inv, d_vals=d_vals,
+                         d_idx=d_idx.astype(np.int32))
 
 
 def _bucket_dplan(dplan: SP.DedupePlan) -> SP.DedupePlan:
     """Round the dedupe plan's unique-row/-scalar counts up to powers of
     two (see :func:`_next_pow2`) so batches with slightly different model
     mixes share one compiled program."""
-    u, d = np.asarray(dplan.uniq).shape[0], np.asarray(dplan.d_vals).shape[0]
+    u, d = dplan.uniq.shape[0], dplan.d_vals.shape[0]
     return _pad_dplan(dplan, _next_pow2(u), _next_pow2(d))
 
 
@@ -445,21 +429,22 @@ def greedy_route_windows(net: ComputeNetwork, batches: list[JobBatch],
         raise ValueError(
             f"windows must share a padded layer width (batch_jobs(pad_to=)); "
             f"got {sorted(lmax)}")
-    padded, stacked, dplans, valid = _stage_windows(batches)
+    with telemetry.span("greedy.stage"):
+        padded, stacked, dplans, valid = _stage_windows(batches)
     j_max = padded[0].num_jobs
-    size0 = _bump_dispatch(_fused_solve_many)
-    outs = _fused_solve_many(net, stacked, dplans, valid,
-                             use_pallas=use_pallas)
-    compiled = _took_compile(_fused_solve_many, size0)
+    with telemetry.span("greedy.dispatch"):
+        outs, compiled = telemetry.call_counted(
+            "fused_dispatches", _fused_solve_many, net, stacked, dplans,
+            valid, use_pallas=use_pallas)
     (orders, costs, assigns, ql_pre, t_sel), q_nodes, q_links = outs
-    orders, costs, assigns = jax.device_get((orders, costs, assigns))
-    # host copies: per-window numpy indexing is free (d2h is zero-copy on
-    # CPU), while indexing the device arrays with python ints / numpy
-    # masks would implicitly stage the indices — tripping the
-    # transfer_guard("disallow") the parity tests run under
-    q_nodes, q_links = jax.device_get((q_nodes, q_links))
-    if extract_paths:
-        ql_pre, t_sel = jax.device_get((ql_pre, t_sel))
+    with telemetry.span("greedy.fetch"):
+        # host copies: per-window numpy indexing is free, while indexing
+        # the device arrays with python ints / numpy masks would
+        # implicitly stage the indices
+        orders, costs, assigns, q_nodes, q_links = telemetry.to_host(
+            (orders, costs, assigns, q_nodes, q_links))
+        if extract_paths:
+            ql_pre, t_sel = telemetry.to_host((ql_pre, t_sel))
     plans = []
     for w, batch in enumerate(batches):
         J = batch.num_jobs
@@ -467,16 +452,18 @@ def greedy_route_windows(net: ComputeNetwork, batches: list[JobBatch],
         order_w = orders[w][keep]
         paths = None
         if extract_paths:
-            paths = _paths_post(
-                net, padded[w], order_w, assigns[w][keep], ql_pre[w][keep],
-                t_sel[w][keep],
-                np.asarray(jax.device_get(padded[w].num_layers)))
-        plans.append(_assemble_plan(
-            batch, net.with_queues(jax.device_put(q_nodes[w]),
-                                   jax.device_put(q_links[w])), order_w,
-            costs[w][keep], assigns[w][keep], paths,
-            meta=_fused_meta(J, rounds=j_max, windows=len(batches),
-                             compiled=compiled, paths=extract_paths)))
+            with telemetry.span("greedy.paths"):
+                paths = _paths_post(
+                    net, padded[w], order_w, assigns[w][keep],
+                    ql_pre[w][keep], t_sel[w][keep],
+                    telemetry.to_host(padded[w].num_layers))
+        with telemetry.span("greedy.assemble"):
+            plans.append(_assemble_plan(
+                batch, net.with_queues(*telemetry.to_device(
+                    (q_nodes[w], q_links[w]))), order_w,
+                costs[w][keep], assigns[w][keep], paths,
+                meta=_fused_meta(J, rounds=j_max, windows=len(batches),
+                                 compiled=compiled, paths=extract_paths)))
     return plans
 
 

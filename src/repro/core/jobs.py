@@ -17,8 +17,9 @@ import dataclasses
 from typing import Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+
+from . import telemetry
 
 
 @dataclasses.dataclass(frozen=True)
@@ -110,10 +111,8 @@ def batch_jobs(jobs: Sequence[InferenceJob], *, pad_to: int | None = None) -> Jo
         # data entry stays 0 so transfers of padded layers are free and the
         # true final transfer d_L is handled by the masked DP epilogue.
         src[i], dst[i], nl[i] = j.src, j.dst, L
-    return JobBatch(
-        src=jnp.asarray(src), dst=jnp.asarray(dst), comp=jnp.asarray(comp),
-        data=jnp.asarray(data), num_layers=jnp.asarray(nl),
-    )
+    return telemetry.to_device(
+        JobBatch(src=src, dst=dst, comp=comp, data=data, num_layers=nl))
 
 
 def synthetic_job(

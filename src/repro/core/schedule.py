@@ -50,7 +50,7 @@ import numpy as np
 
 from .network import ComputeNetwork
 from .jobs import JobBatch
-from . import routing
+from . import routing, telemetry
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,9 +118,10 @@ def job_stages(batch: JobBatch, assign,
     completes.  This is the precedence structure both the one-shot
     simulator and the incremental committed-work drain honour.
     """
-    comp = np.asarray(batch.comp, np.float64)
-    data = np.asarray(batch.data, np.float64)
-    nl = np.asarray(batch.num_layers)
+    comp, data, nl = telemetry.to_host(
+        (batch.comp, batch.data, batch.num_layers))
+    comp = np.asarray(comp, np.float64)
+    data = np.asarray(data, np.float64)
     a = np.asarray(assign)
     stages: dict[int, list[Stage]] = {}
     for j in range(batch.num_jobs):

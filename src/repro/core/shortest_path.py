@@ -12,9 +12,10 @@ the min-plus closure of w_l, the kernel hot-spot (see kernels/minplus.py).
 **once** per queue state and shared by everything that needs it — routing,
 commit, cost evaluation, path extraction.  ``build_closures`` /
 ``build_closures_batch`` are the counted host-level builders (the greedy
-driver calls them once per round; ``closure_build_count`` powers the
-regression test asserting exactly that); ``closures_for`` is the uncounted
-pure builder safe to call under jit/scan tracing.
+driver calls them once per round; the ``closure_builds`` counter of
+:mod:`~repro.core.telemetry` powers the regression test asserting exactly
+that); ``closures_for`` is the uncounted pure builder safe to call under
+jit/scan tracing.
 
 ``reconstruct_path`` recovers an explicit hop list from the closure: from u
 toward v, the next hop is argmin_w  w_l(u, w) + T[l, w, v].  Walking this
@@ -32,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from . import telemetry
 from .network import INF, ComputeNetwork, link_invrate, link_wait
 
 
@@ -59,19 +61,11 @@ class Closures:
         return Closures(w=None if self.w is None else self.w[j], t=self.t[j])
 
 
-_n_builds = 0
-
-
 def closure_build_count() -> int:
-    """Host-level closure builds since the last reset (one per
+    """Host-level closure builds so far in this process (one per
     ``build_closures``/``build_closures_batch`` call; in-jit fallback builds
     are not counted)."""
-    return _n_builds
-
-
-def reset_closure_build_count() -> None:
-    global _n_builds
-    _n_builds = 0
+    return telemetry.counter("closure_builds")
 
 
 def layer_edge_weights(net: ComputeNetwork, data_sizes: jax.Array) -> jax.Array:
@@ -107,8 +101,7 @@ def closures_for(net: ComputeNetwork, data_sizes: jax.Array,
 def build_closures(net: ComputeNetwork, data_sizes: jax.Array,
                    *, use_pallas: bool | None = None) -> Closures:
     """Counted host-level :class:`Closures` build for one data-size vector."""
-    global _n_builds
-    _n_builds += 1
+    telemetry.count("closure_builds")
     return closures_for(net, data_sizes, use_pallas=use_pallas)
 
 
@@ -118,11 +111,14 @@ def dedupe_data(batch) -> tuple[jax.Array, jax.Array]:
     Host-level (needs concrete ``batch.data``); constant across greedy
     rounds, so drivers hoist it out of the round loop.
     """
-    data = np.asarray(jax.device_get(batch.data))
-    uniq, inv = np.unique(data, axis=0, return_inverse=True)
     # explicit staging: keeps solver drivers transfer_guard("disallow")-clean
-    return (jax.device_put(uniq),
-            jax.device_put(inv.reshape(-1).astype(np.int32)))
+    return telemetry.to_device(_dedupe_rows(batch))
+
+
+def _dedupe_rows(batch) -> tuple[np.ndarray, np.ndarray]:
+    data = np.asarray(telemetry.to_host(batch.data))
+    uniq, inv = np.unique(data, axis=0, return_inverse=True)
+    return uniq, inv.reshape(-1).astype(np.int32)
 
 
 @jax.tree_util.register_dataclass
@@ -151,12 +147,16 @@ class DedupePlan:
 
 def dedupe_plan(batch) -> DedupePlan:
     """Build the two-level :class:`DedupePlan` for a job batch (host-level)."""
-    uniq, inv = dedupe_data(batch)
-    uniq_h = np.asarray(uniq)
-    d_vals, d_idx = np.unique(uniq_h, return_inverse=True)
-    return DedupePlan(
-        uniq=uniq, inv=inv, d_vals=jax.device_put(d_vals),
-        d_idx=jax.device_put(d_idx.reshape(uniq_h.shape).astype(np.int32)))
+    return telemetry.to_device(dedupe_plan_host(batch))
+
+
+def dedupe_plan_host(batch) -> DedupePlan:
+    """:func:`dedupe_plan` with numpy leaves, for staging that pads it on
+    the host before the one transfer."""
+    uniq, inv = _dedupe_rows(batch)
+    d_vals, d_idx = np.unique(uniq, return_inverse=True)
+    return DedupePlan(uniq=uniq, inv=inv, d_vals=d_vals,
+                      d_idx=d_idx.reshape(uniq.shape).astype(np.int32))
 
 
 def closures_for_dedup(net: ComputeNetwork, plan: DedupePlan,
@@ -200,8 +200,7 @@ def build_closures_batch(net: ComputeNetwork, batch,
     :func:`dedupe_data` result (it is queue-state independent, so round
     loops hoist it).  Counted as one build.
     """
-    global _n_builds
-    _n_builds += 1
+    telemetry.count("closure_builds")
     uniq, inv = dedupe_data(batch) if dedupe is None else dedupe
     return _closures_gathered(net, uniq, inv, use_pallas=use_pallas)
 

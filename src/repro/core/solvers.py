@@ -31,6 +31,7 @@ from .state import QueueState, Topology
 from .jobs import JobBatch
 from .plan import Plan
 from .shortest_path import closure_build_count
+from . import telemetry
 
 
 @runtime_checkable
@@ -92,7 +93,8 @@ def solve(net: ComputeNetwork | Topology, batch: JobBatch,
     fn = get(method)
     n0 = closure_build_count()
     t0 = time.perf_counter()
-    plan = fn(net, batch, **opts)
+    with telemetry.span("solve"):
+        plan = fn(net, batch, **opts)
     if not isinstance(plan, Plan):
         raise TypeError(f"solver {method!r} returned {type(plan).__name__}, "
                         "expected Plan")
@@ -132,7 +134,8 @@ def solve_fused(net: ComputeNetwork | Topology, batches: list[JobBatch],
                              f"{pad_to}; got layer widths {bad}")
     n0 = closure_build_count()
     t0 = time.perf_counter()
-    plans = greedy.greedy_route_windows(net, batches, **opts)
+    with telemetry.span("solve"):
+        plans = greedy.greedy_route_windows(net, batches, **opts)
     wall = time.perf_counter() - t0
     builds = closure_build_count() - n0
     return [dataclasses.replace(p, meta={

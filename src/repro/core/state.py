@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import telemetry
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -63,11 +65,6 @@ class Topology:
                               state=self.empty_state() if state is None
                               else state)
 
-    def scale_nodes(self, factor) -> "Topology":
-        """Topology with ``mu_node * factor`` (elementwise; straggler views)."""
-        return Topology(mu_node=self.mu_node * jnp.asarray(factor),
-                        mu_link=self.mu_link)
-
 
 def effective_topology(topo: Topology, slowdown,
                        avail_node=None, link_up=None) -> Topology:
@@ -79,11 +76,13 @@ def effective_topology(topo: Topology, slowdown,
     (float32 in both callers).  ``avail_node`` [V] bool zeroes failed
     nodes' compute *and* every incident link (a dead node cannot relay);
     ``link_up`` [V, V] bool zeroes individually failed directed links.
-    With both masks omitted this is exactly ``scale_nodes(1/slowdown)`` —
-    the pre-fault expression, preserved bit-for-bit.
+    With both masks omitted this is exactly ``mu_node * (1 / slowdown)``
+    — the pre-fault expression, preserved bit-for-bit.
     """
     if avail_node is None and link_up is None:
-        return topo.scale_nodes(1.0 / jnp.asarray(slowdown))
+        return Topology(mu_node=_scale_by_inverse(
+            topo.mu_node, telemetry.to_device(np.asarray(slowdown))),
+            mu_link=topo.mu_link)
     avail = (np.ones((topo.num_nodes,), bool) if avail_node is None
              else np.asarray(avail_node, bool))
     scale = jnp.where(jnp.asarray(avail),
@@ -94,6 +93,15 @@ def effective_topology(topo: Topology, slowdown,
     return Topology(mu_node=topo.mu_node * scale,
                     mu_link=topo.mu_link * jnp.asarray(mask,
                                                        topo.mu_link.dtype))
+
+
+@jax.jit
+def _scale_by_inverse(mu_node: jax.Array, slowdown: jax.Array) -> jax.Array:
+    """``mu_node * (1 / slowdown)`` in one program: jitted so the constant
+    is baked at trace time (the eager form staged it per call).  No
+    multiply feeds an add, so there is nothing to contract, and it matches
+    the two eager ops bit for bit (``tests/test_state.py``)."""
+    return mu_node * (1.0 / slowdown)
 
 
 @jax.tree_util.register_dataclass
@@ -137,6 +145,7 @@ def advance(topo: Topology, state: QueueState, dt) -> QueueState:
     )
 
 
+@telemetry.spanned("sched.backlog")
 def backlog_seconds(topo: Topology, state: QueueState) -> float:
     """Worst-resource residual wait: max over nodes/links of Q / mu (host).
 
@@ -144,10 +153,10 @@ def backlog_seconds(topo: Topology, state: QueueState) -> float:
     most backed-up resource — the scalar the online benchmarks and the
     stability tests track over time.
     """
-    mu_n = np.asarray(topo.mu_node, np.float64)
-    mu_l = np.asarray(topo.mu_link, np.float64)
-    q_n = np.asarray(state.q_node, np.float64)
-    q_l = np.asarray(state.q_link, np.float64)
+    mu_n, mu_l, q_n, q_l = (np.asarray(x, np.float64)
+                            for x in telemetry.to_host(
+                                (topo.mu_node, topo.mu_link,
+                                 state.q_node, state.q_link)))
     node_wait = np.where(mu_n > 0, q_n / np.maximum(mu_n, 1e-30), 0.0)
     link_wait = np.where(mu_l > 0, q_l / np.maximum(mu_l, 1e-30), 0.0)
     return float(max(node_wait.max(initial=0.0), link_wait.max(initial=0.0)))

@@ -9,12 +9,10 @@ tiled path.  On CPU the kernels run in interpret mode — the TPU is the
 target, CPU validates semantics.
 
 ``minplus_dispatch`` is the pure (shape -> path) decision function, exposed
-so tests and benchmarks can introspect dispatch without running the kernel;
-``dispatch_counts`` tallies which path each traced ``minplus_matmul`` took.
+so tests and benchmarks can introspect dispatch without running the kernel.
 """
 from __future__ import annotations
 
-import collections
 import functools
 
 import jax
@@ -26,44 +24,6 @@ from .minplus import minplus_matmul_pallas, minplus_matmul_pallas_batched
 _PAD = jnp.float32(1e30)
 # Below this dimension the [n, n, n] broadcast oracle is cheaper than tiling.
 _PALLAS_MIN_DIM = 256
-
-# Trace-time tally of dispatch decisions (jit caching means a hit is recorded
-# once per traced shape, not once per execution) — introspection/testing aid.
-_DISPATCH_COUNTS: collections.Counter = collections.Counter()
-
-
-def dispatch_counts() -> dict[str, int]:
-    """Copy of the {path: times-traced} tally ("oracle" | "pallas_2d" |
-    "pallas_batched").
-
-    These counters fire at **trace time**, not execution time: a jitted
-    caller records each kernel choice once per compiled signature, then
-    every cached re-execution runs the chosen kernel without touching the
-    tally.  The distinction matters most for the fused greedy solver —
-    its whole round loop (J rounds x closure squarings per round) is one
-    device program, so a solve that *executes* hundreds of min-plus
-    kernels adds at most a handful of entries here (and a warmed shape
-    adds zero).  Per-solve execution telemetry lives in the solver's
-    plan meta instead: ``meta["dispatches"]`` / ``meta["rounds_per_
-    dispatch"]`` count what the device actually ran.
-
-    Raises ``RuntimeError`` when called under an active trace: the tally
-    mid-trace is a partial mixture of finished and in-flight tracings, so
-    any number read there silently over/under-counts (and a traced reader
-    would bake the stale snapshot into the compiled program as a
-    constant).
-    """
-    if not jax.core.trace_ctx.is_top_level():
-        raise RuntimeError(
-            "dispatch_counts() called under an active jax trace: the "
-            "trace-time tally is mid-update, and a traced reader would "
-            "bake a stale snapshot into the compiled program. Read it "
-            "from host driver code after the traced call returns.")
-    return dict(_DISPATCH_COUNTS)
-
-
-def reset_dispatch_counts() -> None:
-    _DISPATCH_COUNTS.clear()
 
 
 def minplus_dispatch(a_shape: tuple[int, ...],
@@ -107,7 +67,6 @@ def minplus_matmul(a: jax.Array, b: jax.Array, *, use_pallas: bool | None = None
     shapes use the broadcast oracle.
     """
     kind = minplus_dispatch(a.shape, b.shape, use_pallas=use_pallas)
-    _DISPATCH_COUNTS[kind] += 1
     if kind == "oracle":
         return ref.minplus_matmul_ref(a, b)
 

@@ -505,8 +505,8 @@ def rl006_dispatch_accounting(module: LintModule):
                 "dispatch-accounting: Plan built without meta=; solver "
                 "entry points must thread meta['dispatches'] or "
                 "meta['n_routings'] so the one-dispatch contract stays "
-                "checkable (see fused_dispatch_count in "
-                "src/repro/core/greedy.py)."), call
+                "checkable (see the fused_dispatches counter in "
+                "src/repro/core/telemetry.py)."), call
             continue
         keys = _resolve_meta_keys(module, call, meta)
         if keys is not None and not (keys & ACCOUNTING_KEYS):
@@ -514,5 +514,5 @@ def rl006_dispatch_accounting(module: LintModule):
                 call, "RL006",
                 "dispatch-accounting: plan meta carries no dispatch "
                 "accounting key (need one of "
-                f"{sorted(ACCOUNTING_KEYS)}); see fused_dispatch_count "
-                "in src/repro/core/greedy.py."), call
+                f"{sorted(ACCOUNTING_KEYS)}); see the fused_dispatches "
+                "counter in src/repro/core/telemetry.py."), call

@@ -33,7 +33,8 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.core import arrivals as A, completions as C, jobs as J, schedule
+from repro.core import (arrivals as A, completions as C, jobs as J, schedule,
+                        telemetry)
 from repro.core.state import Topology, backlog_seconds
 from .admission import (AdmissionController, AdmissionPolicy, ReplanMonitor,
                         ReplanPolicy)
@@ -286,6 +287,7 @@ class OnlineScheduler(RoutedScheduler):
         """Event time == the scheduler's one authoritative clock."""
         return self.clock
 
+    @telemetry.spanned("sched.drain")
     def advance_to(self, t: float) -> None:
         """Move the clock to absolute time ``t``, draining if enabled.
 
@@ -312,6 +314,7 @@ class OnlineScheduler(RoutedScheduler):
         """Arrival event: drain to ``t``, place the batch, record the epoch."""
         return self.submit_window(t, infer_jobs, pad_to=pad_to)
 
+    @telemetry.spanned("sched.submit_window")
     def submit_window(self, t: float, infer_jobs: Sequence[J.InferenceJob],
                       *, arrivals: Sequence[float] | None = None,
                       pad_to: int | None = None,

@@ -57,7 +57,8 @@ import time
 
 import numpy as np
 
-from repro.core import completions as C, jobs as J, network as N, solvers
+from repro.core import (completions as C, jobs as J, network as N, solvers,
+                        telemetry)
 from repro.core.state import QueueState, Topology, effective_topology
 from repro.core.plan import Plan
 from repro.configs import registry
@@ -307,9 +308,8 @@ class RoutedScheduler:
 
     def _sync_ledger_queues(self) -> None:
         """Materialize the ledger's residual work into the QueueState."""
-        import jax.numpy as jnp
-        qn, ql = self.ledger.queue_arrays()
-        self.state = self.state.with_queues(jnp.asarray(qn), jnp.asarray(ql))
+        self.state = self.state.with_queues(
+            *telemetry.to_device(self.ledger.queue_arrays()))
 
     def advance(self, dt: float) -> None:
         """Let ``dt`` seconds pass: the backlog drains at effective rates
@@ -321,9 +321,8 @@ class RoutedScheduler:
         self._stamp_clock()
 
     def _stamp_clock(self) -> None:
-        import jax.numpy as jnp
-        self.state = dataclasses.replace(self.state,
-                                         clock=jnp.float32(self._now))
+        self.state = dataclasses.replace(
+            self.state, clock=telemetry.to_device(np.float32(self._now)))
 
     @property
     def clock(self) -> float:
@@ -364,6 +363,7 @@ class RoutedScheduler:
                                   "windows_per_dispatch", "jit_compiled")
                 if k in m}
 
+    @telemetry.spanned("sched.topology")
     def _effective_topology(self) -> Topology:
         if not self.degraded:
             # bit-identical to the pre-fault expression (and rates)
@@ -391,6 +391,7 @@ class RoutedScheduler:
         return ((self.ledger is not None or self.commit_log is not None)
                 and method in self._PATH_SOLVERS)
 
+    @telemetry.spanned("sched.commit")
     def _commit_plan(self, topo: Topology, batch: J.JobBatch, plan: Plan,
                      pre_state: QueueState,
                      names: list[str] | None) -> Plan:
@@ -457,7 +458,8 @@ class RoutedScheduler:
         queue/ledger/telemetry mutation.  The admission controller scores
         the returned plan with ``completions.predict_completions`` before
         deciding whether to commit it (:meth:`commit_presolved`)."""
-        batch = J.batch_jobs(infer_jobs, pad_to=pad_to)
+        with telemetry.span("sched.batch"):
+            batch = J.batch_jobs(infer_jobs, pad_to=pad_to)
         method = self.method if method is None else method
         opts = self.solver_opts
         if self._want_paths(method):
@@ -532,7 +534,8 @@ class RoutedScheduler:
                 self._window_states.append(self.state)
             return out
         topo = self._effective_topology()
-        batches = [J.batch_jobs(jobs, pad_to=pad_to) for jobs in windows]
+        with telemetry.span("sched.batch"):
+            batches = [J.batch_jobs(jobs, pad_to=pad_to) for jobs in windows]
         opts = self.solver_opts
         if self._want_paths(method):
             opts = {"extract_paths": True, **opts}

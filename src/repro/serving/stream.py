@@ -75,7 +75,7 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
-from repro.core import arrivals as A, jobs as J
+from repro.core import arrivals as A, jobs as J, telemetry
 from repro.core.state import Topology
 from .online import OnlineScheduler, OnlineTrace
 
@@ -502,6 +502,11 @@ class StreamingPipeline:
 
     # -- solver commit stage -------------------------------------------------
     def _commit(self, t: float, ws: list[_Window], d: float) -> None:
+        telemetry.set_window(ws[0].index)
+        with telemetry.span("pipeline.commit"):
+            self._commit_group(t, ws, d)
+
+    def _commit_group(self, t: float, ws: list[_Window], d: float) -> None:
         if self._injector is not None and self.sched.degraded:
             # Commit-time routability: the topology may have degraded since
             # these requests were admitted; a request whose endpoints are

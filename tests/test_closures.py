@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import greedy, jobs as J, network as N, routing, solvers
+from repro.core import (greedy, jobs as J, network as N, routing, solvers,
+                        telemetry)
 from repro.core import shortest_path as SP
 from repro.kernels import ops
 from util import random_instance
@@ -46,21 +47,21 @@ def test_one_closure_build_per_greedy_round():
     rng = np.random.default_rng(0)
     net, jobs = random_instance(rng, num_jobs=5)
     batch = J.batch_jobs(jobs)
-    SP.reset_closure_build_count()
+    n0 = telemetry.counter("closure_builds")
     greedy.greedy_route_ref(net, batch)
-    assert SP.closure_build_count() == batch.num_jobs  # one per round
-    SP.reset_closure_build_count()
+    assert telemetry.counter("closure_builds") - n0 == batch.num_jobs
+    n0 = telemetry.counter("closure_builds")
     greedy.greedy_route(net, batch)
-    assert SP.closure_build_count() == 0  # fused: all in-program
+    assert telemetry.counter("closure_builds") == n0  # fused: in-program
 
 
 def test_lazy_one_closure_build_per_round():
     rng = np.random.default_rng(1)
     net, jobs = random_instance(rng, num_jobs=5)
     batch = J.batch_jobs(jobs)
-    SP.reset_closure_build_count()
+    n0 = telemetry.counter("closure_builds")
     greedy.greedy_route(net, batch, lazy=True)
-    assert SP.closure_build_count() == batch.num_jobs
+    assert telemetry.counter("closure_builds") - n0 == batch.num_jobs
 
 
 def test_solver_meta_reports_closure_builds():
@@ -104,16 +105,14 @@ def test_transfer_closure_stack_dispatches_to_batched_kernel():
     lmax = 8
     v = 256
     assert ops.minplus_dispatch((lmax + 1, v, v)) == "pallas_batched"
-    # trace a real transfer_closure at that size (eval_shape: no execution)
-    # and assert its squaring loop recorded the batched-kernel choice
+    # trace a real transfer_closure at that size (no execution) and assert
+    # its squaring loop reaches the Pallas kernel
     net = N.make_network(v, [(i, (i + 1) % v, 1.0) for i in range(v)],
                          np.ones(v))
     data = jnp.ones((lmax + 1,), jnp.float32)
-    ops.reset_dispatch_counts()
-    out = jax.eval_shape(SP.transfer_closure, net, data)
-    assert out.shape == (lmax + 1, v, v)
-    assert ops.dispatch_counts().get("pallas_batched", 0) >= 1
-    assert ops.dispatch_counts().get("oracle", 0) == 0
+    traced = jax.make_jaxpr(SP.transfer_closure)(net, data)
+    assert traced.out_avals[0].shape == (lmax + 1, v, v)
+    assert "pallas_call" in str(traced)
     # and the batched path is numerically right where it is cheap to run
     rng = np.random.default_rng(0)
     w = jnp.asarray(np.where(rng.random((3, 30, 30)) < 0.4,

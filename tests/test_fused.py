@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import greedy, jobs as J, network as N, solvers
+from repro.core import greedy, jobs as J, network as N, solvers, telemetry
 from repro.core import shortest_path as SP
 from repro.scenarios import make_scenario
 from repro.serving.online import OnlineScheduler
@@ -106,16 +106,16 @@ def test_fused_solve_is_one_dispatch():
     net, jobs = random_instance(rng, num_jobs=8)  # 8 = pow2: exact meta
     batch = J.batch_jobs(jobs)
     greedy.greedy_route(net, batch)     # compile warmup, outside the guard
-    SP.reset_closure_build_count()
-    greedy.reset_fused_dispatch_count()
+    builds0 = SP.closure_build_count()
+    n0 = telemetry.counter("fused_dispatches")
     # transfer_guard("disallow") is the runtime complement of lint rule
     # RL003: any *implicit* host<->device transfer in the warm solve path
     # (all staging must be explicit jax.device_put) fails loudly here, not
     # just via the dispatch counter.
     with jax.transfer_guard("disallow"):
         plan = greedy.greedy_route(net, batch)
-    assert greedy.fused_dispatch_count() == 1
-    assert SP.closure_build_count() == 0
+    assert telemetry.counter("fused_dispatches") - n0 == 1
+    assert SP.closure_build_count() == builds0
     assert plan.meta["fused"] is True
     assert plan.meta["dispatches"] == 1
     assert plan.meta["rounds_per_dispatch"] == batch.num_jobs
@@ -123,7 +123,7 @@ def test_fused_solve_is_one_dispatch():
     # a second solve at the same shapes must not recompile
     with jax.transfer_guard("disallow"):
         greedy.greedy_route(net, batch)
-    assert greedy.fused_dispatch_count() == 2
+    assert telemetry.counter("fused_dispatches") - n0 == 2
 
 
 # ---------------------------------------------------------------------------
@@ -143,12 +143,12 @@ def test_multi_window_matches_sequential_fused():
                                                for j in jobs)))
         off += n
     greedy.greedy_route_windows(net, batches, extract_paths=True)  # warmup
-    greedy.reset_fused_dispatch_count()
+    n0 = telemetry.counter("fused_dispatches")
     # warm multi-window solve must also be implicit-transfer-free (RL003's
     # runtime complement) — ragged windows are padded/staged via device_put
     with jax.transfer_guard("disallow"):
         fused = greedy.greedy_route_windows(net, batches, extract_paths=True)
-    assert greedy.fused_dispatch_count() == 1
+    assert telemetry.counter("fused_dispatches") - n0 == 1
     cur, seq = net, []
     for b in batches:
         p = greedy.greedy_route(cur, b, extract_paths=True)

@@ -7,7 +7,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import jobs as J, network as N, schedule, solve
-from repro.core.state import QueueState, Topology, advance, backlog_seconds
+from repro.core.state import (QueueState, Topology, advance, backlog_seconds,
+                              effective_topology)
 from util import random_instance
 
 
@@ -51,6 +52,21 @@ def test_advance_exact_drain_rate():
     st3 = advance(net.topology, state, 100.0)   # fully drained, clipped at 0
     assert float(np.asarray(st3.q_node).max()) == 0.0
     assert float(np.asarray(st3.q_link).max()) == 0.0
+
+
+def test_effective_rates_match_the_eager_expression():
+    """The jitted healthy-path rates equal the eager ``mu * (1 / s)``."""
+    rng = np.random.default_rng(5)
+    topo = Topology(mu_node=jnp.asarray(rng.uniform(1e9, 5e11, 24),
+                                        jnp.float32),
+                    mu_link=jnp.ones((24, 24), jnp.float32))
+    for slowdown in (np.ones(24, np.float32),
+                     rng.uniform(0.5, 9.0, 24).astype(np.float32)):
+        eff = effective_topology(topo, slowdown)
+        eager = topo.mu_node * (1.0 / jnp.asarray(slowdown))
+        np.testing.assert_array_equal(np.asarray(eff.mu_node),
+                                      np.asarray(eager))
+        assert eff.mu_link is topo.mu_link
 
 
 def test_backlog_seconds_worst_resource():
