@@ -61,7 +61,8 @@ import dataclasses
 import numpy as np
 
 from . import eventsim, schedule, telemetry
-from .state import QueueState, Topology, effective_topology
+from .state import (QueueState, Topology, effective_topology,
+                    host_backlog_seconds)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -265,8 +266,7 @@ class CommittedWork:
     def backlog_seconds(self, topo: Topology) -> float:
         """Worst-resource residual wait under the exact model (see
         :func:`repro.core.state.backlog_seconds`)."""
-        from .state import backlog_seconds as _bs
-        return _bs(topo, self.queue_state())
+        return host_backlog_seconds(*host_rates(topo), *self.queue_arrays())
 
 
 def _plan_jobs(batch, plan, *, names, next_prio: int, at: float,
@@ -443,7 +443,7 @@ def _live_engine(ledger: CommittedWork, mu_node: np.ndarray,
     return eng
 
 
-def _host_rates(topo: Topology) -> tuple[np.ndarray, np.ndarray]:
+def host_rates(topo: Topology) -> tuple[np.ndarray, np.ndarray]:
     """The topology's rates as float64 host arrays (one counted fetch)."""
     mu_node, mu_link = telemetry.to_host((topo.mu_node, topo.mu_link))
     return np.asarray(mu_node, np.float64), np.asarray(mu_link, np.float64)
@@ -458,7 +458,7 @@ def warm_engine(topo: Topology, ledger: CommittedWork) -> CommittedWork:
     later commit extends it in place.
     """
     if _engine_of(ledger) is None:
-        mu_node, mu_link = _host_rates(topo)
+        mu_node, mu_link = host_rates(topo)
         _attach(ledger, _LedgerEngine(ledger, mu_node, mu_link))
     return ledger
 
@@ -482,7 +482,8 @@ def down_keys(topo: Topology, avail_node, link_up=None) -> tuple:
 
 
 def drain_exact(topo: Topology, ledger: CommittedWork, dt, *,
-                engine: str = "indexed", down: tuple = ()) -> CommittedWork:
+                engine: str = "indexed", down: tuple = (),
+                rates=None) -> CommittedWork:
     """Advance the ledger ``dt`` seconds with preempt-resume priority service.
 
     The exact counterpart of the fluid ``QueueState.advance``: every
@@ -506,6 +507,10 @@ def drain_exact(topo: Topology, ledger: CommittedWork, dt, *,
     this window* (work targeting them waits; served work stays served) —
     typically :func:`down_keys` of the scheduler's availability masks.
     Resources absent from it are restored on the persistent engine.
+
+    ``rates`` is ``topo``'s ``(mu_node, mu_link)`` as float64 host arrays,
+    where the caller holds them (the scheduler's copy of its effective
+    topology); without it they are fetched from the device.
     """
     _check_engine(engine)
     dt = float(dt)
@@ -519,7 +524,7 @@ def drain_exact(topo: Topology, ledger: CommittedWork, dt, *,
             eng.eng.now = t_end
             _attach(new, eng)
         return new
-    mu_node, mu_link = _host_rates(topo)
+    mu_node, mu_link = host_rates(topo) if rates is None else rates
     if engine == "ref":
         tasks = _tasks_of(ledger)
         schedule.run_event_loop_ref(tasks, mu_node, mu_link, t=ledger.clock,
@@ -537,8 +542,8 @@ def drain_exact(topo: Topology, ledger: CommittedWork, dt, *,
 
 
 def run_to_completion(topo: Topology, ledger: CommittedWork, *,
-                      engine: str = "indexed",
-                      down: tuple = ()) -> tuple[dict[str, float],
+                      engine: str = "indexed", down: tuple = (),
+                      rates=None) -> tuple[dict[str, float],
                                                  "CommittedWork"]:
     """Serve every committed job to completion; the ground-truth replay.
 
@@ -551,13 +556,14 @@ def run_to_completion(topo: Topology, ledger: CommittedWork, *,
 
     ``down`` resources stay failed for the whole run: a job still needing
     one can never complete, so stuck work raises — clear it first
-    (recovery policies requeue, migrate, or shed it).
+    (recovery policies requeue, migrate, or shed it).  ``rates`` as in
+    :func:`drain_exact`.
     """
     _check_engine(engine)
     completions = dict(ledger.completed)
     if not ledger.jobs:
         return completions, ledger
-    mu_node, mu_link = _host_rates(topo)
+    mu_node, mu_link = host_rates(topo) if rates is None else rates
     if engine == "ref":
         tasks = _tasks_of(ledger)
         t = schedule.run_event_loop_ref(tasks, mu_node, mu_link,
@@ -614,7 +620,7 @@ def predict_completions(topo: Topology, ledger: CommittedWork, *,
         raise ValueError(
             f"cannot score candidates at t={at} behind the ledger clock "
             f"{ledger.clock}")
-    mu_node, mu_link = _host_rates(topo)
+    mu_node, mu_link = host_rates(topo)
     seen = set(ledger.names_seen)
     next_prio = ledger.next_prio
     extras: list[LedgerJob] = []
@@ -711,15 +717,6 @@ def replay_piecewise(topo: Topology, log: CommittedWork, *,
     return run_to_completion(eff, cur, engine=engine, down=down)
 
 
-def _backlog_arrays(mu_node: np.ndarray, mu_link: np.ndarray,
-                    qn: np.ndarray, ql: np.ndarray) -> float:
-    """Worst-resource residual wait from raw numpy arrays (the host-side
-    counterpart of :func:`repro.core.state.backlog_seconds`)."""
-    node_wait = np.where(mu_node > 0, qn / np.maximum(mu_node, 1e-30), 0.0)
-    link_wait = np.where(mu_link > 0, ql / np.maximum(mu_link, 1e-30), 0.0)
-    return float(max(node_wait.max(initial=0.0), link_wait.max(initial=0.0)))
-
-
 def exact_backlog_trace(topo: Topology, log: CommittedWork, times, *,
                         engine: str = "indexed") -> np.ndarray:
     """Exact-model backlog (s) just before each epoch of a commit log.
@@ -759,7 +756,7 @@ def exact_backlog_trace(topo: Topology, log: CommittedWork, times, *,
                               engine="ref")
             out.append(cur.backlog_seconds(topo))
         return np.asarray(out, np.float64)
-    mu_node, mu_link = _host_rates(topo)
+    mu_node, mu_link = host_rates(topo)
     eng = eventsim.EventEngine(mu_node, mu_link, clock=log.clock)
     out = []
     k = 0
@@ -773,5 +770,5 @@ def exact_backlog_trace(topo: Topology, log: CommittedWork, times, *,
             eng.add_tasks([_task_of(j) for j in add])
         eng.advance(max(t, eng.now))
         qn, ql = eng.queue_arrays()
-        out.append(_backlog_arrays(mu_node, mu_link, qn, ql))
+        out.append(host_backlog_seconds(mu_node, mu_link, qn, ql))
     return np.asarray(out, np.float64)

@@ -145,18 +145,22 @@ def advance(topo: Topology, state: QueueState, dt) -> QueueState:
     )
 
 
-@telemetry.spanned("sched.backlog")
 def backlog_seconds(topo: Topology, state: QueueState) -> float:
     """Worst-resource residual wait: max over nodes/links of Q / mu (host).
 
     This is the quantity a new top-priority arrival would wait behind at the
     most backed-up resource — the scalar the online benchmarks and the
-    stability tests track over time.
+    stability tests track over time.  Fetches the four arrays in one wait;
+    :func:`host_backlog_seconds` is the same formula over host copies.
     """
+    return host_backlog_seconds(*telemetry.to_host(
+        (topo.mu_node, topo.mu_link, state.q_node, state.q_link)))
+
+
+def host_backlog_seconds(mu_node, mu_link, q_node, q_link) -> float:
+    """:func:`backlog_seconds` from host arrays, computed in float64."""
     mu_n, mu_l, q_n, q_l = (np.asarray(x, np.float64)
-                            for x in telemetry.to_host(
-                                (topo.mu_node, topo.mu_link,
-                                 state.q_node, state.q_link)))
+                            for x in (mu_node, mu_link, q_node, q_link))
     node_wait = np.where(mu_n > 0, q_n / np.maximum(mu_n, 1e-30), 0.0)
     link_wait = np.where(mu_l > 0, q_l / np.maximum(mu_l, 1e-30), 0.0)
     return float(max(node_wait.max(initial=0.0), link_wait.max(initial=0.0)))

@@ -35,7 +35,7 @@ import numpy as np
 
 from repro.core import (arrivals as A, completions as C, jobs as J, schedule,
                         telemetry)
-from repro.core.state import Topology, backlog_seconds
+from repro.core.state import Topology
 from .admission import (AdmissionController, AdmissionPolicy, ReplanMonitor,
                         ReplanPolicy)
 from .scheduler import Placement, Request, RoutedScheduler, requests_to_jobs
@@ -365,12 +365,12 @@ class OnlineScheduler(RoutedScheduler):
             if len(set(names)) != len(names):
                 raise ValueError("window job names must be unique")
         self.advance_to(t)
-        eff = self._effective_topology()
-        before = backlog_seconds(eff, self.state)
+        before = self._backlog()
         reuse, assess_s = None, 0.0
         if ctl is not None and ctl.active(jobs):
             jobs, arrs, reuse, assess_s = self._assess_admission(
-                float(t), jobs, arrs, eff, pad_to=pad_to, method=method)
+                float(t), jobs, arrs, self._effective_topology(),
+                pad_to=pad_to, method=method)
             track_wait = True
         self.trace.deadlines_by_name.update(
             {j.name: j.deadline_s for j in jobs
@@ -401,7 +401,7 @@ class OnlineScheduler(RoutedScheduler):
                                             method=method)
             self.last_solve_s += assess_s
             self.total_solve_s += assess_s
-        after = backlog_seconds(eff, self.state)
+        after = self._backlog()
         self.trace.arrivals_by_name.update(
             {j.name: a for j, a in zip(jobs, arrs)})
         self.trace.records.append(ArrivalRecord(
@@ -550,8 +550,7 @@ class OnlineScheduler(RoutedScheduler):
                 waits[w] = {j.name: float(t) - float(a)
                             for j, a in zip(jobs, arrs)}
         self.advance_to(t)
-        eff = self._effective_topology()
-        before = backlog_seconds(eff, self.state)
+        before = self._backlog()
         per_window = self.schedule_windows(windows, pad_to=pad_to,
                                            method=method)
         walls = 0.0
@@ -565,7 +564,7 @@ class OnlineScheduler(RoutedScheduler):
             # telemetry matches what W submit_window calls would have read
             # — not the solver's fluid committed queues, which differ from
             # the ledger materialization in the last ulp.
-            after = backlog_seconds(eff, self._window_states[w])
+            after = self._backlog(*self._window_states[w])
             solve_w = float(placements[0].plan.meta.get(
                 "solve_share_s", placements[0].plan.meta.get("solve_s", 0.0)))
             walls += solve_w
@@ -660,8 +659,7 @@ class OnlineScheduler(RoutedScheduler):
                     rec,
                     latencies=tuple(wait + bound_by_name[n]
                                     for n in rec.names),
-                    backlog_after=backlog_seconds(
-                        self._effective_topology(), self.state))
+                    backlog_after=self._backlog())
         return out
 
     # -- SLO guard ----------------------------------------------------------
@@ -701,7 +699,7 @@ class OnlineScheduler(RoutedScheduler):
             return None
         rec = self.trace.records[-1]
         expected = max(rec.backlog_after - (self.now - rec.time), 0.0)
-        measured = backlog_seconds(self._effective_topology(), self.state)
+        measured = self._backlog()
         return (measured - expected) / max(float(bounds.max()), 1e-9)
 
     def check_replan(self) -> bool:
@@ -722,7 +720,8 @@ class OnlineScheduler(RoutedScheduler):
             raise ValueError("finish() requires drain='exact'")
         comps, self.ledger = C.run_to_completion(
             self._effective_topology(), self.ledger,
-            engine=self.sim_engine, down=self._down_keys())
+            engine=self.sim_engine, down=self._down_keys(),
+            rates=self._eff_rates)
         self._sync_ledger_queues()
         if comps:
             self._now = max(self._now, max(comps.values()))

@@ -59,7 +59,8 @@ import numpy as np
 
 from repro.core import (completions as C, jobs as J, network as N, solvers,
                         telemetry)
-from repro.core.state import QueueState, Topology, effective_topology
+from repro.core.state import (QueueState, Topology, backlog_seconds,
+                              effective_topology, host_backlog_seconds)
 from repro.core.plan import Plan
 from repro.configs import registry
 
@@ -165,6 +166,14 @@ class RoutedScheduler:
         # compute *and* every incident link; links can also fail alone.
         self._avail_node = np.ones((self.topology.num_nodes,), bool)
         self._link_up = np.ones((self.topology.num_nodes,) * 2, bool)
+        # The health-scaled topology, built on first use after a health
+        # event, and float64 host copies of its rates: the solver, the
+        # drain and the backlog all read the device's own bits.
+        self._eff: Topology | None = None
+        self._eff_rates: tuple[np.ndarray, np.ndarray] | None = None
+        # Exact mode: float32 host copies of the ledger queues last
+        # uploaded into ``self.state`` (None while they are not current).
+        self._queues: tuple[np.ndarray, np.ndarray] | None = None
         self.drain_mode = drain
         # Live registry of committed InferenceJobs (exact mode): the fault
         # policies reconstruct residual jobs from it when a resource fails.
@@ -224,6 +233,7 @@ class RoutedScheduler:
         true segment-by-segment health history.
         """
         self._slowdown[node] = self._check_slowdown(node, factor)
+        self._eff = None
         if self.commit_log is not None:
             self.commit_log = self.commit_log.record_slowdown(
                 self._now, node, self._slowdown[node])
@@ -267,6 +277,7 @@ class RoutedScheduler:
         self._avail_node[node] = bool(up)
         if up:
             self._slowdown[node] = 1.0
+        self._eff = None
         if self.commit_log is not None:
             self.commit_log = self.commit_log.record_health(
                 self._now, node, 1.0 if up else np.inf)
@@ -284,6 +295,7 @@ class RoutedScheduler:
                 f"(mu_link[{u}, {v}] == 0); availability events apply "
                 f"to real links only")
         self._link_up[u, v] = bool(up)
+        self._eff = None
         if self.commit_log is not None:
             self.commit_log = self.commit_log.record_health(
                 self._now, ("link", u, v), 1.0 if up else np.inf)
@@ -297,19 +309,21 @@ class RoutedScheduler:
     def _drain_state(self, dt: float) -> None:
         """Advance backlogs ``dt`` seconds at effective (health-aware) rates
         under the configured drain model.  Does not move the clock."""
+        eff = self._effective_topology()
         if self.drain_mode == "exact":
-            self.ledger = C.drain_exact(self._effective_topology(),
-                                        self.ledger, dt,
+            self.ledger = C.drain_exact(eff, self.ledger, dt,
                                         engine=self.sim_engine,
-                                        down=self._down_keys())
+                                        down=self._down_keys(),
+                                        rates=self._eff_rates)
             self._sync_ledger_queues()
         else:
-            self.state = self.state.advance(self._effective_topology(), dt)
+            self.state = self.state.advance(eff, dt)
 
     def _sync_ledger_queues(self) -> None:
         """Materialize the ledger's residual work into the QueueState."""
+        self._queues = self.ledger.queue_arrays()
         self.state = self.state.with_queues(
-            *telemetry.to_device(self.ledger.queue_arrays()))
+            *telemetry.to_device(self._queues))
 
     def advance(self, dt: float) -> None:
         """Let ``dt`` seconds pass: the backlog drains at effective rates
@@ -341,6 +355,7 @@ class RoutedScheduler:
             jnp.zeros_like(self.state.q_link))
         if self.ledger is not None:
             self.ledger = self.ledger.cleared()
+        self._queues = None
         self._last = None
         self.last_plan = None
 
@@ -365,11 +380,34 @@ class RoutedScheduler:
 
     @telemetry.spanned("sched.topology")
     def _effective_topology(self) -> Topology:
-        if not self.degraded:
-            # bit-identical to the pre-fault expression (and rates)
-            return effective_topology(self.topology, self._slowdown)
-        return effective_topology(self.topology, self._slowdown,
-                                  self._avail_node, self._link_up)
+        """The health-scaled topology, rebuilt only after a health event
+        (``report_slowdown``, ``set_node_availability``,
+        ``set_link_availability``), each counted as ``topology_builds``."""
+        if self._eff is None:
+            telemetry.count("topology_builds")
+            if not self.degraded:
+                # bit-identical to the pre-fault expression (and rates)
+                eff = effective_topology(self.topology, self._slowdown)
+            else:
+                eff = effective_topology(self.topology, self._slowdown,
+                                         self._avail_node, self._link_up)
+            self._eff_rates = C.host_rates(eff)
+            self._eff = eff
+        return self._eff
+
+    @telemetry.spanned("sched.backlog")
+    def _backlog(self, state: QueueState | None = None,
+                 queues: tuple[np.ndarray, np.ndarray] | None = None
+                 ) -> float:
+        """``backlog_seconds`` of ``state`` (default: the live state and
+        its ledger queues) at the effective rates.  Computed from the host
+        copies where the queues have one, else fetched from the device."""
+        eff = self._effective_topology()
+        if state is None:
+            state, queues = self.state, self._queues
+        if queues is None:
+            return backlog_seconds(eff, state)
+        return host_backlog_seconds(*self._eff_rates, *queues)
 
     # -- placement ----------------------------------------------------------
     def _placements(self, plan: Plan,
@@ -474,12 +512,12 @@ class RoutedScheduler:
         current state — the second half of :meth:`schedule_jobs`."""
         pre_state = self.state
         pre_ledger, pre_log = self.ledger, self.commit_log
-        plan = self._commit_plan(self._effective_topology(), batch, plan,
-                                 pre_state, [j.name for j in infer_jobs])
+        topo = self._effective_topology()
+        plan = self._commit_plan(topo, batch, plan, pre_state,
+                                 [j.name for j in infer_jobs])
         # Record only after the commit succeeds, so a raising solver can't
         # poison replan_last() with a batch that was never scheduled.
-        self._last = (batch, infer_jobs, pre_state,
-                      self._effective_topology(), self._now,
+        self._last = (batch, infer_jobs, pre_state, topo, self._now,
                       pre_ledger, pre_log)
         if self.ledger is not None:
             # Fault policies rebuild residual jobs from this registry;
@@ -531,7 +569,7 @@ class RoutedScheduler:
             for jobs in windows:
                 out.append(self.schedule_jobs(jobs, pad_to=pad_to,
                                               method=method))
-                self._window_states.append(self.state)
+                self._window_states.append((self.state, self._queues))
             return out
         topo = self._effective_topology()
         with telemetry.span("sched.batch"):
@@ -542,10 +580,10 @@ class RoutedScheduler:
         plans = solvers.solve_fused(topo, batches, state=self.state,
                                     pad_to=pad_to, **opts)
         out = []
-        # Per-window post-commit queue snapshots: after _commit_plan,
-        # self.state is authoritative (ledger-synced in exact mode, plan
-        # queues in fluid), so telemetry reading these matches what W
-        # sequential schedule_jobs calls would have recorded.
+        # Per-window post-commit queue snapshots, (state, host queues):
+        # after _commit_plan, self.state is authoritative (ledger-synced in
+        # exact mode, plan queues in fluid), so telemetry reading these
+        # matches what W sequential schedule_jobs calls would have recorded.
         self._window_states = []
         for jobs, batch, plan in zip(windows, batches, plans):
             pre_state = self.state
@@ -557,7 +595,7 @@ class RoutedScheduler:
                 for j in jobs:
                     self.inflight_jobs[j.name] = j
             out.append(self._placements(plan, jobs))
-            self._window_states.append(self.state)
+            self._window_states.append((self.state, self._queues))
         return out
 
     def warmup(self, sample_jobs: list[J.InferenceJob],
@@ -658,7 +696,7 @@ class RoutedScheduler:
         # Everything is computed locally first: a declined replan (the
         # min_improvement gate) must leave the scheduler untouched.
         elapsed = self._now - pre_now
-        ledger = None
+        ledger = queues = None
         if self.drain_mode == "exact":
             ledger = pre_ledger
             if elapsed > 0 and self.drain_queues:
@@ -667,8 +705,8 @@ class RoutedScheduler:
                 # index lazily from the snapshot's immutable job records.
                 ledger = C.drain_exact(pre_topo, ledger, elapsed,
                                        engine=self.sim_engine)
-            qn, ql = ledger.queue_arrays()
-            state = pre_state.with_queues(jnp.asarray(qn), jnp.asarray(ql))
+            queues = ledger.queue_arrays()
+            state = pre_state.with_queues(*map(jnp.asarray, queues))
         else:
             state = pre_state
             if elapsed > 0 and self.drain_queues:
@@ -699,7 +737,7 @@ class RoutedScheduler:
                 return None
         # Committing: apply the rollback, then the new plan.
         self.ledger = ledger if self.drain_mode == "exact" else self.ledger
-        self.state = state
+        self.state, self._queues = state, queues
         # The superseded batch never ran to completion: drop it from the
         # ground-truth record too (same approximation as the state rollback)
         # — but keep the full health history, which rollback cannot undo.

@@ -1,9 +1,14 @@
 """Serving: routed scheduler behaviour (straggler avoidance, queue-aware
 spreading) and the decode engine end-to-end."""
+import dataclasses
+
 import numpy as np
+import pytest
 
 from repro.core import network as N
-from repro.serving.scheduler import Request, RoutedScheduler
+from repro.scenarios import make_scenario
+from repro.serving.scheduler import (Request, RoutedScheduler,
+                                     requests_to_jobs)
 
 
 def _cluster():
@@ -169,3 +174,171 @@ def test_scheduler_advance_drains_queues():
     assert float(np.asarray(sched.state.q_node).max()) == 0.0
     assert float(np.asarray(sched.state.q_link).max()) == 0.0
     assert sched.clock > 0
+
+
+# -- the cached effective topology and the host copies ----------------------
+
+def _bits(*arrays):
+    return [np.asarray(a).tobytes() for a in arrays]
+
+
+def _fresh_topology(sched):
+    """The effective topology built anew from the scheduler's health."""
+    from repro.core.state import effective_topology
+    masks = (sched._avail_node, sched._link_up) if sched.degraded else ()
+    return effective_topology(sched.topology, sched._slowdown, *masks)
+
+
+# (the state before the event, the event): node 2 and link 1->2 of _cluster
+HEALTH_EVENTS = {
+    "slowdown": ((), ("report_slowdown", 2, 2.5)),
+    "recovery": (("report_slowdown", 2, 2.5), ("report_recovery", 2)),
+    "node-down": ((), ("set_node_availability", 2, False)),
+    "node-up": (("set_node_availability", 2, False),
+                ("set_node_availability", 2, True)),
+    "link-down": ((), ("set_link_availability", 1, 2, False)),
+    "link-up": (("set_link_availability", 1, 2, False),
+                ("set_link_availability", 1, 2, True)),
+}
+
+
+@pytest.mark.parametrize("track_commits", [False, True],
+                         ids=["no-log", "commit-log"])
+@pytest.mark.parametrize("event", list(HEALTH_EVENTS))
+def test_health_event_rebuilds_the_effective_topology(event, track_commits,
+                                                      monkeypatch):
+    """Each health mutator drops the cached topology; the next use builds
+    it once, bit-identical to a fresh build, with host rates equal to its
+    fetch, and the very next drain reads them."""
+    from repro.core import completions as C, telemetry
+    sched = RoutedScheduler(_cluster(), drain="exact",
+                            track_commits=track_commits)
+    sched.schedule([Request("smollm_135m", 0, 5, name=f"r{i}")
+                    for i in range(3)])
+    before, (name, *args) = HEALTH_EVENTS[event]
+    if before:
+        getattr(sched, before[0])(*before[1:])
+    cached = sched._effective_topology()
+    built = telemetry.counter("topology_builds")
+    assert sched._effective_topology() is cached
+    getattr(sched, name)(*args)
+    eff = sched._effective_topology()
+    assert eff is not cached
+    assert sched._effective_topology() is eff
+    assert telemetry.counter("topology_builds") == built + 1
+    fresh = _fresh_topology(sched)
+    assert (_bits(eff.mu_node, eff.mu_link)
+            == _bits(fresh.mu_node, fresh.mu_link))
+    assert _bits(*sched._eff_rates) == _bits(*C.host_rates(fresh))
+    seen = []
+    drain = C.drain_exact
+
+    def spy(*args, rates, **kwargs):
+        seen.append(rates)
+        return drain(*args, rates=rates, **kwargs)
+
+    monkeypatch.setattr(C, "drain_exact", spy)
+    sched.advance(1e-3)
+    assert len(seen) == 1 and _bits(*seen[0]) == _bits(*C.host_rates(fresh))
+
+
+def test_a_healthy_stream_builds_the_topology_once():
+    from repro.core import telemetry
+    from repro.serving.online import OnlineScheduler
+    sched = OnlineScheduler(_cluster().topology, drain="exact")
+    built = telemetry.counter("topology_builds")
+    for w in range(5):
+        sched.submit_window(0.01 * (w + 1),
+                            requests_to_jobs([Request("smollm_135m", 0, 5,
+                                                      name=f"r{w}")]))
+    assert telemetry.counter("topology_builds") == built + 1
+
+
+def _serve(cls, per_window: int, drain: str, events):
+    """Eight served windows on USNET, ``events(sched, w, t)`` before each;
+    a window every mean gap of a single request, so backlogs carry over."""
+    sc = make_scenario("us-backbone:paper", seed=0)
+    rng = np.random.default_rng(5)
+    sched = cls(sc.topology, method="greedy", drain=drain,
+                sim_engine="indexed" if per_window == 1 else "ref")
+    gap = 1 / sc.nominal_rate(0.8)
+    for w in range(8):
+        t = (w + 1) * gap
+        events(sched, w, t - gap / 2)
+        sched.submit_window(t, sc.sample_jobs(rng, per_window),
+                            pad_to=sc.max_layers)
+    comps = sched.finish() if drain == "exact" else {}
+    return sched, comps
+
+
+def _health(sched, w, t):
+    """A slowdown, a node down and up, a link down and up, a recovery."""
+    link = (1, 2)     # USNET link between two nodes no request ends at
+    if w == 2:
+        sched.report_slowdown(7, 3.0, at=t)
+    elif w == 3:
+        sched.set_node_availability(12, False, at=t)
+    elif w in (4, 6):
+        for u, v in (link, link[::-1]):
+            sched.set_link_availability(u, v, w == 6, at=t)
+        if w == 6:
+            sched.report_recovery(7, at=t)
+    elif w == 5:
+        sched.set_node_availability(12, True, at=t)
+
+
+def _replan(sched, w, t):
+    if w == 3:
+        sched.report_slowdown(7, 4.0, at=t)
+        assert sched.replan_last() is not None
+
+
+STREAMS = {
+    "b1-exact-health": (1, "exact", _health),
+    "b32-exact-health": (32, "exact", _health),
+    "b1-exact-replan": (1, "exact", _replan),
+    "b32-fluid-health": (32, "fluid", _health),
+}
+
+
+@pytest.mark.parametrize("stream", list(STREAMS))
+def test_backlog_from_host_copies_matches_the_fetch(stream):
+    """Every backlog the scheduler reads from its host copies equals the
+    fetching ``backlog_seconds`` bit for bit, and the stream's records and
+    completions equal those of a scheduler that rebuilds the effective
+    topology at every use and fetches every backlog (as it did before the
+    copies were kept).  Fluid mode holds no queue copy and fetches."""
+    from repro.core.state import backlog_seconds
+    from repro.serving.online import OnlineScheduler
+
+    class Checked(OnlineScheduler):
+        reads = mirrored = 0
+
+        def _backlog(self, state=None, queues=None):
+            got = super()._backlog(state, queues)
+            if state is None:
+                state, queues = self.state, self._queues
+            assert got == backlog_seconds(_fresh_topology(self), state)
+            self.reads += 1
+            self.mirrored += queues is not None
+            return got
+
+    class Rebuilding(OnlineScheduler):
+        def _effective_topology(self):
+            self._eff = None
+            return super()._effective_topology()
+
+        def _backlog(self, state=None, queues=None):
+            return backlog_seconds(self._effective_topology(),
+                                   self.state if state is None else state)
+
+    per_window, drain, events = STREAMS[stream]
+    got, got_done = _serve(Checked, per_window, drain, events)
+    want, want_done = _serve(Rebuilding, per_window, drain, events)
+    assert got.reads >= 16
+    assert got.mirrored == (got.reads if drain == "exact" else 0)
+    strip = [dataclasses.replace(r, solve_s=0.0) for r in got.trace.records]
+    assert strip == [dataclasses.replace(r, solve_s=0.0)
+                     for r in want.trace.records]
+    assert any(r.backlog_before > 0 for r in strip)
+    assert got_done == want_done

@@ -145,6 +145,18 @@ def test_every_transfer_is_counted(served, monkeypatch):
     assert telemetry.counter("d2h") > d2h
 
 
+def test_a_healthy_batch_moves_only_its_own_data(served):
+    """After warm-up a healthy window builds no effective topology and
+    fetches no rate or queue the host holds: per batch 6 waits (the
+    staging's, the path post-pass's and the ledger's fetches of the batch,
+    the scan's two fetches, the walk's) and 6 uploads (the batch, the
+    dedupe plan, the path operands, two queue syncs, the clock)."""
+    n = 3
+    counters = _recorded(served, n)["counters"]
+    assert counters.get("topology_builds", 0) == 0
+    assert (counters["d2h"], counters["h2d"]) == (6 * n, 6 * n)
+
+
 def _batch(sc, n, seed=0):
     return J.batch_jobs(sc.sample_jobs(np.random.default_rng(seed), n),
                         pad_to=sc.max_layers)
