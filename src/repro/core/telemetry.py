@@ -23,7 +23,9 @@ nothing is written anywhere until then.
   signature (jit cache misses, eager ops included), only while on.
 * :func:`to_device` and :func:`to_host` are the served path's host<->device
   transfers, counted as ``h2d`` and ``d2h`` (one per call, which may carry
-  a pytree; a ``d2h`` is one wait for the device).
+  a pytree; a ``d2h`` is one wait for the device), and their bytes as
+  ``h2d_bytes`` and ``d2h_bytes`` (the summed ``nbytes`` of the pytree's
+  array leaves).
 """
 from __future__ import annotations
 
@@ -110,15 +112,25 @@ def counter(name: str) -> int:
     return _COUNTS[name]
 
 
+def _nbytes(x) -> int:
+    """Summed ``nbytes`` of the array leaves of the pytree ``x`` (as size
+    times item size: a jax array's ``nbytes`` costs a few times more)."""
+    return sum(leaf.size * leaf.dtype.itemsize
+               for leaf in jax.tree_util.tree_leaves(x)
+               if hasattr(leaf, "dtype"))
+
+
 def to_device(x):
-    """``jax.device_put(x)``, counted as one ``h2d``."""
+    """``jax.device_put(x)``, counted as one ``h2d`` of its bytes."""
     _COUNTS["h2d"] += 1
+    _COUNTS["h2d_bytes"] += _nbytes(x)
     return _device_put(x)
 
 
 def to_host(x):
-    """``jax.device_get(x)``, counted as one ``d2h``."""
+    """``jax.device_get(x)``, counted as one ``d2h`` of its bytes."""
     _COUNTS["d2h"] += 1
+    _COUNTS["d2h_bytes"] += _nbytes(x)
     return _device_get(x)
 
 

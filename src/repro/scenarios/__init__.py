@@ -18,6 +18,7 @@ Catalog (see ``available_scenarios()``):
   edge-cloud         lm                edge sites -> aggregation -> cloud
   random-geometric   synthetic         seeded geometric mesh
   star               synthetic         cellular hub-and-spoke
+  fat-tree           paper             k=8 fat-tree fabric, V=208 (Al-Fares)
 """
 from __future__ import annotations
 
@@ -38,6 +39,7 @@ _DEFAULT_TRAFFIC = {
     "edge-cloud": "lm",
     "random-geometric": "synthetic",
     "star": "synthetic",
+    "fat-tree": "paper",
 }
 
 
@@ -109,13 +111,17 @@ class Scenario:
         compute *and* transfers — so offered-load calibration respects
         whichever resource actually bottlenecks the scenario.
         """
-        from repro.core import routing
+        from repro.core import routing, shortest_path
         rng = np.random.default_rng(self.seed + 0x5EED)
         # 32 samples: enough that a lopsided mix (rare-but-heavy entries)
         # doesn't under-estimate the mean and mis-calibrate offered load.
+        # Jobs sharing a data-size vector share one closure stack (the
+        # deduped build is bit-identical to a per-job one).
         batch = J.batch_jobs(self.sample_jobs(rng, 32))
-        costs = np.asarray(routing.route_batch(self.topology.view(),
-                                               batch).cost, np.float64)
+        net = self.topology.view()
+        closures = shortest_path.build_closures_batch(net, batch)
+        costs = np.asarray(routing.route_batch(
+            net, batch, closures=closures).cost, np.float64)
         return float(costs.mean())
 
     def nominal_rate(self, load: float) -> float:

@@ -3,7 +3,8 @@
 Every generator returns ``(net, names, ingress, egress)`` where ``net`` is
 a fresh :class:`~repro.core.network.ComputeNetwork` (empty queues), and
 ingress/egress are the node sets traffic enters/leaves through.  All
-generators are deterministic in ``seed``.
+generators are deterministic in ``seed`` (``fat_tree`` has no random part
+and ignores it).
 
 Families:
   * ``paper_small``      — the paper's 5-node Fig. 2 topology.
@@ -16,6 +17,8 @@ Families:
                            component; heterogeneous compute.
   * ``star``             — cellular: one hub with fat compute, leaves with
                            thin local compute and mixed-rate uplinks.
+  * ``fat_tree``         — the k-ary fat-tree datacenter fabric of Al-Fares
+                           et al. (SIGCOMM 2008, §3); k=8 gives V=208.
 """
 from __future__ import annotations
 
@@ -129,10 +132,68 @@ def star(seed: int = 0, *, num_leaves: int = 8, capacity_scale: float = 1e-3):
     return net, names, leaves, leaves
 
 
+def fat_tree(seed: int = 0, *, k: int = 8, capacity_scale: float = 1e-3):
+    """The k-ary fat-tree of Al-Fares, Loukissas and Vahdat (SIGCOMM 2008,
+    §3): k pods of k/2 aggregation and k/2 edge switches, (k/2)^2 core
+    switches and k^3/4 hosts, every link 125 MB/s (1 GbE commodity links,
+    times ``capacity_scale``), bidirectional.
+
+    Each edge switch links to k/2 hosts and to every aggregation switch of
+    its pod; aggregation switch ``a`` of every pod links to core switches
+    ``a*k/2 .. a*k/2 + k/2 - 1`` (one port per pod on each core switch).
+
+    Node order: the (k/2)^2 core switches; then pod by pod its k/2
+    aggregation switches followed by its k/2 edge switches; then the
+    hosts in edge-switch order (host ``h`` hangs off edge switch
+    ``h // (k/2)`` counted across pods).  Switches carry no compute; host
+    ``h`` gets 30, 50, 200, 100, 70 GFLOP/s cycled by ``h`` (arXiv:2111.07006's
+    values, as ``us_backbone`` cycles them).  Ingress is the first host
+    of each pod and egress the last, so k*k ordered pairs, k of them
+    within a pod.  The fabric has no random part: ``seed`` is ignored.
+    """
+    if k < 2 or k % 2:
+        raise ValueError(f"fat_tree needs an even k >= 2, got {k}")
+    half = k // 2
+    n_core, n_pod_sw, n_host = half * half, k * k, k * half * half
+    v = n_core + n_pod_sw + n_host
+    cap = 125 * MB * capacity_scale
+
+    def agg(p, a):
+        return n_core + p * k + a
+
+    def edge(p, e):
+        return n_core + p * k + half + e
+
+    def host(h):
+        return n_core + n_pod_sw + h
+
+    edges = []
+    for p in range(k):
+        for a in range(half):
+            edges += [(agg(p, a), a * half + c, cap) for c in range(half)]
+            edges += [(agg(p, a), edge(p, e), cap) for e in range(half)]
+        for e in range(half):
+            first = (p * half + e) * half
+            edges += [(edge(p, e), host(first + i), cap) for i in range(half)]
+    caps_cycle = [30, 50, 200, 100, 70]
+    caps = [0.0] * (n_core + n_pod_sw) \
+        + [caps_cycle[h % 5] * G for h in range(n_host)]
+    names = [f"core{c}" for c in range(n_core)]
+    for p in range(k):
+        names += [f"agg{p}.{a}" for a in range(half)]
+        names += [f"edge{p}.{e}" for e in range(half)]
+    names += [f"host{h}" for h in range(n_host)]
+    per_pod = half * half
+    ingress = [host(p * per_pod) for p in range(k)]
+    egress = [host(p * per_pod + per_pod - 1) for p in range(k)]
+    return N.make_network(v, edges, caps), names, ingress, egress
+
+
 FAMILIES = {
     "paper-small": paper_small,
     "us-backbone": us_backbone,
     "edge-cloud": edge_cloud,
     "random-geometric": random_geometric,
     "star": star,
+    "fat-tree": fat_tree,
 }

@@ -61,6 +61,21 @@ def test_fused_extract_paths_matches_ref(with_queues):
     assert set(fused.paths) == set(range(batch.num_jobs))
 
 
+def test_fused_extract_paths_matches_ref_on_a_fat_tree():
+    """A k=4 fat-tree (V=36) with a 4-job paper window: uniform links give
+    every pair of pods four equal-cost paths, so hop ties are everywhere;
+    the fused solve still equals the reference bit for bit."""
+    sc = make_scenario("fat-tree:paper", seed=0, k=4)
+    net = sc.topology.view()
+    jobs = sc.sample_jobs(np.random.default_rng(15), 4)
+    batch = J.batch_jobs(jobs, pad_to=sc.max_layers)
+    fused = greedy.greedy_route(net, batch, extract_paths=True)
+    ref = greedy.greedy_route_ref(net, batch, extract_paths=True)
+    _assert_plans_bitwise(fused, ref, paths=True)
+    hops = [len(h) for p in fused.paths.values() for h in p]
+    assert max(hops) <= 6
+
+
 def test_fused_dedupe_rows_bit_identical():
     """Duplicate data rows (the dedupe fast path) keep bit-identity."""
     rng = np.random.default_rng(20)

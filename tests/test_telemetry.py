@@ -157,6 +157,35 @@ def test_a_healthy_batch_moves_only_its_own_data(served):
     assert (counters["d2h"], counters["h2d"]) == (6 * n, 6 * n)
 
 
+def _tree():
+    """A pytree of 48 + 20 + 4 = 72 bytes of array leaves."""
+    return {"a": np.zeros((3, 4), np.float32),
+            "b": (np.arange(5, dtype=np.int32), np.float32(2.0))}
+
+
+def test_transfer_bytes_are_the_leaves_nbytes():
+    up, down = telemetry.counter("h2d_bytes"), telemetry.counter("d2h_bytes")
+    on_device = telemetry.to_device(_tree())
+    assert telemetry.counter("h2d_bytes") - up == 72
+    back = telemetry.to_host(on_device)
+    assert telemetry.counter("d2h_bytes") - down == 72
+    assert sum(x.nbytes for x in jax.tree_util.tree_leaves(back)) == 72
+
+
+def test_recorded_transfer_bytes_are_those_moved_while_on():
+    telemetry.disable()
+    telemetry.to_device(_tree())
+    telemetry.enable()
+    try:
+        telemetry.to_host(telemetry.to_device(_tree()["a"]))
+    finally:
+        telemetry.disable()
+    telemetry.to_host(telemetry.to_device(_tree()))
+    counters = telemetry.snapshot()["counters"]
+    assert (counters["h2d_bytes"], counters["d2h_bytes"]) == (48, 48)
+    assert (counters["h2d"], counters["d2h"]) == (1, 1)
+
+
 def _batch(sc, n, seed=0):
     return J.batch_jobs(sc.sample_jobs(np.random.default_rng(seed), n),
                         pad_to=sc.max_layers)
