@@ -7,11 +7,10 @@ for the current queues (through the two-level dedupe of
 scalars), every job is routed against it (a vmapped batch of single-job
 DPs), the earliest-finishing unrouted job takes the next priority slot, and
 its load is committed to the queues — all on device, exactly one dispatch
-per solve and one host sync for the results.  ``extract_paths=True`` adds
-one batched post-pass (``_paths_post``) that replays the reference path
-extraction against the scan's own per-round queue snapshots and closure
-stacks — see the note there on the FMA-proof edge-weight form that keeps
-it bit-identical to ``greedy_route_ref``.
+per solve and one host sync for the results.  The commit walks the chosen
+job's per-layer transfer paths to charge the link queues; the scan emits
+those hops, so ``extract_paths=True`` only formats them on the host — the
+same walk, on the same operands, that ``greedy_route_ref`` runs per round.
 
 :func:`greedy_route_ref` keeps the previous host-driven round loop (one
 closure build + one jitted round per priority level, with per-round
@@ -34,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .network import INF, ComputeNetwork, link_invrate
+from .network import ComputeNetwork
 from .jobs import JobBatch
 from .plan import Plan
 from . import routing
@@ -104,10 +103,10 @@ def _fused_rounds(net0: ComputeNetwork, batch: JobBatch,
     so live rounds are bit-identical to the unguarded loop) and the
     emitted job index is -1.
 
-    Besides (job, cost, assign) each round also emits its pre-commit link
-    queues and the chosen job's closure stack — the inputs
-    :func:`_paths_post` needs to replay the reference path extraction
-    without re-running the solve.
+    Besides (job, cost, assign) each round emits the hops its commit
+    charged (``[Lmax+1, V, 2]`` int32, see ``routing.commit_with_hops``):
+    they are ``plan.paths`` of the round's job, so no per-round ``[V, V]``
+    snapshot leaves the device and no second walk runs.
     """
     J = batch.num_jobs
 
@@ -129,7 +128,7 @@ def _fused_rounds(net0: ComputeNetwork, batch: JobBatch,
         assign_j = routing.assign_from_backpointers(total[j], bps[j])
         any_left = jnp.any(~routed)
         with jax.named_scope("commit"):
-            net2 = routing.commit_assignment(
+            net2, hops_j = routing.commit_with_hops(
                 cur, batch.comp[j], batch.data[j], batch.src[j],
                 batch.dst[j], batch.num_layers[j], assign_j,
                 closures=cl.job(j))
@@ -137,7 +136,7 @@ def _fused_rounds(net0: ComputeNetwork, batch: JobBatch,
         ql2 = jnp.where(any_left, net2.q_link, q_link)
         out_j = jnp.where(any_left, j, jnp.int32(-1))
         return ((qn2, ql2, routed.at[j].set(True)),
-                (out_j, cost[j], assign_j, q_link, cl.t[j]))
+                (out_j, cost[j], assign_j, hops_j))
 
     (q_node, q_link, _), ys = jax.lax.scan(
         body, (net0.q_node, net0.q_link, routed0), None, length=J)
@@ -171,87 +170,19 @@ def _fused_solve_many(net: ComputeNetwork, batches: JobBatch,
 
 
 def _fused_meta(J: int, *, rounds: int, windows: int = 1,
-                compiled: bool = False, paths: bool = False) -> dict:
+                compiled: bool = False) -> dict:
     # n_routings/rounds_per_dispatch report the *padded* scan work (what
     # the device actually ran), "jobs" the real window size.
     return {"n_routings": rounds * rounds, "jobs": J, "fused": True,
             "dispatches": 1, "rounds_per_dispatch": windows * rounds,
-            "windows_per_dispatch": windows, "path_dispatches": int(paths),
-            "jit_compiled": bool(compiled)}
+            "windows_per_dispatch": windows, "jit_compiled": bool(compiled)}
 
 
-@functools.partial(jax.jit, static_argnames=("max_hops",))
-def _walk_paths(data: jax.Array, ql_pre: jax.Array, inv: jax.Array,
-                t: jax.Array, starts: jax.Array, ends: jax.Array,
-                *, max_hops: int) -> jax.Array:
-    """[P, Lmax+1] batched path walks -> hops [P, Lmax+1, max_hops, 2].
-
-    Rebuilds the per-round edge weights in the same program as the walks
-    (one dispatch instead of a chain of eager ops feeding a jit call).
-    The ``(d + Q) * inv`` expression matches
-    ``shortest_path.layer_edge_weights`` exactly — its last rounding is
-    the multiply, so it is contraction-proof in any program context and
-    the weights stay bit-identical to the reference extraction's.
-    """
-    w = jnp.minimum((data[:, :, None, None] + ql_pre[:, None]) * inv, INF)
-    fn = functools.partial(routing.reconstruct_path, max_hops=max_hops)
-    return jax.vmap(jax.vmap(fn))(w, t, starts, ends)
-
-
-def _paths_post(net0: ComputeNetwork, batch: JobBatch, order, assigns,
-                ql_pre, t_sel, num_layers_h) -> dict[int, list]:
-    """One batched post-pass: ``plan.paths`` for every round of a solve.
-
-    Replays exactly what :func:`greedy_route_ref` does per round
-    (``routing.extract_paths`` against the pre-commit queues) from the
-    scan's emitted snapshots: per-round link queues ``ql_pre [P, V, V]``
-    and the committed job's closure stack ``t_sel [P, Lmax+1, V, V]``.
-
-    The edge weights are rebuilt inside :func:`_walk_paths` (one jit
-    dispatch for weights + walks) with ``layer_edge_weights``'s exact
-    ``(d + Q) * inv`` expression against each round's pre-commit queues —
-    that form's last rounding is the multiply, so LLVM cannot contract it
-    into an FMA and the rebuild is bit-identical to the reference
-    extraction's weights under every program context.  ``t`` (no
-    contractible pattern) is taken from the scan and matches the
-    reference's jit-built closures bit-for-bit.
-    """
-    order = np.asarray(order)
-    if order.size == 0:
-        return {}
-    L_sel = np.asarray(num_layers_h)[order]
-    operands = _walk_operands(net0, batch, order, assigns, ql_pre, t_sel,
-                              L_sel)
-    telemetry.count("walk_dispatches")
-    hops = telemetry.to_host(_walk_paths(*operands,
-                                         max_hops=net0.num_nodes))
-    return {int(j): routing.hops_to_paths(hops[p], int(L_sel[p]))
+def _round_paths(order, hops, num_layers) -> dict[int, list]:
+    """``plan.paths`` of a solve's kept rounds: round ``p`` committed job
+    ``order[p]`` along ``hops[p]``."""
+    return {int(j): routing.hops_to_paths(hops[p], num_layers[j])
             for p, j in enumerate(order)}
-
-
-def _walk_operands(net0: ComputeNetwork, batch: JobBatch, order, assigns,
-                   ql_pre, t_sel, L_sel) -> tuple:
-    """:func:`_walk_paths`'s operands for the rounds in ``order`` (whose
-    jobs have ``L_sel`` layers), staged on the device."""
-    assigns = np.asarray(assigns)
-    lmax = batch.max_layers
-    src_h, dst_h, data_h = telemetry.to_host((batch.src, batch.dst,
-                                              batch.data))
-    src_sel, dst_sel = src_h[order], dst_h[order]
-    # Per-layer walk endpoints: node_l -> node_{l+1} with node_0 = src and
-    # dst from layer num_layers on (layers past num_layers are dropped by
-    # the formatter, their walks are dead weight in the batched call).
-    starts = np.concatenate([src_sel[:, None], assigns], 1).astype(np.int32)
-    ends = np.concatenate([assigns, dst_sel[:, None]], 1)
-    ends = np.where(np.arange(lmax + 1)[None, :] >= L_sel[:, None],
-                    dst_sel[:, None], ends).astype(np.int32)
-
-    # An explicit, counted transfer (not jnp.asarray): the solver path
-    # stays clean under jax.transfer_guard("disallow") (the runtime
-    # complement of lint rule RL003; see tests/test_telemetry.py).
-    data_d, ql_d, t_d, starts_d, ends_d = telemetry.to_device(
-        (data_h[order], ql_pre, t_sel, starts, ends))
-    return data_d, ql_d, link_invrate(net0), t_d, starts_d, ends_d
 
 
 def _assemble_plan(batch: JobBatch, net: ComputeNetwork, order, costs,
@@ -277,10 +208,10 @@ def greedy_route(net: ComputeNetwork, batch: JobBatch,
     ``lazy=True`` and ``share_closures=False`` delegate to it (the lazy
     probe loop is host-driven by design, and no-reuse mode exists only to
     benchmark the closure-reuse win).  ``extract_paths=True`` fills
-    ``plan.paths`` in one batched post-pass over the scan's emitted
-    snapshots (see :func:`_paths_post`).  ``plan.meta`` reports the
+    ``plan.paths`` from the hops each round's commit charged, fetched in
+    the same host sync as the results.  ``plan.meta`` reports the
     fused-dispatch accounting (``fused``/``dispatches``/
-    ``rounds_per_dispatch``/``path_dispatches``) plus ``jit_compiled`` —
+    ``rounds_per_dispatch``) plus ``jit_compiled`` —
     True when this call traced+compiled a new shape signature, the wall
     the serving warm-up exists to keep out of latency models.
     """
@@ -295,28 +226,23 @@ def greedy_route(net: ComputeNetwork, batch: JobBatch,
         out, compiled = telemetry.call_counted(
             "fused_dispatches", _fused_solve, net, padded, dplan, routed0,
             use_pallas=use_pallas)
-    (order, costs, assigns, ql_pre, t_sel), q_node, q_link = out
+    (order, costs, assigns, hops), q_node, q_link = out
     with telemetry.span("greedy.fetch"):
-        order, costs, assigns, num_layers_h = telemetry.to_host(
-            (order, costs, assigns, batch.num_layers))
-        if extract_paths:
-            # host copies before mask-slicing: indexing a device array
-            # with a numpy mask is an implicit h2d of the indices
-            ql_h, t_h = telemetry.to_host((ql_pre, t_sel))
+        order, costs, assigns, num_layers_h, hops = telemetry.to_host(
+            (order, costs, assigns, batch.num_layers,
+             hops if extract_paths else None))
     # drop padding rounds; every round is real in the common unpadded
     # serving case, where the mask gathers would be pure eager overhead
     keep = slice(None) if (order >= 0).all() else order >= 0
     paths = None
     if extract_paths:
         with telemetry.span("greedy.paths"):
-            paths = _paths_post(net, batch, order[keep], assigns[keep],
-                                ql_h[keep], t_h[keep], num_layers_h)
+            paths = _round_paths(order[keep], hops[keep], num_layers_h)
     with telemetry.span("greedy.assemble"):
         return _assemble_plan(
             batch, net.with_queues(q_node, q_link), order[keep],
             costs[keep], assigns[keep], paths,
-            meta=_fused_meta(J, rounds=padded.num_jobs, compiled=compiled,
-                             paths=extract_paths))
+            meta=_fused_meta(J, rounds=padded.num_jobs, compiled=compiled))
 
 
 def _stage_window(batch: JobBatch) -> tuple:
@@ -436,15 +362,15 @@ def greedy_route_windows(net: ComputeNetwork, batches: list[JobBatch],
         outs, compiled = telemetry.call_counted(
             "fused_dispatches", _fused_solve_many, net, stacked, dplans,
             valid, use_pallas=use_pallas)
-    (orders, costs, assigns, ql_pre, t_sel), q_nodes, q_links = outs
+    (orders, costs, assigns, hops), q_nodes, q_links = outs
     with telemetry.span("greedy.fetch"):
         # host copies: per-window numpy indexing is free, while indexing
         # the device arrays with python ints / numpy masks would
         # implicitly stage the indices
-        orders, costs, assigns, q_nodes, q_links = telemetry.to_host(
-            (orders, costs, assigns, q_nodes, q_links))
-        if extract_paths:
-            ql_pre, t_sel = telemetry.to_host((ql_pre, t_sel))
+        orders, costs, assigns, q_nodes, q_links, num_layers, hops = (
+            telemetry.to_host((orders, costs, assigns, q_nodes, q_links,
+                               stacked.num_layers,
+                               hops if extract_paths else None)))
     plans = []
     for w, batch in enumerate(batches):
         J = batch.num_jobs
@@ -453,17 +379,14 @@ def greedy_route_windows(net: ComputeNetwork, batches: list[JobBatch],
         paths = None
         if extract_paths:
             with telemetry.span("greedy.paths"):
-                paths = _paths_post(
-                    net, padded[w], order_w, assigns[w][keep],
-                    ql_pre[w][keep], t_sel[w][keep],
-                    telemetry.to_host(padded[w].num_layers))
+                paths = _round_paths(order_w, hops[w][keep], num_layers[w])
         with telemetry.span("greedy.assemble"):
             plans.append(_assemble_plan(
                 batch, net.with_queues(*telemetry.to_device(
                     (q_nodes[w], q_links[w]))), order_w,
                 costs[w][keep], assigns[w][keep], paths,
                 meta=_fused_meta(J, rounds=j_max, windows=len(batches),
-                                 compiled=compiled, paths=extract_paths)))
+                                 compiled=compiled)))
     return plans
 
 

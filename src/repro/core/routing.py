@@ -294,9 +294,10 @@ def commit_with_hops(net: ComputeNetwork, comp: jax.Array, data: jax.Array,
     transfer hops the commit charged, padded with (-1, -1); exactly the
     rows :func:`reconstruct_path` walks, so formatting them with
     :func:`hops_to_paths` reproduces :func:`extract_paths` without a
-    second reconstruction.  Not jitted here: the fused solver traces it
-    inside its own program (jitting at this level would just add a
-    dispatch for eager callers, who should prefer ``commit_assignment``).
+    second reconstruction.  The fused solver's round scan commits through
+    here and emits the hops as ``plan.paths``.  Not jitted: the scan
+    traces it inside its own program (jitting at this level would just add
+    a dispatch for eager callers, who should prefer ``commit_assignment``).
     """
     return _commit_impl(net, comp, data, src, dst, num_layers, assign,
                         closures)
@@ -311,7 +312,7 @@ def hops_to_paths(hops, num_layers: int) -> list:
     *real* hops only — real paths are a few hops while the buffer holds V
     rows of mostly (-1, -1) padding, and the fused solver formats every
     layer of every round through here, so converting the padding to
-    Python ints was a measurable slice of its path post-pass.
+    Python ints would be a measurable slice of its ``greedy.paths`` span.
     """
     import numpy as np
     live = np.asarray(hops)[:int(num_layers) + 1]

@@ -219,11 +219,11 @@ def reconstruct_path(w: jax.Array, t: jax.Array, src: jax.Array, dst: jax.Array,
     dst is reached. jit/vmap friendly (fixed max_hops).
 
     A fixed-length ``scan`` with ``unroll=4``: the fused solver walks every
-    layer of every round on device (plus a [P, Lmax+1] batched post-pass for
-    ``plan.paths``), so per-step loop overhead — not the few-hop arithmetic —
-    is the cost, and unrolling beats both the plain scan and a
-    ``while_loop`` early exit (whose batched ``cond`` pays its own
-    per-iteration carry).  Unrolling is contraction-safe here: the body is
+    layer of every round on device (its commit charges the walked hops and
+    emits them as ``plan.paths``), so per-step loop overhead — not the
+    few-hop arithmetic — is the cost, and unrolling beats both the plain
+    scan and a ``while_loop`` early exit (whose batched ``cond`` pays its
+    own per-iteration carry).  Unrolling is contraction-safe here: the body is
     gathers, adds, and an argmin — no multiply feeding an add, so there is
     no FMA for LLVM to contract differently across unroll factors.
     Post-arrival steps emit exactly the (-1, -1) padding, so the output is

@@ -76,6 +76,31 @@ def test_fused_extract_paths_matches_ref_on_a_fat_tree():
     assert max(hops) <= 6
 
 
+@pytest.mark.parametrize("scenario,kw", [
+    ("us-backbone:paper", {}), ("fat-tree:paper", {"k": 4})],
+    ids=["usnet", "fat-tree-k4"])
+def test_fused_paths_are_the_hops_the_solve_charged(scenario, kw):
+    """Replaying every round's ``plan.paths`` onto the pre-solve link
+    queues — each hop += its layer's data, in round order, in float32 —
+    reproduces the solve's committed ``q_link`` bit for bit: the paths
+    the plan carries are the ones the queues were charged for.  Solved
+    at a queued state (a first window already committed)."""
+    sc = make_scenario(scenario, seed=0, **kw)
+    rng = np.random.default_rng(16)
+    first = J.batch_jobs(sc.sample_jobs(rng, 4), pad_to=sc.max_layers)
+    net = greedy.greedy_route(sc.topology.view(), first).net
+    batch = J.batch_jobs(sc.sample_jobs(rng, 6), pad_to=sc.max_layers)
+    plan = greedy.greedy_route(net, batch, extract_paths=True)
+    q_link = np.array(net.q_link, np.float32)
+    assert q_link.any()
+    data = np.asarray(batch.data, np.float32)
+    for j in plan.order.tolist():
+        for l, hops in enumerate(plan.paths[j]):
+            for u, v in hops:
+                q_link[u, v] += data[j, l]
+    np.testing.assert_array_equal(q_link, np.asarray(plan.net.q_link))
+
+
 def test_fused_dedupe_rows_bit_identical():
     """Duplicate data rows (the dedupe fast path) keep bit-identity."""
     rng = np.random.default_rng(20)

@@ -147,14 +147,14 @@ def test_every_transfer_is_counted(served, monkeypatch):
 
 def test_a_healthy_batch_moves_only_its_own_data(served):
     """After warm-up a healthy window builds no effective topology and
-    fetches no rate or queue the host holds: per batch 6 waits (the
-    staging's, the path post-pass's and the ledger's fetches of the batch,
-    the scan's two fetches, the walk's) and 6 uploads (the batch, the
-    dedupe plan, the path operands, two queue syncs, the clock)."""
+    fetches no rate or queue the host holds: per batch 3 waits (the
+    staging's and the ledger's fetches of the batch, the scan's one fetch
+    of its results and hops) and 5 uploads (the batch, the dedupe plan,
+    two queue syncs, the clock)."""
     n = 3
     counters = _recorded(served, n)["counters"]
     assert counters.get("topology_builds", 0) == 0
-    assert (counters["d2h"], counters["h2d"]) == (6 * n, 6 * n)
+    assert (counters["d2h"], counters["h2d"]) == (3 * n, 5 * n)
 
 
 def _tree():
@@ -189,6 +189,31 @@ def test_recorded_transfer_bytes_are_those_moved_while_on():
 def _batch(sc, n, seed=0):
     return J.batch_jobs(sc.sample_jobs(np.random.default_rng(seed), n),
                         pad_to=sc.max_layers)
+
+
+def test_a_solve_with_paths_is_one_program_and_one_fetch():
+    """``greedy_route(extract_paths=True)`` on a k=4 fat-tree runs one
+    device program, uploads once (the dedupe plan and the mask) and waits
+    twice: the staging's fetch of the batch's data, then one fetch of the
+    round outputs, hops included, and the layer counts.  The bytes fetched
+    are exactly those leaves'."""
+    sc = make_scenario("fat-tree:paper", seed=0, k=4)
+    net, batch = sc.topology.view(), _batch(sc, 4)
+    greedy.greedy_route(net, batch, extract_paths=True)
+    rounds, _, _ = jax.eval_shape(greedy._fused_solve, net,
+                                  *greedy._stage_window(batch))
+    fetched = (batch.data, batch.num_layers) + tuple(rounds)
+    n0 = telemetry.counter("fused_dispatches")
+    before = {k: telemetry.counter(k) for k in ("h2d", "d2h", "d2h_bytes")}
+    with jax.transfer_guard("disallow"):
+        plan = greedy.greedy_route(net, batch, extract_paths=True)
+    moved = {k: telemetry.counter(k) - v for k, v in before.items()}
+    assert telemetry.counter("fused_dispatches") - n0 == 1
+    assert (moved["h2d"], moved["d2h"]) == (1, 2)
+    assert moved["d2h_bytes"] == sum(
+        np.dtype(x.dtype).itemsize * int(np.prod(x.shape))
+        for x in jax.tree_util.tree_leaves(fetched))
+    assert set(plan.paths) == set(range(batch.num_jobs))
 
 
 def test_fused_dispatches_count_executions():

@@ -74,12 +74,36 @@ def test_minplus_batched_compiles_for_tpu(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_fused_solve_compiles_for_tpu(one_chip):
+@pytest.fixture(scope="module")
+def fused_solve(one_chip):
+    """``_fused_solve``'s operands at the served shapes and the program
+    compiled from them for the described chip."""
     net, batch = _served_window()
     operands = (net,) + greedy._stage_window(batch)
     compiled = greedy._fused_solve.lower(
         *_shapes(operands, one_chip)).compile()
+    return operands, compiled
+
+
+def test_fused_solve_compiles_for_tpu(fused_solve):
+    _, compiled = fused_solve
     assert compiled.as_text()
+
+
+def test_fused_solve_emits_hops_and_no_snapshot_for_tpu(fused_solve):
+    """The compiled solve's round outputs carry each round's hops
+    ``[P, Lmax+1, V, 2]`` int32 and no ``[V, V]`` snapshot: ``plan.paths``
+    need neither a second program nor a closure crossing the host link."""
+    operands, compiled = fused_solve
+    net, batch = operands[0], operands[1]
+    assert compiled.as_text()
+    rounds, _, _ = jax.eval_shape(greedy._fused_solve, *operands)
+    v, p = net.num_nodes, batch.num_jobs
+    hops = rounds[-1]
+    assert (hops.shape, hops.dtype) == ((p, batch.max_layers + 1, v, 2),
+                                        jnp.int32)
+    assert all(leaf.shape[-2:] != (v, v)
+               for leaf in jax.tree_util.tree_leaves(rounds))
 
 
 def test_fused_solve_many_compiles_for_tpu(one_chip):
@@ -88,20 +112,4 @@ def test_fused_solve_many_compiles_for_tpu(one_chip):
     _, *operands = greedy._stage_windows([batch, batch])
     compiled = greedy._fused_solve_many.lower(
         *_shapes((net, *operands), one_chip)).compile()
-    assert compiled.as_text()
-
-
-def test_walk_paths_compiles_for_tpu(one_chip):
-    net, batch = _served_window()
-    # the solve's outputs, abstractly, feed the staging greedy_route uses
-    staged = greedy._stage_window(batch)
-    (order, _, assigns, ql_pre, t_sel), _, _ = jax.eval_shape(
-        greedy._fused_solve, net, *staged)
-    zeros = lambda s: np.zeros(s.shape, s.dtype)
-    order = np.arange(order.shape[0])
-    operands = greedy._walk_operands(
-        net, staged[0], order, zeros(assigns), zeros(ql_pre), zeros(t_sel),
-        np.asarray(staged[0].num_layers)[order])
-    compiled = greedy._walk_paths.lower(
-        *_shapes(operands, one_chip), max_hops=net.num_nodes).compile()
     assert compiled.as_text()
