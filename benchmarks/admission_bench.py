@@ -77,7 +77,7 @@ def _prediction_gap(name: str, *, windows: int = 3, batch: int = 2) -> float:
     sched = OnlineScheduler(sc.topology, drain="exact")
     t = 0.0
     for _ in range(windows):
-        sched.submit_jobs(t, sc.sample_jobs(rng, batch),
+        sched.submit_window(t, sc.sample_jobs(rng, batch),
                           pad_to=sc.max_layers)
         t += 0.05
     preds = C.predict_completions(sched._effective_topology(), sched.ledger)

@@ -102,7 +102,7 @@ def _drive(name: str, engine: str, *, arrivals: int, batch: int, load: float,
                             track_commits=True)
     t0 = time.time()
     for t in times:
-        sched.submit_jobs(float(t), sc.sample_jobs(rng, batch),
+        sched.submit_window(float(t), sc.sample_jobs(rng, batch),
                           pad_to=sc.max_layers)
     t_submit = time.time() - t0
     t0 = time.time()

@@ -66,7 +66,7 @@ def main():
             sched.report_slowdown(cloud, 1.0, at=recover_at)
             recovered = True
             print(f"  [{recover_at:9.1f}s] cloud recovered")
-        placements = sched.submit_jobs(float(t), sc.sample_jobs(rng, 1),
+        placements = sched.submit_window(float(t), sc.sample_jobs(rng, 1),
                                        pad_to=sc.max_layers)
         if degraded and not recovered:
             cloud_hits_during_outage += sum(
@@ -100,7 +100,7 @@ def main():
     rng = np.random.default_rng(7)
     exact = OnlineScheduler(sc.topology, method="greedy", drain="exact")
     for t in times[times < slowdown_at]:
-        exact.submit_jobs(float(t), sc.sample_jobs(rng, 1),
+        exact.submit_window(float(t), sc.sample_jobs(rng, 1),
                           pad_to=sc.max_layers)
     completions = exact.finish()  # serve everything committed to completion
     etr = exact.trace

@@ -21,7 +21,7 @@ from repro.configs import registry
 from repro.core import network as N
 from repro.models import model as M
 from repro.serving.engine import DecodeEngine
-from repro.serving.scheduler import Request, RoutedScheduler
+from repro.serving.scheduler import Request, RoutedScheduler, requests_to_jobs
 
 
 def main():
@@ -39,7 +39,7 @@ def main():
             for i in range(4)]
     reqs += [Request("deepseek_v2_236b", src=0, dst=5, seq_len=2048,
                      name="dsv2-0")]
-    plans = sched.schedule(reqs)
+    plans = sched.schedule_jobs(requests_to_jobs(reqs))
     print("placements (greedy, queue-aware):")
     for p in plans:
         print(f"  prio {p.priority}: {p.job_name:10s} slices {p.nodes_used} "

@@ -14,7 +14,7 @@ from repro.configs import registry
 from repro.core import network as N
 from repro.models import model as M
 from repro.serving.engine import DecodeEngine
-from repro.serving.scheduler import Request, RoutedScheduler
+from repro.serving.scheduler import Request, RoutedScheduler, requests_to_jobs
 from repro.launch import compile_cache
 
 
@@ -39,9 +39,9 @@ def main():
     args = ap.parse_args()
 
     sched = RoutedScheduler(default_cluster(), method=args.method)
-    plans = sched.schedule([
+    plans = sched.schedule_jobs(requests_to_jobs([
         Request(args.arch, src=0, dst=5, seq_len=2048, name=f"req{i}")
-        for i in range(args.requests)])
+        for i in range(args.requests)]))
     for p in plans:
         print(f"[serve] prio {p.priority} {p.job_name}: slices "
               f"{p.nodes_used} bound {p.bound_s*1e3:.2f} ms")

@@ -398,13 +398,7 @@ class FaultInjector:
             return
         residuals = [self._residual(job) for job in affected]
         names = [job.name for job in affected]
-        sched.ledger = sched.ledger.remove_jobs(names, at=now)
-        if sched.commit_log is not None:
-            sched.commit_log = sched.commit_log.record_removal(now, names)
-        sched._sync_ledger_queues()
-        # The pre-batch snapshot may straddle the outage; a replan_last
-        # rollback would resurrect the withdrawn jobs.
-        sched._last = None
+        sched.withdraw(names, at=now)
         viable: list[tuple[J.InferenceJob, float]] = []
         for orig, new_job, arrival, reason in residuals:
             if self.policy == "lost":

@@ -38,7 +38,7 @@ from repro.core import (arrivals as A, completions as C, jobs as J, schedule,
 from repro.core.state import Topology
 from .admission import (AdmissionController, AdmissionPolicy, ReplanMonitor,
                         ReplanPolicy)
-from .scheduler import Placement, Request, RoutedScheduler, requests_to_jobs
+from .scheduler import Placement, RoutedScheduler
 
 
 @dataclasses.dataclass(frozen=True)
@@ -309,18 +309,14 @@ class OnlineScheduler(RoutedScheduler):
         self._stamp_clock()
 
     # -- events -------------------------------------------------------------
-    def submit_jobs(self, t: float, infer_jobs: Sequence[J.InferenceJob],
-                    *, pad_to: int | None = None) -> list[Placement]:
-        """Arrival event: drain to ``t``, place the batch, record the epoch."""
-        return self.submit_window(t, infer_jobs, pad_to=pad_to)
-
     @telemetry.spanned("sched.submit_window")
     def submit_window(self, t: float, infer_jobs: Sequence[J.InferenceJob],
                       *, arrivals: Sequence[float] | None = None,
                       pad_to: int | None = None,
                       solve_mode: str = "batched",
                       method: str | None = None) -> list[Placement]:
-        """Window-batched submission (the streaming pipeline's hook).
+        """Arrival event: drain to ``t``, place one window, record it (the
+        streaming pipeline's hook).
 
         ``t`` is the *commit* instant: the state drains to it and the whole
         window is placed there in one scheduler entry (one drain sync, one
@@ -336,86 +332,124 @@ class OnlineScheduler(RoutedScheduler):
         latency is then queueing wait plus the solver's completion bound,
         ``(t - arrival_i) + bound_i`` — the quantity a batching window
         actually delivers.  With ``arrivals`` omitted every request
-        arrived at ``t`` and this is exactly :meth:`submit_jobs`; names
-        within a window must be unique (they key the wait accounting and
-        the exact-drain completions).  After either mode ``last_solve_s``
-        holds the window's total solve wall.
+        arrived at ``t``; otherwise names within a window must be unique
+        (they key the wait accounting and the exact-drain completions).
+        After either mode ``last_solve_s`` holds the window's total solve
+        wall.  Armed admission control assesses the window first.
         """
         if solve_mode not in ("batched", "sequential"):
             raise ValueError(f"solve_mode must be 'batched' or "
                              f"'sequential', got {solve_mode!r}")
-        jobs = list(infer_jobs)
-        if arrivals is not None and len(arrivals) != len(jobs):
+        return self._submit(t, [infer_jobs],
+                            None if arrivals is None else [arrivals],
+                            pad_to=pad_to, method=method,
+                            solve_mode=solve_mode)[0]
+
+    def submit_windows(self, t: float,
+                       windows: Sequence[Sequence[J.InferenceJob]],
+                       *, arrivals: Sequence[Sequence[float]] | None = None,
+                       pad_to: int | None = None,
+                       method: str | None = None) -> list[list[Placement]]:
+        """Cross-arrival fused submission: W queued windows commit at ``t``
+        in one dispatch (:meth:`RoutedScheduler.schedule_windows`) — the
+        plans and records of W :meth:`submit_window` calls at ``t``, each
+        record's ``solve_s`` the window's share of the dispatch.
+        ``arrivals`` holds one list per window."""
+        if self.admission is not None and (self.admission.gating
+                                           or self.admission.deferred):
             raise ValueError(
-                f"arrivals ({len(arrivals)}) must align with infer_jobs "
-                f"({len(jobs)})")
-        arrs = ([float(a) for a in arrivals] if arrivals is not None
-                else [float(t)] * len(jobs))
-        track_wait = arrivals is not None
+                "admission control gates windows one at a time — use "
+                "submit_window (fused multi-window dispatch would commit "
+                "candidates before they can be assessed)")
+        return self._submit(t, windows, arrivals, pad_to=pad_to,
+                            method=method)
+
+    def _submit(self, t: float,
+                windows: Sequence[Sequence[J.InferenceJob]],
+                arrivals: Sequence[Sequence[float]] | None, *,
+                pad_to: int | None, method: str | None,
+                solve_mode: str = "batched") -> list[list[Placement]]:
+        """The one decision body behind :meth:`submit_window` (W = 1) and
+        :meth:`submit_windows`: drain to ``t``, place the windows through
+        :meth:`~RoutedScheduler.schedule_windows`, and record one
+        :class:`ArrivalRecord` each.  Admission, its deferred re-entry and
+        the sequential solve shape apply to a single window."""
+        windows = [list(w) for w in windows]
+        arrs = ([[float(t)] * len(w) for w in windows] if arrivals is None
+                else [[float(a) for a in w] for w in arrivals])
+        if [len(a) for a in arrs] != [len(w) for w in windows]:
+            raise ValueError("arrivals must align with the windows' jobs")
+        unique = arrivals is not None
         ctl = self.admission
         if ctl is not None and not ctl.external_defer and ctl.deferred:
             # Deferred arrivals ride the next window with their ORIGINAL
             # arrival instants (wait accounting spans the deferral).
             for job, a0 in ctl.pop_deferred():
-                jobs.append(job)
-                arrs.append(float(a0))
-            track_wait = True
-        if track_wait:
-            names = [j.name for j in jobs]
-            if len(set(names)) != len(names):
-                raise ValueError("window job names must be unique")
+                windows[0].append(job)
+                arrs[0].append(float(a0))
+            unique = True
+        if unique and any(len({j.name for j in w}) != len(w)
+                          for w in windows):
+            raise ValueError("window job names must be unique")
         self.advance_to(t)
         before = self._backlog()
         reuse, assess_s = None, 0.0
-        if ctl is not None and ctl.active(jobs):
-            jobs, arrs, reuse, assess_s = self._assess_admission(
-                float(t), jobs, arrs, self._effective_topology(),
+        if ctl is not None and len(windows) == 1 and ctl.active(windows[0]):
+            jobs, a, reuse, assess_s = self._assess_admission(
+                float(t), windows[0], arrs[0], self._effective_topology(),
                 pad_to=pad_to, method=method)
-            track_wait = True
-        self.trace.deadlines_by_name.update(
-            {j.name: j.deadline_s for j in jobs
-             if np.isfinite(j.deadline_s)})
-        if ctl is not None and not jobs:
+            windows, arrs = [jobs], [a]
+        for jobs in windows:
+            self.trace.deadlines_by_name.update(
+                {j.name: j.deadline_s for j in jobs
+                 if np.isfinite(j.deadline_s)})
+        if ctl is not None and windows == [[]]:
             # Admission shed/deferred the whole window: nothing to commit,
             # the shed records already tell the story.
             self.last_solve_s = assess_s
             self.total_solve_s += assess_s
             self.check_replan()
-            return []
-        wait = ({j.name: float(t) - a for j, a in zip(jobs, arrs)}
-                if track_wait else None)
-        if solve_mode == "sequential" and len(jobs) > 1:
+            return [[]]
+        if solve_mode == "sequential" and len(windows[0]) > 1:
             placements, walls = [], 0.0
-            for job in jobs:
-                placements.extend(self.schedule_jobs([job], pad_to=pad_to,
-                                                     method=method))
+            for job in windows[0]:
+                placements += self.schedule_jobs([job], pad_to=pad_to,
+                                                 method=method)
                 walls += self.last_solve_s
-            self.last_solve_s = walls + assess_s
-            self.total_solve_s += assess_s
+            per, states = [placements], [(self.state, self._queues)]
+            self.last_solve_s = walls
         elif reuse is not None:
             # Every candidate was admitted: commit the assessment's own
             # solve — admission adds no second dispatch on this path.
-            placements = self.commit_presolved(jobs, *reuse)
+            (batch, plan), assess_s = reuse, 0.0
+            per, states = self._commit_windows(windows, [batch], [plan])
         else:
-            placements = self.schedule_jobs(jobs, pad_to=pad_to,
-                                            method=method)
-            self.last_solve_s += assess_s
-            self.total_solve_s += assess_s
-        after = self._backlog()
-        self.trace.arrivals_by_name.update(
-            {j.name: a for j, a in zip(jobs, arrs)})
-        self.trace.records.append(ArrivalRecord(
-            time=t,
-            names=tuple(p.job_name for p in placements),
-            latencies=tuple(p.bound_s if wait is None
-                            else wait[p.job_name] + p.bound_s
-                            for p in placements),
-            backlog_before=before,
-            backlog_after=after,
-            solve_s=self.last_solve_s,
-        ))
+            per, states = self.schedule_windows(windows, pad_to=pad_to,
+                                                method=method)
+        self.last_solve_s += assess_s
+        self.total_solve_s += assess_s
+        walls = [self.last_solve_s]
+        if len(per) != 1:   # each window's share of the shared dispatch
+            walls = [float(p[0].plan.meta.get(
+                "solve_share_s", p[0].plan.meta.get("solve_s", 0.0)))
+                for p in per]
+            self.last_solve_s = sum(walls, 0.0)
+        for jobs, a, placements, state, solve_s in zip(windows, arrs, per,
+                                                       states, walls):
+            self.trace.arrivals_by_name.update(
+                {j.name: x for j, x in zip(jobs, a)})
+            wait = {j.name: float(t) - x for j, x in zip(jobs, a)}
+            # The post-commit snapshot's backlog (ledger-synced in exact
+            # mode), not the solver's fluid committed queues.
+            after = self._backlog(*state)
+            self.trace.records.append(ArrivalRecord(
+                time=t, names=tuple(p.job_name for p in placements),
+                latencies=tuple(wait[p.job_name] + p.bound_s
+                                for p in placements),
+                backlog_before=before, backlog_after=after, solve_s=solve_s))
+            before = after
         self.check_replan()
-        return placements
+        return per
 
     def _assess_admission(self, t: float, jobs: list[J.InferenceJob],
                           arrs: list[float], eff: Topology,
@@ -510,83 +544,6 @@ class OnlineScheduler(RoutedScheduler):
         finally:
             ctl.final = False
 
-    def submit_windows(self, t: float,
-                       windows: Sequence[Sequence[J.InferenceJob]],
-                       *, arrivals: Sequence[Sequence[float]] | None = None,
-                       pad_to: int | None = None,
-                       method: str | None = None) -> list[list[Placement]]:
-        """Cross-arrival fused submission: W queued windows, one dispatch.
-
-        All windows commit at instant ``t`` (one drain sync), solved in
-        order against each other's committed queues by
-        :meth:`RoutedScheduler.schedule_windows` — the same plans W
-        back-to-back :meth:`submit_window` calls at ``t`` would commit,
-        in a single fused device program.  One :class:`ArrivalRecord` per
-        window keeps the trace shape identical to the sequential path
-        (per-window ``solve_s`` is the shared dispatch's per-window
-        share); ``arrivals`` aligns per-window arrival instants exactly
-        as in :meth:`submit_window`.
-        """
-        if self.admission is not None and (self.admission.gating
-                                           or self.admission.deferred):
-            raise ValueError(
-                "admission control gates windows one at a time — use "
-                "submit_window (fused multi-window dispatch would commit "
-                "candidates before they can be assessed)")
-        windows = [list(w) for w in windows]
-        if arrivals is not None and len(arrivals) != len(windows):
-            raise ValueError(f"arrivals ({len(arrivals)}) must align with "
-                             f"windows ({len(windows)})")
-        waits: list[dict[str, float] | None] = [None] * len(windows)
-        if arrivals is not None:
-            for w, (jobs, arrs) in enumerate(zip(windows, arrivals)):
-                if len(arrs) != len(jobs):
-                    raise ValueError(
-                        f"window {w}: arrivals ({len(arrs)}) must align "
-                        f"with jobs ({len(jobs)})")
-                names = [j.name for j in jobs]
-                if len(set(names)) != len(names):
-                    raise ValueError("window job names must be unique")
-                waits[w] = {j.name: float(t) - float(a)
-                            for j, a in zip(jobs, arrs)}
-        self.advance_to(t)
-        before = self._backlog()
-        per_window = self.schedule_windows(windows, pad_to=pad_to,
-                                           method=method)
-        walls = 0.0
-        for w, (jobs, placements) in enumerate(zip(windows, per_window)):
-            arrs = (arrivals[w] if arrivals is not None
-                    else [t] * len(jobs))
-            self.trace.arrivals_by_name.update(
-                {j.name: float(a) for j, a in zip(jobs, arrs)})
-            # Backlogs come from the scheduler's per-window post-commit
-            # snapshots (ledger-synced in exact mode), so the recorded
-            # telemetry matches what W submit_window calls would have read
-            # — not the solver's fluid committed queues, which differ from
-            # the ledger materialization in the last ulp.
-            after = self._backlog(*self._window_states[w])
-            solve_w = float(placements[0].plan.meta.get(
-                "solve_share_s", placements[0].plan.meta.get("solve_s", 0.0)))
-            walls += solve_w
-            wait = waits[w]
-            self.trace.records.append(ArrivalRecord(
-                time=t,
-                names=tuple(p.job_name for p in placements),
-                latencies=tuple(p.bound_s if wait is None
-                                else wait[p.job_name] + p.bound_s
-                                for p in placements),
-                backlog_before=before,
-                backlog_after=after,
-                solve_s=solve_w,
-            ))
-            before = after
-        self.last_solve_s = walls
-        return per_window
-
-    def submit(self, t: float, requests: list[Request],
-               *, pad_to: int | None = None) -> list[Placement]:
-        return self.submit_jobs(t, requests_to_jobs(requests), pad_to=pad_to)
-
     def report_slowdown(self, node: int, factor: float,
                         *, at: float | None = None) -> None:
         """Straggler event on the clock: drain to ``at`` (default: now),
@@ -642,7 +599,7 @@ class OnlineScheduler(RoutedScheduler):
             self.trace.events.append(
                 {"time": self.now, "event": "replan_skipped",
                  "reason": self.last_replan_reason})
-        if out is not None:
+        else:
             self.trace.events.append({"time": self.now, "event": "replan",
                                       "reason": self.last_replan_reason,
                                       "bound_s": self.last_plan.bound()})
@@ -823,20 +780,36 @@ def run_online(scenario, *, horizon: float, seed: int = 0,
             injector.apply(faults[fi])
             fi += 1
             sched.check_replan()
-        jobs = scenario.sample_jobs(rng, batch_size)
-        if deadline_s is not None:
-            jobs = [j if np.isfinite(j.deadline_s)
-                    else j.with_deadline(deadline_s) for j in jobs]
+        jobs = with_deadline(scenario.sample_jobs(rng, batch_size),
+                             deadline_s)
         if injector is not None and sched.degraded:
             jobs = injector.filter_arrivals(float(t), jobs)
             if not jobs:
                 continue
-        sched.submit_jobs(float(t), jobs, pad_to=pad_to)
+        sched.submit_window(float(t), jobs, pad_to=pad_to)
     while fi < len(faults) and faults[fi].time <= horizon:
         injector.apply(faults[fi])
         fi += 1
         sched.check_replan()
     sched.flush_deferred(pad_to=pad_to)
+    return end_run(sched, finish=finish)
+
+
+def with_deadline(jobs: list[J.InferenceJob],
+                  deadline_s: float | None) -> list[J.InferenceJob]:
+    """Stamp a uniform relative SLO onto every job that carries none (a
+    job's own finite ``deadline_s`` wins); ``None`` stamps nothing."""
+    if deadline_s is None:
+        return jobs
+    return [j if np.isfinite(j.deadline_s) else j.with_deadline(deadline_s)
+            for j in jobs]
+
+
+def end_run(sched: OnlineScheduler, *, finish: bool) -> OnlineTrace:
+    """End-of-run accounting of :func:`run_online` and ``run_stream``: with
+    ``finish`` the exact ledger (if any) is served to completion and the
+    commit log (if any) replayed; the trace keeps the commit log either
+    way.  Returns the trace."""
     if finish:
         if sched.ledger is not None:
             sched.finish()

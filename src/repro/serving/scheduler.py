@@ -65,19 +65,6 @@ from repro.core.plan import Plan
 from repro.configs import registry
 
 
-def check_slowdown_factor(factor: float) -> float:
-    """Validate a straggler slowdown factor (the "factor=2 means half
-    speed" convention): must be finite and > 0, since the effective
-    topology divides by it — factor <= 0 would produce negative or
-    infinite capacities."""
-    factor = float(factor)
-    if not np.isfinite(factor) or factor <= 0:
-        raise ValueError(
-            f"slowdown factor must be finite and > 0 (factor=2 means half "
-            f"speed, factor=1 restores full health), got {factor}")
-    return factor
-
-
 @dataclasses.dataclass(frozen=True)
 class Placement:
     """View over one job of a stored :class:`Plan`."""
@@ -201,24 +188,17 @@ class RoutedScheduler:
         self.last_solve_s: float = 0.0
         self.total_solve_s: float = 0.0
 
-    # -- compatibility views ------------------------------------------------
-    @property
-    def net(self) -> N.ComputeNetwork:
-        """Current composed view (base topology + live queue state)."""
-        return self.topology.view(self.state)
-
-    @property
-    def base_net(self) -> N.ComputeNetwork:
-        """Healthy-capacity view with empty queues."""
-        return self.topology.view()
-
     # -- cluster health / time ---------------------------------------------
     def _check_slowdown(self, node: int, factor: float) -> float:
-        """Validate a slowdown event's arguments (raises ``ValueError``)."""
-        factor = check_slowdown_factor(factor)
-        if not (0 <= int(node) < self.topology.num_nodes):
-            raise ValueError(f"node {node} out of range "
-                             f"[0, {self.topology.num_nodes})")
+        """Validate a slowdown event's arguments (the "factor=2 means half
+        speed" convention): the factor must be finite and > 0, since the
+        effective topology divides by it, and the node in range."""
+        factor = float(factor)
+        if not np.isfinite(factor) or factor <= 0:
+            raise ValueError(
+                f"slowdown factor must be finite and > 0 (factor=2 means "
+                f"half speed, factor=1 restores full health), got {factor}")
+        self._check_node(node)
         return factor
 
     def report_slowdown(self, node: int, factor: float) -> None:
@@ -246,10 +226,7 @@ class RoutedScheduler:
         ``replay_piecewise`` sees the recovery window instead of treating
         the last reported slowdown as permanent.
         """
-        if not (0 <= int(node) < self.topology.num_nodes):
-            raise ValueError(f"node {node} out of range "
-                             f"[0, {self.topology.num_nodes})")
-        self.report_slowdown(int(node), 1.0)
+        self.report_slowdown(self._check_node(node), 1.0)
 
     def _check_node(self, node: int) -> int:
         node = int(node)
@@ -359,25 +336,6 @@ class RoutedScheduler:
         self._last = None
         self.last_plan = None
 
-    def stats(self) -> dict:
-        """Solve-time/closure-build telemetry of the most recent placement.
-
-        ``closure_builds`` counts host-level min-plus closure builds during
-        the solve — the reference round loop reports exactly J (one build
-        per round, so a regression that reintroduces per-call rebuilds
-        shows up here first) while the fused solver reports 0 (its closure
-        work happens inside the device program; the honest per-solve
-        accounting is ``fused``/``dispatches``/``rounds_per_dispatch``).
-        """
-        if self.last_plan is None:
-            return {}
-        m = self.last_plan.meta
-        return {k: m[k] for k in ("method", "solve_s", "solve_share_s",
-                                  "closure_builds", "n_routings", "fused",
-                                  "dispatches", "rounds_per_dispatch",
-                                  "windows_per_dispatch", "jit_compiled")
-                if k in m}
-
     @telemetry.spanned("sched.topology")
     def _effective_topology(self) -> Topology:
         """The health-scaled topology, rebuilt only after a health event
@@ -425,9 +383,12 @@ class RoutedScheduler:
     # other method _ledger_commit falls back to a full replay_solution.
     _PATH_SOLVERS = ("greedy", "greedy_ref", "lazy")
 
-    def _want_paths(self, method: str) -> bool:
-        return ((self.ledger is not None or self.commit_log is not None)
-                and method in self._PATH_SOLVERS)
+    def _solve_opts(self, method: str) -> dict:
+        """Solver options, asking for paths where a ledger will need them."""
+        if ((self.ledger is not None or self.commit_log is not None)
+                and method in self._PATH_SOLVERS):
+            return {"extract_paths": True, **self.solver_opts}
+        return self.solver_opts
 
     @telemetry.spanned("sched.commit")
     def _commit_plan(self, topo: Topology, batch: J.JobBatch, plan: Plan,
@@ -435,11 +396,9 @@ class RoutedScheduler:
                      names: list[str] | None) -> Plan:
         """Commit one solved plan: queue state, ledger/commit-log, telemetry.
 
-        Shared by the per-batch path (:meth:`commit_presolved`) and the
-        cross-arrival fused path (:meth:`schedule_windows`), which solves
-        W windows in one dispatch and then commits them through here one
-        at a time (``pre_state`` = the queue state that window was solved
-        against).
+        Every commit goes through here, one window at a time, from
+        :meth:`_commit_windows` (``pre_state`` = the queue state that
+        window was solved against) or from :meth:`replan_last`.
         """
         if plan.net is None:  # e.g. the exact solver reports no queue state
             plan = dataclasses.replace(
@@ -489,6 +448,53 @@ class RoutedScheduler:
                                                      at=self._now)
         return plan
 
+    def _solve(self, windows: list[list[J.InferenceJob]], *,
+               pad_to: int | None, method: str
+               ) -> tuple[list[J.JobBatch], list[Plan]]:
+        """Pure solve of W >= 1 windows, each against the previous one's
+        committed queues: one window through ``solvers.solve`` (the
+        registry is read at call time), several in one fused dispatch."""
+        with telemetry.span("sched.batch"):
+            batches = [J.batch_jobs(jobs, pad_to=pad_to) for jobs in windows]
+        opts, topo = self._solve_opts(method), self._effective_topology()
+        if len(batches) == 1:
+            return batches, [solvers.solve(topo, batches[0], method=method,
+                                           state=self.state, **opts)]
+        return batches, solvers.solve_fused(topo, batches, state=self.state,
+                                            pad_to=pad_to, **opts)
+
+    def _commit_windows(self, windows: list[list[J.InferenceJob]],
+                        batches: list[J.JobBatch], plans: list[Plan]
+                        ) -> tuple[list[list[Placement]], list[tuple]]:
+        """The one commit loop: commit solved windows in order.  Returns
+        the per-window placements and post-commit ``(state, host queues)``
+        — what W one-window commits would leave, read by the backlog."""
+        topo = self._effective_topology()
+        out, states = [], []
+        for jobs, batch, plan in zip(windows, batches, plans):
+            pre_state, pre_ledger, pre_log = (self.state, self.ledger,
+                                              self.commit_log)
+            plan = self._commit_plan(topo, batch, plan, pre_state,
+                                     [j.name for j in jobs])
+            # Record only after the commit succeeds, so a raising solver
+            # can't poison replan_last() with a batch never scheduled.
+            self._last = (batch, jobs, pre_state, topo, self._now,
+                          pre_ledger, pre_log)
+            if self.ledger is not None:
+                # Fault policies rebuild residual jobs from this registry;
+                # prune lazily once dead entries dominate (mirrors the
+                # engine cache's bloat rule — amortized O(1) per job).
+                self.inflight_jobs.update((j.name, j) for j in jobs)
+                n = len(self.inflight_jobs)
+                if n >= 2048 and n > 2 * len(self.ledger.jobs):
+                    live = {j.name for j in self.ledger.jobs}
+                    self.inflight_jobs = {k: j for k, j in
+                                          self.inflight_jobs.items()
+                                          if k in live}
+            out.append(self._placements(plan, jobs))
+            states.append((self.state, self._queues))
+        return out, states
+
     def presolve(self, infer_jobs: list[J.InferenceJob],
                  *, pad_to: int | None = None,
                  method: str | None = None) -> tuple[J.JobBatch, Plan]:
@@ -496,107 +502,59 @@ class RoutedScheduler:
         queue/ledger/telemetry mutation.  The admission controller scores
         the returned plan with ``completions.predict_completions`` before
         deciding whether to commit it (:meth:`commit_presolved`)."""
-        with telemetry.span("sched.batch"):
-            batch = J.batch_jobs(infer_jobs, pad_to=pad_to)
-        method = self.method if method is None else method
-        opts = self.solver_opts
-        if self._want_paths(method):
-            opts = {"extract_paths": True, **opts}
-        plan = solvers.solve(self._effective_topology(), batch,
-                             method=method, state=self.state, **opts)
+        (batch,), (plan,) = self._solve(
+            [infer_jobs], pad_to=pad_to,
+            method=self.method if method is None else method)
         return batch, plan
 
     def commit_presolved(self, infer_jobs: list[J.InferenceJob],
                          batch: J.JobBatch, plan: Plan) -> list[Placement]:
         """Commit a plan solved by :meth:`presolve` against the *unchanged*
-        current state — the second half of :meth:`schedule_jobs`."""
-        pre_state = self.state
-        pre_ledger, pre_log = self.ledger, self.commit_log
-        topo = self._effective_topology()
-        plan = self._commit_plan(topo, batch, plan, pre_state,
-                                 [j.name for j in infer_jobs])
-        # Record only after the commit succeeds, so a raising solver can't
-        # poison replan_last() with a batch that was never scheduled.
-        self._last = (batch, infer_jobs, pre_state, topo, self._now,
-                      pre_ledger, pre_log)
-        if self.ledger is not None:
-            # Fault policies rebuild residual jobs from this registry;
-            # prune lazily once dead entries dominate (mirrors the
-            # engine cache's bloat rule — amortized O(1) per job).
-            for j in infer_jobs:
-                self.inflight_jobs[j.name] = j
-            if (len(self.inflight_jobs) >= 2048
-                    and len(self.inflight_jobs) > 2 * len(self.ledger.jobs)):
-                live = {j.name for j in self.ledger.jobs}
-                self.inflight_jobs = {n: j for n, j in
-                                      self.inflight_jobs.items() if n in live}
-        return self._placements(plan, infer_jobs)
+        current state."""
+        return self._commit_windows([infer_jobs], [batch], [plan])[0][0]
 
     def schedule_jobs(self, infer_jobs: list[J.InferenceJob],
                       *, pad_to: int | None = None,
                       method: str | None = None) -> list[Placement]:
-        """Place pre-built :class:`InferenceJob`s (the online loop's path).
-
-        ``method`` overrides the configured solver for this batch only —
-        the fault layer's migrate policy re-places residual jobs with the
-        ``"migrate"`` solver while regular traffic keeps the default.
-        """
-        batch, plan = self.presolve(infer_jobs, pad_to=pad_to, method=method)
-        return self.commit_presolved(infer_jobs, batch, plan)
-
-    def schedule(self, requests: list[Request]) -> list[Placement]:
-        return self.schedule_jobs(requests_to_jobs(requests))
+        """Place one window: :meth:`schedule_windows` with W = 1."""
+        return self.schedule_windows([infer_jobs], pad_to=pad_to,
+                                     method=method)[0][0]
 
     def schedule_windows(self, windows: list[list[J.InferenceJob]],
                          *, pad_to: int | None = None,
-                         method: str | None = None) -> list[list[Placement]]:
-        """Place several queued arrival windows in **one** fused dispatch.
+                         method: str | None = None
+                         ) -> tuple[list[list[Placement]], list[tuple]]:
+        """Place W queued arrival windows in order, each against the
+        previous one's committed queues; returns the per-window placements
+        and post-commit ``(state, host queues)``.
 
-        Windows are solved in order, each against the previous window's
-        committed queues (``solvers.solve_fused``), then committed one at
-        a time so the ledger/commit-log records match W sequential
-        :meth:`schedule_jobs` calls.  Only the fused greedy has a
-        multi-window device program; any other method falls back to
-        sequential scheduling (same results, W dispatches).
+        Several windows under the fused greedy share **one** dispatch;
+        any other method has no multi-window program and places them one
+        after another (same results, W dispatches).  ``method`` overrides
+        the configured solver for this call only — the fault layer's
+        migrate policy re-places residual jobs with ``"migrate"``.
         """
         method = self.method if method is None else method
         if not windows:
-            self._window_states = []
-            return []
-        if method != "greedy" or len(windows) == 1:
-            out = []
-            self._window_states = []
-            for jobs in windows:
-                out.append(self.schedule_jobs(jobs, pad_to=pad_to,
-                                              method=method))
-                self._window_states.append((self.state, self._queues))
-            return out
-        topo = self._effective_topology()
-        with telemetry.span("sched.batch"):
-            batches = [J.batch_jobs(jobs, pad_to=pad_to) for jobs in windows]
-        opts = self.solver_opts
-        if self._want_paths(method):
-            opts = {"extract_paths": True, **opts}
-        plans = solvers.solve_fused(topo, batches, state=self.state,
-                                    pad_to=pad_to, **opts)
-        out = []
-        # Per-window post-commit queue snapshots, (state, host queues):
-        # after _commit_plan, self.state is authoritative (ledger-synced in
-        # exact mode, plan queues in fluid), so telemetry reading these
-        # matches what W sequential schedule_jobs calls would have recorded.
-        self._window_states = []
-        for jobs, batch, plan in zip(windows, batches, plans):
-            pre_state = self.state
-            plan = self._commit_plan(topo, batch, plan, pre_state,
-                                     [j.name for j in jobs])
-            self._last = (batch, jobs, pre_state, topo, self._now,
-                          self.ledger, self.commit_log)
-            if self.ledger is not None:
-                for j in jobs:
-                    self.inflight_jobs[j.name] = j
-            out.append(self._placements(plan, jobs))
-            self._window_states.append((self.state, self._queues))
-        return out
+            return [], []
+        if len(windows) > 1 and method != "greedy":
+            per = [self.schedule_windows([jobs], pad_to=pad_to, method=method)
+                   for jobs in windows]
+            return [o for (o,), _ in per], [st for _, (st,) in per]
+        return self._commit_windows(
+            windows, *self._solve(windows, pad_to=pad_to, method=method))
+
+    def withdraw(self, names, *, at: float) -> None:
+        """Take live committed jobs back at ``at`` (``drain="exact"``):
+        served work stays served, the commit log (if kept) records the
+        removal, the queues are re-materialized from the ledger, and the
+        replan snapshot goes — its rollback would resurrect the jobs."""
+        names = list(names)
+        self.ledger = self.ledger.remove_jobs(names, at=at)
+        if self.commit_log is not None:
+            self.commit_log = self.commit_log.record_removal(at, names)
+        self._sync_ledger_queues()
+        self._last = None
 
     def warmup(self, sample_jobs: list[J.InferenceJob],
                *, pad_to: int | None = None, max_jobs: int | None = None,
@@ -623,9 +581,7 @@ class RoutedScheduler:
             return {"compiles": 0, "wall_s": 0.0, "warm_solve_s": 0.0}
         t0 = time.perf_counter()
         topo = self._effective_topology()
-        opts = dict(self.solver_opts)
-        if self._want_paths(self.method):
-            opts = {"extract_paths": True, **opts}
+        opts = self._solve_opts(self.method)
         top = max_jobs if max_jobs is not None else len(sample_jobs)
         sizes, s = [], 1
         while s < top:
@@ -648,16 +604,15 @@ class RoutedScheduler:
             compiles += int(plans[0].meta.get("jit_compiled", False))
         wall = time.perf_counter() - t0
         # One more solve at the largest (already-compiled) size: a clean
-        # compile-excluded wall measurement for the latency-model seed.
-        t1 = time.perf_counter()
-        plan = solvers.solve(topo, J.batch_jobs(cyc, pad_to=pad_to),
-                             method=self.method, state=self.state, **opts)
-        warm = time.perf_counter() - t1
-        if plan.meta.get("jit_compiled", False):   # unseen shape slipped in
+        # compile-excluded wall measurement for the latency-model seed,
+        # taken again if an unseen shape slipped in.
+        for _ in range(2):
             t1 = time.perf_counter()
-            solvers.solve(topo, J.batch_jobs(cyc, pad_to=pad_to),
-                          method=self.method, state=self.state, **opts)
+            plan = solvers.solve(topo, J.batch_jobs(cyc, pad_to=pad_to),
+                                 method=self.method, state=self.state, **opts)
             warm = time.perf_counter() - t1
+            if not plan.meta.get("jit_compiled", False):
+                break
         return {"compiles": compiles, "wall_s": wall + warm,
                 "warm_solve_s": warm}
 
@@ -715,11 +670,8 @@ class RoutedScheduler:
         # Candidate re-solve at current health against the rolled-back
         # queues (pure — nothing committed yet).
         topo = self._effective_topology()
-        opts = self.solver_opts
-        if self._want_paths(self.method):
-            opts = {"extract_paths": True, **opts}
         plan = solvers.solve(topo, batch, method=self.method, state=state,
-                             **opts)
+                             **self._solve_opts(self.method))
         if min_improvement is not None:
             from repro.core import schedule
             old = self.last_plan

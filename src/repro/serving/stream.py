@@ -77,7 +77,7 @@ import numpy as np
 
 from repro.core import arrivals as A, jobs as J, telemetry
 from repro.core.state import Topology
-from .online import OnlineScheduler, OnlineTrace
+from .online import OnlineScheduler, OnlineTrace, end_run, with_deadline
 
 
 @dataclasses.dataclass(frozen=True)
@@ -529,11 +529,7 @@ class StreamingPipeline:
         if nonempty:
             jobs_w = [[a.job for a in w.jobs] for w in nonempty]
             arrs_w = [[a.arrival_s for a in w.jobs] for w in nonempty]
-            if len(nonempty) == 1:
-                one = self._solve_window(t, jobs_w[0], arrs_w[0])
-                per = None if one is None else [one]
-            else:
-                per = self._solve_windows(t, jobs_w, arrs_w)
+            per = self._solve_group(t, jobs_w, arrs_w)
             wall = self.sched.last_solve_s
             if per is None:           # solver died twice: shed the group
                 for w in nonempty:
@@ -548,7 +544,8 @@ class StreamingPipeline:
                 # A wall that paid a jit compile would poison the EMA (the
                 # model would predict compile-sized latency for every
                 # following solve); record it separately instead.
-                if bool(self.sched.stats().get("jit_compiled", False)):
+                plan = self.sched.last_plan
+                if plan is not None and plan.meta.get("jit_compiled", False):
                     self.trace.compile_walls.append(wall)
                 else:
                     self._observe_solve(wall)
@@ -579,61 +576,30 @@ class StreamingPipeline:
             self._finish_window(t, w, d, wall=walls.get(id(w), 0.0))
         self._release(t)
 
-    def _solve_window(self, t: float, jobs, arrivals):
-        """One window's solve with the robustness contract: a solver
-        exception must not kill the pipeline.  A clean failure (nothing
-        committed) is retried once; a *partial* failure (sequential mode
-        committed a prefix before the raise) is rolled back through the
-        ledger's withdrawal machinery — the raise happened at the commit
-        instant, so zero served work is discarded — and not retried
-        (committed names are unique for the ledger's lifetime, so the same
-        requests cannot be resubmitted).  Returns ``None`` when the window
-        commits nothing; the caller sheds it with ``reason:
-        "solver_error"``."""
+    def _solve_group(self, t: float, jobs_w, arrs_w):
+        """Commit a group of windows (one through ``submit_window``,
+        several through ``submit_windows``) so that a solver exception
+        cannot kill the pipeline.  A clean failure is retried once; a
+        partial one is withdrawn (``sched.withdraw`` at the commit instant,
+        so no served work is discarded) and not retried, since committed
+        names stay taken.  Returns per-window placements, or ``None`` when
+        nothing commits (the caller sheds it as ``solver_error``)."""
         sched = self.sched
         for attempt in (0, 1):
             pre = (sched.ledger.names_seen if sched.ledger is not None
                    else frozenset())
             try:
-                return sched.submit_window(
-                    t, jobs, arrivals=arrivals, pad_to=self._pad_to,
-                    solve_mode=self.config.solve_mode)
-            except Exception:  # noqa: BLE001 — serving must survive
-                landed = (sorted(sched.ledger.names_seen - pre)
-                          if sched.ledger is not None else [])
-                if landed:
-                    sched.ledger = sched.ledger.remove_jobs(landed, at=t)
-                    if sched.commit_log is not None:
-                        sched.commit_log = sched.commit_log.record_removal(
-                            t, landed)
-                    sched._sync_ledger_queues()
-                    sched._last = None
-                    return None
-        return None
-
-    def _solve_windows(self, t: float, jobs_w, arrs_w):
-        """Cross-arrival fused solve of several windows, with the same
-        robustness contract as :meth:`_solve_window`: a clean failure is
-        retried once, a partial failure (some windows committed before the
-        raise) is rolled back through the ledger and not retried.  Returns
-        per-window placement lists, or ``None`` when nothing commits."""
-        sched = self.sched
-        for attempt in (0, 1):
-            pre = (sched.ledger.names_seen if sched.ledger is not None
-                   else frozenset())
-            try:
+                if len(jobs_w) == 1:
+                    return [sched.submit_window(
+                        t, jobs_w[0], arrivals=arrs_w[0], pad_to=self._pad_to,
+                        solve_mode=self.config.solve_mode)]
                 return sched.submit_windows(t, jobs_w, arrivals=arrs_w,
                                             pad_to=self._pad_to)
             except Exception:  # noqa: BLE001 — serving must survive
                 landed = (sorted(sched.ledger.names_seen - pre)
                           if sched.ledger is not None else [])
                 if landed:
-                    sched.ledger = sched.ledger.remove_jobs(landed, at=t)
-                    if sched.commit_log is not None:
-                        sched.commit_log = sched.commit_log.record_removal(
-                            t, landed)
-                    sched._sync_ledger_queues()
-                    sched._last = None
+                    sched.withdraw(landed, at=t)
                     return None
         return None
 
@@ -747,18 +713,8 @@ def run_stream(scenario, *, horizon: float, seed: int = 0,
         stream = ((float(t), scenario.sample_jobs(rng, batch_size))
                   for t in times)
     if deadline_s is not None:
-        def _with_slo(src, d=float(deadline_s)):
-            for t, jobs in src:
-                yield t, [j if np.isfinite(j.deadline_s)
-                          else j.with_deadline(d) for j in jobs]
-        stream = _with_slo(stream)
+        stream = ((t, with_deadline(jobs, deadline_s)) for t, jobs in stream)
     pipe.run(stream, horizon=horizon, pad_to=pad_to,
              fault_schedule=fault_schedule, recovery=recovery,
              max_retries=max_retries)
-    if finish:
-        if sched.ledger is not None:
-            sched.finish()
-        if sched.commit_log is not None:
-            sched.replay_ground_truth()
-    pipe.trace.commit_log = sched.commit_log
-    return pipe.trace
+    return end_run(sched, finish=finish)

@@ -84,11 +84,11 @@ def test_defer_then_expire_charged_from_original_arrival(scenario):
     filler = scenario.sample_jobs(rng, 3)
     (victim,) = scenario.sample_jobs(rng, 1)
     victim = victim.with_deadline(1e-3)   # can never be met once queued
-    sched.submit_jobs(0.0, filler + [victim], pad_to=scenario.max_layers)
+    sched.submit_window(0.0, filler + [victim], pad_to=scenario.max_layers)
     assert [j.name for j, _ in sched.admission.deferred] == [victim.name]
     # next window, past the deadline: the deferral expires
     later = scenario.sample_jobs(rng, 1)
-    sched.submit_jobs(0.5, later, pad_to=scenario.max_layers)
+    sched.submit_window(0.5, later, pad_to=scenario.max_layers)
     (rec,) = [s for s in sched.trace.shed if s["name"] == victim.name]
     assert rec["reason"] == "deadline_miss"
     assert rec["arrival"] == 0.0 and rec["time"] == 0.5
@@ -102,7 +102,7 @@ def test_flush_deferred_drains_out(scenario):
     rng = np.random.default_rng(6)
     jobs = [j.with_deadline(1e-3) for j in scenario.sample_jobs(rng, 2)]
     filler = scenario.sample_jobs(rng, 2)
-    sched.submit_jobs(0.0, filler + jobs, pad_to=scenario.max_layers)
+    sched.submit_window(0.0, filler + jobs, pad_to=scenario.max_layers)
     assert len(sched.admission.deferred) == 2
     placed = sched.flush_deferred(at=0.25, pad_to=scenario.max_layers)
     assert placed == [] and not sched.admission.deferred
@@ -179,7 +179,7 @@ def test_replan_reasons_recorded(scenario):
     assert sched.replan_last() is None
     assert sched.last_replan_reason == "no_batch"
     rng = np.random.default_rng(12)
-    sched.submit_jobs(0.0, scenario.sample_jobs(rng, 2),
+    sched.submit_window(0.0, scenario.sample_jobs(rng, 2),
                       pad_to=scenario.max_layers)
     # steady health: a re-solve ties, so any positive margin declines it
     assert sched.replan_last(min_improvement=0.25) is None
@@ -282,7 +282,7 @@ def test_admission_counters_live_on_trace(scenario):
                             admission="reject")
     rng = np.random.default_rng(21)
     jobs = [j.with_deadline(1e-3) for j in scenario.sample_jobs(rng, 2)]
-    sched.submit_jobs(0.0, jobs, pad_to=scenario.max_layers)
+    sched.submit_window(0.0, jobs, pad_to=scenario.max_layers)
     s = sched.trace.summary()
     assert s["admission"]["assessed"] == 2
     assert s["admission"]["rejected"] + s["admission"]["expired"] == 2

@@ -221,7 +221,7 @@ def test_exact_backlog_trace_rejects_drained_ledger():
     sc = make_scenario("star", seed=0)
     rng = np.random.default_rng(0)
     sched = OnlineScheduler(sc.topology, drain="exact")
-    sched.submit_jobs(0.0, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
+    sched.submit_window(0.0, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
     sched.advance_to(1e-3)
     with pytest.raises(ValueError, match="undrained"):
         C.exact_backlog_trace(sc.topology, sched.ledger, [1.0])
@@ -233,7 +233,7 @@ def test_scheduler_drain_mode_validation_and_reset():
         OnlineScheduler(sc.topology, drain="magic")
     sched = OnlineScheduler(sc.topology, drain="exact")
     rng = np.random.default_rng(1)
-    sched.submit_jobs(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
+    sched.submit_window(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
     assert sched.ledger is not None and len(sched.ledger.jobs) == 2
     # state queues were materialized from the ledger
     qn, _ = sched.ledger.queue_arrays()
@@ -250,8 +250,8 @@ def test_exact_replan_rolls_ledger_back():
     sc = make_scenario("edge-cloud", traffic="synthetic", seed=0)
     sched = OnlineScheduler(sc.topology, drain="exact")
     rng = np.random.default_rng(3)
-    sched.submit_jobs(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
-    sched.submit_jobs(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
+    sched.submit_window(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
+    sched.submit_window(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
     assert len(sched.ledger.jobs) == 4
     bound0 = sched.last_plan.bound()
     sched.advance_to(1e9)  # everything committed has long been served
@@ -265,18 +265,21 @@ def test_exact_replan_rolls_ledger_back():
 def test_ledger_rejects_duplicate_job_names():
     """Completion records key on job names; a repeat would silently
     overwrite an earlier job's completion, so commit() rejects it."""
-    from repro.serving.scheduler import Request, RoutedScheduler
+    from repro.serving.scheduler import (Request, RoutedScheduler,
+                                         requests_to_jobs)
     from repro.core import network as N
 
     G, GB = 1e12, 1e9
     net = N.make_network(3, [(0, 1, 10 * GB), (1, 2, 10 * GB)],
                          [0, 50 * G, 0])
     sched = RoutedScheduler(net, drain="exact")
-    sched.schedule([Request("smollm_135m", 0, 2)])  # defaults to name req0
+    # the name defaults to req0
+    sched.schedule_jobs(requests_to_jobs([Request("smollm_135m", 0, 2)]))
     with pytest.raises(ValueError, match="duplicate job name 'req0'"):
-        sched.schedule([Request("smollm_135m", 0, 2)])
+        sched.schedule_jobs(requests_to_jobs([Request("smollm_135m", 0, 2)]))
     # distinct names are fine across batches
-    sched.schedule([Request("smollm_135m", 0, 2, name="r1")])
+    sched.schedule_jobs(
+        requests_to_jobs([Request("smollm_135m", 0, 2, name="r1")]))
     assert len(sched.ledger.jobs) == 2
 
 
@@ -299,8 +302,8 @@ def test_exact_bounds_hold_through_replan():
     sc = make_scenario("edge-cloud", traffic="synthetic", seed=0)
     sched = OnlineScheduler(sc.topology, drain="exact")
     rng = np.random.default_rng(11)
-    sched.submit_jobs(0.0, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
-    sched.submit_jobs(0.5, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
+    sched.submit_window(0.0, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
+    sched.submit_window(0.5, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
     victim = int(sched.last_plan.assign[int(sched.last_plan.order[0]), 0])
     sched.report_slowdown(victim, 50.0, at=1.0)
     sched.replan_last()

@@ -184,7 +184,7 @@ def _lockstep_schedulers(sc, seeds=(0,), arrivals=6, **kw):
     for _ in range(arrivals):
         jobs = sc.sample_jobs(rng, 1)
         for sched in scheds.values():
-            sched.submit_jobs(t, list(jobs), pad_to=sc.max_layers)
+            sched.submit_window(t, list(jobs), pad_to=sc.max_layers)
         t += float(rng.uniform(0.05, 0.4))
     return scheds
 
@@ -213,12 +213,12 @@ def test_persistent_engine_is_threaded_not_rebuilt():
     sc = make_scenario("star", seed=0)
     sched = OnlineScheduler(sc.topology, drain="exact")
     rng = np.random.default_rng(3)
-    sched.submit_jobs(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
+    sched.submit_window(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
     eng0 = C._engine_of(sched.ledger)
     assert eng0 is not None
     snapshot = sched.ledger
     sched.advance_to(0.05)
-    sched.submit_jobs(0.1, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
+    sched.submit_window(0.1, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
     assert C._engine_of(sched.ledger) is eng0        # same index, threaded
     assert C._engine_of(snapshot) is None            # snapshot went stale
     # and the stale snapshot still drains correctly (lazy rebuild)
@@ -236,8 +236,8 @@ def test_replan_rollback_with_indexed_engine():
     for eng in ("indexed", "ref"):
         sched = OnlineScheduler(sc.topology, drain="exact", sim_engine=eng)
         rng = np.random.default_rng(3)
-        sched.submit_jobs(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
-        sched.submit_jobs(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
+        sched.submit_window(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
+        sched.submit_window(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
         assert len(sched.ledger.jobs) == 4
         sched.advance_to(1e9)
         assert not sched.ledger.jobs
@@ -268,10 +268,10 @@ def test_piecewise_replay_matches_incremental_through_slowdown():
     sc = make_scenario("star", seed=0)
     sched = OnlineScheduler(sc.topology, drain="exact", track_commits=True)
     rng = np.random.default_rng(5)
-    sched.submit_jobs(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
+    sched.submit_window(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
     victim = int(sched.last_plan.assign[int(sched.last_plan.order[0]), 0])
     sched.report_slowdown(victim, 6.0, at=0.02)
-    sched.submit_jobs(0.05, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
+    sched.submit_window(0.05, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
     incremental = sched.finish()
     assert sched.commit_log.health == ((0.02, victim, 6.0),)
     replay = sched.replay_ground_truth()
@@ -292,8 +292,8 @@ def test_replan_keeps_health_history_in_commit_log():
     sc = make_scenario("edge-cloud", traffic="synthetic", seed=0)
     sched = OnlineScheduler(sc.topology, drain="exact", track_commits=True)
     rng = np.random.default_rng(7)
-    sched.submit_jobs(0.0, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
-    sched.submit_jobs(0.2, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
+    sched.submit_window(0.0, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
+    sched.submit_window(0.2, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
     sched.report_slowdown(0, 2.0, at=0.3)
     sched.replan_last()
     assert len(sched.commit_log.health) == 1

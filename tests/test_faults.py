@@ -220,7 +220,7 @@ def _stranded_setup(policy, **kw):
     then node 8 fails at t=0.1 — returns (sched, injector, outage rec)."""
     sc = make_scenario("edge-cloud", seed=0)
     sched = OnlineScheduler(sc.topology, drain="exact", track_commits=True)
-    sched.submit_jobs(0.0, sc.sample_jobs(np.random.default_rng(0), 2))
+    sched.submit_window(0.0, sc.sample_jobs(np.random.default_rng(0), 2))
     inj = F.FaultInjector(sched, policy=policy, **kw)
     rec = inj.apply(F.node_fail(0.1, 8))
     assert rec["affected"], "setup: no work landed on the victim node"

@@ -247,7 +247,25 @@ def test_online_fused_reproduces_serial_trace():
     assert list(gf.values()) == list(gr.values())
 
 
-def test_submit_windows_matches_sequential_submits():
+def _scheduler_view(s):
+    """What a commit leaves in the scheduler: live ledger names, the
+    inflight registry, and the replan snapshot (batch, jobs, ledgers)."""
+    batch, jobs, _, _, _, pre_ledger, _ = s._last
+    names = (lambda led: None if led is None
+             else [j.name for j in led.jobs])
+    return (names(s.ledger), list(s.inflight_jobs),
+            [np.asarray(x).tolist() for x in jax.tree_util.tree_leaves(batch)],
+            [j.name for j in jobs], names(pre_ledger))
+
+
+@pytest.mark.parametrize("drain,epochs,sizes,gap", [
+    ("fluid", 3, (4, 3), 0.05),
+    ("exact", 3, (4, 3), 0.05),
+    # 2,112 commits at half load: past the inflight registry's prune
+    # threshold (2,048 entries), so both paths must prune alike.
+    ("exact", 33, (32, 32), 16.0),
+])
+def test_submit_windows_matches_sequential_submits(drain, epochs, sizes, gap):
     """Fused cross-arrival submission vs W sequential submit_window calls.
 
     Fluid mode is bit-identical.  Exact mode re-materializes queues from
@@ -256,44 +274,47 @@ def test_submit_windows_matches_sequential_submits():
     drift by f32-ulp rounding (~1e-7 relative); committed work — and
     hence completions and replayed ground truth — stays bitwise equal,
     as does the backlog telemetry (read from per-window post-commit
-    snapshots)."""
+    snapshots).  Both paths leave the same scheduler state behind: live
+    ledger, inflight registry and replan snapshot."""
     sc = make_scenario("paper-small", seed=0)
+    rng = np.random.default_rng(7)
+    epochs_w = [[sc.sample_jobs(rng, n) for n in sizes]
+                for _ in range(epochs)]
 
-    def run(mode, drain):
-        rng = np.random.default_rng(7)
+    def run(mode):
         kw = (dict(track_commits=True, sim_engine="indexed")
               if drain == "exact" else {})
         s = OnlineScheduler(sc.topology, drain=drain, **kw)
         t = 0.0
-        for _ in range(3):
-            t += 0.05
-            wins = [sc.sample_jobs(rng, n) for n in (4, 3)]
+        for wins in epochs_w:
+            t += gap
             if mode == "fused":
                 s.submit_windows(t, wins, pad_to=sc.max_layers)
             else:
                 for w in wins:
                     s.submit_window(t, w, pad_to=sc.max_layers)
+        view = _scheduler_view(s)
         if drain == "exact":
             s.finish()
-        return s
+        return s, view
 
-    for drain in ("fluid", "exact"):
-        mf, ms = run("fused", drain), run("seq", drain)
-        assert len(mf.trace.records) == len(ms.trace.records)
-        for x, y in zip(mf.trace.records, ms.trace.records):
-            assert x.backlog_before == y.backlog_before
-            assert x.backlog_after == y.backlog_after
-            if drain == "fluid":
-                assert x.latencies == y.latencies
-            else:
-                np.testing.assert_allclose(np.asarray(x.latencies),
-                                           np.asarray(y.latencies),
-                                           rtol=1e-5)
-        if drain == "exact":
-            assert (list(mf.trace.completions.values())
-                    == list(ms.trace.completions.values()))
-            assert (list(mf.replay_ground_truth().values())
-                    == list(ms.replay_ground_truth().values()))
+    (mf, vf), (ms, vs) = run("fused"), run("seq")
+    assert vf == vs
+    assert len(mf.trace.records) == len(ms.trace.records)
+    for x, y in zip(mf.trace.records, ms.trace.records):
+        assert x.backlog_before == y.backlog_before
+        assert x.backlog_after == y.backlog_after
+        if drain == "fluid":
+            assert x.latencies == y.latencies
+        else:
+            np.testing.assert_allclose(np.asarray(x.latencies),
+                                       np.asarray(y.latencies),
+                                       rtol=1e-5)
+    if drain == "exact":
+        assert (list(mf.trace.completions.values())
+                == list(ms.trace.completions.values()))
+        assert (list(mf.replay_ground_truth().values())
+                == list(ms.replay_ground_truth().values()))
 
 
 # ---------------------------------------------------------------------------

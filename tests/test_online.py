@@ -92,7 +92,7 @@ def _edge_cloud_sched(**kw):
 def test_slowdown_and_replan_are_clock_events():
     sc, sched = _edge_cloud_sched()
     rng = np.random.default_rng(0)
-    sched.submit_jobs(1.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
+    sched.submit_window(1.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
     before = sched.last_plan
     victim = int(before.assign[int(before.order[0]), 0])
     sched.report_slowdown(victim, 100.0, at=2.5)
@@ -111,7 +111,7 @@ def test_nodrain_clock_still_advances():
     baseline freezes backlogs but not the clock."""
     sc, sched = _edge_cloud_sched(drain_queues=False)
     rng = np.random.default_rng(2)
-    sched.submit_jobs(0.0, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
+    sched.submit_window(0.0, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
     q0 = np.asarray(sched.state.q_node).copy()
     sched.advance_to(5.0)
     assert sched.clock == pytest.approx(5.0)
@@ -124,8 +124,8 @@ def test_replan_drains_elapsed_time_from_rollback():
     sc, sched = _edge_cloud_sched()
     rng = np.random.default_rng(3)
     # batch 1 builds backlog; batch 2's pre-state snapshot carries it
-    sched.submit_jobs(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
-    sched.submit_jobs(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
+    sched.submit_window(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
+    sched.submit_window(0.0, sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
     assert float(np.asarray(sched._last[2].q_node).sum()) > 0
     bound0 = sched.last_plan.bound()  # scored against batch-1 backlog
     sched.advance_to(1e9)  # everything committed has long been served
@@ -141,7 +141,7 @@ def test_inherited_advance_shares_the_one_clock():
     clock: mixing them must not drain the same interval twice."""
     sc, sched = _edge_cloud_sched()
     rng = np.random.default_rng(5)
-    sched.submit_jobs(0.0, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
+    sched.submit_window(0.0, sc.sample_jobs(rng, 1), pad_to=sc.max_layers)
     sched.advance(5.0)                 # inherited explicit-drain call
     assert sched.now == pytest.approx(5.0)
     q_after_advance = np.asarray(sched.state.q_node).copy()
@@ -165,7 +165,7 @@ def test_slowdown_slows_draining():
     rng = np.random.default_rng(1)
     jobs = sc.sample_jobs(rng, 2)
     for s in (fast, slow):
-        s.submit_jobs(0.0, list(jobs), pad_to=sc.max_layers)
+        s.submit_window(0.0, list(jobs), pad_to=sc.max_layers)
     q = np.asarray(fast.state.q_node, np.float64)
     mu = np.asarray(sc.topology.mu_node, np.float64)
     waits = np.where(mu > 0, q / np.maximum(mu, 1e-30), 0.0)

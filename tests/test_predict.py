@@ -25,7 +25,7 @@ RTOL = 1e-9
 def _drive(sched, sc, rng, windows, batch=2, dt=0.05):
     t = 0.0
     for _ in range(windows):
-        sched.submit_jobs(t, sc.sample_jobs(rng, batch),
+        sched.submit_window(t, sc.sample_jobs(rng, batch),
                           pad_to=sc.max_layers)
         t += dt
     return t
@@ -179,7 +179,7 @@ def test_predictions_exact_through_outage_segment():
             jobs = injector.filter_arrivals(float(t), jobs)
             if not jobs:
                 continue
-        sched.submit_jobs(float(t), jobs, pad_to=sc.max_layers)
+        sched.submit_window(float(t), jobs, pad_to=sc.max_layers)
     while fi < len(faults):
         injector.apply(faults[fi])
         fi += 1

@@ -27,7 +27,7 @@ def test_placements_valid_and_prioritized():
     sched = RoutedScheduler(_cluster())
     reqs = [Request("smollm_135m", src=0, dst=5, seq_len=1024, name=f"r{i}")
             for i in range(4)]
-    plans = sched.schedule(reqs)
+    plans = sched.schedule_jobs(requests_to_jobs(reqs))
     # regression: the list is built in priority order (no re-sort needed)
     assert [p.priority for p in plans] == [0, 1, 2, 3]
     for p in plans:
@@ -42,8 +42,8 @@ def test_placements_are_views_over_stored_plan():
     from repro.core.plan import Plan
 
     sched = RoutedScheduler(_cluster())
-    plans = sched.schedule([Request("smollm_135m", 0, 5, name=f"r{i}")
-                            for i in range(3)])
+    plans = sched.schedule_jobs(requests_to_jobs(
+        [Request("smollm_135m", 0, 5, name=f"r{i}") for i in range(3)]))
     stored = sched.last_plan
     assert stored is not None and stored.solver == "greedy"
     for p in plans:
@@ -60,7 +60,7 @@ def test_scheduler_method_flag():
     by_method = {}
     for method in ("greedy", "lazy"):
         sched = RoutedScheduler(_cluster(), method=method)
-        sched.schedule(reqs)
+        sched.schedule_jobs(requests_to_jobs(reqs))
         by_method[method] = sched.last_plan
     np.testing.assert_allclose(by_method["greedy"].bounds,
                                by_method["lazy"].bounds, rtol=1e-6)
@@ -69,8 +69,8 @@ def test_scheduler_method_flag():
 def test_replan_last_routes_around_straggler():
     """report_slowdown + replan_last re-places the same batch."""
     sched = RoutedScheduler(_cluster())
-    plans = sched.schedule([Request("olmo_1b", 0, 5, name=f"r{i}")
-                            for i in range(2)])
+    plans = sched.schedule_jobs(requests_to_jobs(
+        [Request("olmo_1b", 0, 5, name=f"r{i}") for i in range(2)]))
     victim = plans[0].nodes_used[0]
     sched.report_slowdown(victim, 50.0)
     replans = sched.replan_last()
@@ -85,7 +85,7 @@ def test_queue_aware_spreading():
     sched = RoutedScheduler(_cluster())
     reqs = [Request("olmo_1b", src=0, dst=5, seq_len=2048, name=f"r{i}")
             for i in range(8)]
-    plans = sched.schedule(reqs)
+    plans = sched.schedule_jobs(requests_to_jobs(reqs))
     used = {n for p in plans for n in p.nodes_used}
     assert len(used) >= 2, f"all jobs piled on {used}"
 
@@ -93,12 +93,13 @@ def test_queue_aware_spreading():
 def test_straggler_avoidance():
     """A slice reported 10x slow receives no new placements."""
     sched = RoutedScheduler(_cluster())
-    plans0 = sched.schedule([Request("olmo_1b", 0, 5, name="warm")])
+    plans0 = sched.schedule_jobs(
+        requests_to_jobs([Request("olmo_1b", 0, 5, name="warm")]))
     hot = plans0[0].nodes_used[0]
     sched.drain()
     sched.report_slowdown(hot, 10.0)
-    plans = sched.schedule([Request("olmo_1b", 0, 5, name=f"r{i}")
-                            for i in range(4)])
+    plans = sched.schedule_jobs(requests_to_jobs(
+        [Request("olmo_1b", 0, 5, name=f"r{i}") for i in range(4)]))
     for p in plans:
         assert hot not in p.nodes_used, (hot, p.nodes_used)
 
@@ -147,8 +148,8 @@ def test_scheduler_exact_drain_end_to_end():
     """drain='exact' on the request path: placements come out the same shape,
     advance() drains the ledger, and full drain empties the queues."""
     sched = RoutedScheduler(_cluster(), drain="exact")
-    plans = sched.schedule([Request("smollm_135m", 0, 5, name=f"r{i}")
-                            for i in range(3)])
+    plans = sched.schedule_jobs(requests_to_jobs(
+        [Request("smollm_135m", 0, 5, name=f"r{i}") for i in range(3)]))
     assert [p.priority for p in plans] == [0, 1, 2]
     assert len(sched.ledger.jobs) == 3
     q0 = float(np.asarray(sched.state.q_node).sum())
@@ -164,7 +165,8 @@ def test_scheduler_exact_drain_end_to_end():
 def test_scheduler_advance_drains_queues():
     """Time passing drains the committed backlog at effective rates."""
     sched = RoutedScheduler(_cluster())
-    sched.schedule([Request("olmo_1b", 0, 5, name="r0")])
+    sched.schedule_jobs(
+        requests_to_jobs([Request("olmo_1b", 0, 5, name="r0")]))
     q0 = float(np.asarray(sched.state.q_node).sum())
     assert q0 > 0
     sched.advance(1e-3)
@@ -213,8 +215,8 @@ def test_health_event_rebuilds_the_effective_topology(event, track_commits,
     from repro.core import completions as C, telemetry
     sched = RoutedScheduler(_cluster(), drain="exact",
                             track_commits=track_commits)
-    sched.schedule([Request("smollm_135m", 0, 5, name=f"r{i}")
-                    for i in range(3)])
+    sched.schedule_jobs(requests_to_jobs(
+        [Request("smollm_135m", 0, 5, name=f"r{i}") for i in range(3)]))
     before, (name, *args) = HEALTH_EVENTS[event]
     if before:
         getattr(sched, before[0])(*before[1:])
@@ -342,3 +344,73 @@ def test_backlog_from_host_copies_matches_the_fetch(stream):
                      for r in want.trace.records]
     assert any(r.backlog_before > 0 for r in strip)
     assert got_done == want_done
+
+
+@pytest.mark.parametrize("engine", ["indexed", "ref"])
+def test_withdraw_leaves_the_state_of_never_committing(engine):
+    """``withdraw`` takes a committed window back: ledger, host and device
+    queues equal those of a scheduler that never committed it, the commit
+    log records the removal, and the replan snapshot is gone."""
+    from repro.serving.online import OnlineScheduler
+    sc = make_scenario("paper-small", seed=0)
+    rng = np.random.default_rng(3)
+    kept, taken = sc.sample_jobs(rng, 3), sc.sample_jobs(rng, 2)
+
+    def sched_with(*windows):
+        s = OnlineScheduler(sc.topology, drain="exact", track_commits=True,
+                            sim_engine=engine)
+        for jobs in windows:
+            s.submit_window(0.5, jobs, pad_to=sc.max_layers)
+        return s
+
+    s, ref = sched_with(kept, taken), sched_with(kept)
+    names = [j.name for j in taken]
+    s.withdraw(names, at=0.5)
+    assert [j.name for j in s.ledger.jobs] == [j.name for j in ref.ledger.jobs]
+    for got, want in zip((*s._queues, *s.ledger.queue_arrays()),
+                         (*ref._queues, *ref.ledger.queue_arrays())):
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(np.asarray(s.state.q_node), ref._queues[0])
+    np.testing.assert_array_equal(np.asarray(s.state.q_link), ref._queues[1])
+    assert s.commit_log.removed[-len(names):] == tuple(
+        (0.5, n) for n in names)
+    assert s.replan_last() is None
+    assert s.last_replan_reason == "no_batch"
+
+
+def test_pipeline_reaches_each_patched_hook_once_per_window():
+    """The benchmark's hooks: with ``submit_window`` and ``advance_to``
+    replaced on the scheduler instance and a counting ``"greedy"`` in the
+    solver registry, a batched pipeline (windows of up to 4, one window per
+    dispatch) calls each exactly once per window."""
+    from repro.core import solvers
+    from repro.serving.online import OnlineScheduler
+    from repro.serving.stream import StreamConfig, StreamingPipeline
+    sc = make_scenario("paper-small", seed=0)
+    rng = np.random.default_rng(4)
+    sched = OnlineScheduler(sc.topology, method="greedy", drain="exact")
+    pipe = StreamingPipeline(sched, StreamConfig(
+        window_s=0.05, max_batch=4, solve_mode="batched", solver_latency=0.0,
+        fuse_windows=1))
+    calls = {"submit_window": 0, "advance_to": 0, "greedy": 0}
+    greedy = solvers.get("greedy")
+    submit_window, advance_to = sched.submit_window, sched.advance_to
+
+    def counted(name, fn):
+        def call(*args, **kwargs):
+            calls[name] += 1
+            return fn(*args, **kwargs)
+        return call
+
+    solvers.register("greedy")(counted("greedy", greedy))
+    sched.submit_window = counted("submit_window", submit_window)
+    sched.advance_to = counted("advance_to", advance_to)
+    try:
+        stream = [(0.02 * i, sc.sample_jobs(rng, 1)) for i in range(12)]
+        trace = pipe.run(stream, pad_to=sc.max_layers)
+    finally:
+        solvers.register("greedy")(greedy)
+        del sched.submit_window, sched.advance_to
+    windows = len(trace.windows)
+    assert windows >= 3 and max(w.size for w in trace.windows) > 1
+    assert calls == dict.fromkeys(calls, windows)

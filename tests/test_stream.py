@@ -149,7 +149,7 @@ def test_sequential_mode_commits_serial_plans(star):
                             solve_mode="sequential")
     serial = OnlineScheduler(star.topology)
     want = [p for j in jobs
-            for p in serial.submit_jobs(2.0, [j], pad_to=star.max_layers)]
+            for p in serial.submit_window(2.0, [j], pad_to=star.max_layers)]
     assert [p.job_name for p in got] == [p.job_name for p in want]
     assert [p.bound_s for p in got] == [p.bound_s for p in want]
     assert [p.assign.tolist() for p in got] == \
