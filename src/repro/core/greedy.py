@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .network import ComputeNetwork
+from . import jobs as J
 from .jobs import JobBatch
 from .plan import Plan
 from . import routing
@@ -213,56 +214,62 @@ def greedy_route(net: ComputeNetwork, batch: JobBatch,
     fused-dispatch accounting (``fused``/``dispatches``/
     ``rounds_per_dispatch``) plus ``jit_compiled`` —
     True when this call traced+compiled a new shape signature, the wall
-    the serving warm-up exists to keep out of latency models.
+    the serving warm-up exists to keep out of latency models.  A host
+    batch (the serving scheduler's) is staged with no fetch; a device
+    batch is fetched once first (``batch_fetches``).
     """
     if lazy or not share_closures:
-        return greedy_route_ref(net, batch, use_pallas=use_pallas,
+        return greedy_route_ref(net, J.device_batch(batch),
+                                use_pallas=use_pallas,
                                 lazy=lazy, share_closures=share_closures,
                                 extract_paths=extract_paths)
-    J = batch.num_jobs
     with telemetry.span("greedy.stage"):
-        padded, dplan, routed0 = _stage_window(batch)
+        host = J.host_batch(batch)
+        padded, dplan, routed0 = _stage_window(host)
     with telemetry.span("greedy.dispatch"):
         out, compiled = telemetry.call_counted(
             "fused_dispatches", _fused_solve, net, padded, dplan, routed0,
             use_pallas=use_pallas)
     (order, costs, assigns, hops), q_node, q_link = out
     with telemetry.span("greedy.fetch"):
-        order, costs, assigns, num_layers_h, hops = telemetry.to_host(
-            (order, costs, assigns, batch.num_layers,
-             hops if extract_paths else None))
+        order, costs, assigns, hops = telemetry.to_host(
+            (order, costs, assigns, hops if extract_paths else None))
     # drop padding rounds; every round is real in the common unpadded
     # serving case, where the mask gathers would be pure eager overhead
     keep = slice(None) if (order >= 0).all() else order >= 0
     paths = None
     if extract_paths:
         with telemetry.span("greedy.paths"):
-            paths = _round_paths(order[keep], hops[keep], num_layers_h)
+            paths = _round_paths(order[keep], hops[keep], host.num_layers)
     with telemetry.span("greedy.assemble"):
         return _assemble_plan(
-            batch, net.with_queues(q_node, q_link), order[keep],
+            host, net.with_queues(q_node, q_link), order[keep],
             costs[keep], assigns[keep], paths,
-            meta=_fused_meta(J, rounds=padded.num_jobs, compiled=compiled))
+            meta=_fused_meta(host.num_jobs, rounds=padded.num_jobs,
+                             compiled=compiled))
 
 
 def _stage_window(batch: JobBatch) -> tuple:
-    """:func:`_fused_solve`'s operands for one window: the batch padded to
-    a power of two, its bucketed dedupe plan, and the pre-routed mask that
-    keeps the dummy jobs out of every round."""
-    J = batch.num_jobs
-    j_pad = _next_pow2(J)
+    """:func:`_fused_solve`'s operands for one window, in one upload: the
+    batch padded to a power of two, its bucketed dedupe plan, and the
+    pre-routed mask that keeps the dummy jobs out of every round.  A host
+    batch (the scheduler's) is staged with no fetch."""
+    batch = J.host_batch(batch)
+    n = batch.num_jobs
+    j_pad = _next_pow2(n)
     padded = _pad_batch(batch, j_pad)
-    dplan, routed0 = telemetry.to_device(
-        (_bucket_dplan(SP.dedupe_plan_host(padded)), np.arange(j_pad) >= J))
-    return padded, dplan, routed0
+    return telemetry.to_device(
+        (padded, _bucket_dplan(SP.dedupe_plan_host(padded)),
+         np.arange(j_pad) >= n))
 
 
 def _stage_windows(batches: list[JobBatch]) -> tuple:
     """:func:`_fused_solve_many`'s operands for W windows: the per-window
-    padded batches (unstacked), then the stacked batches, stacked dedupe
-    plans and the [W, J] mask of real jobs."""
-    j_max = _next_pow2(max(b.num_jobs for b in batches))
-    padded = [_pad_batch(b, j_max) for b in batches]
+    padded host batches, then, in one upload, the stacked batches, the
+    stacked dedupe plans and the [W, J] mask of real jobs."""
+    hosts = [J.host_batch(b) for b in batches]
+    j_max = _next_pow2(max(b.num_jobs for b in hosts))
+    padded = [_pad_batch(b, j_max) for b in hosts]
     dplans = [SP.dedupe_plan_host(b) for b in padded]
     u_max = _next_pow2(max(d.uniq.shape[0] for d in dplans))
     d_max = _next_pow2(max(d.d_vals.shape[0] for d in dplans))
@@ -270,11 +277,11 @@ def _stage_windows(batches: list[JobBatch]) -> tuple:
     dplans = jax.tree_util.tree_map(lambda *leaves: np.stack(leaves),
                                     *dplans)
     valid = np.array(
-        [[1] * b.num_jobs + [0] * (j_max - b.num_jobs) for b in batches],
+        [[1] * b.num_jobs + [0] * (j_max - b.num_jobs) for b in hosts],
         bool)
-    stacked = jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves),
+    stacked = jax.tree_util.tree_map(lambda *leaves: np.stack(leaves),
                                      *padded)
-    return (padded, stacked) + telemetry.to_device((dplans, valid))
+    return (padded,) + telemetry.to_device((stacked, dplans, valid))
 
 
 def _next_pow2(n: int) -> int:
@@ -291,23 +298,22 @@ def _next_pow2(n: int) -> int:
 
 
 def _pad_batch(batch: JobBatch, j_to: int) -> JobBatch:
-    """Pad a window's batch to ``j_to`` jobs with inert dummies (zero
+    """Pad a window's host batch to ``j_to`` jobs with inert dummies (zero
     compute/data, src=dst=0) — they are pre-routed in the fused scan, so
     they never route, commit, or perturb real jobs' values."""
-    J = batch.num_jobs
-    if J == j_to:
+    n = batch.num_jobs
+    if n == j_to:
         return batch
-    pad = j_to - J
-    host = telemetry.to_host(batch)
+    pad = j_to - n
 
     def pad0(x):
         return np.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
 
-    return telemetry.to_device(JobBatch(
-        src=pad0(host.src), dst=pad0(host.dst), comp=pad0(host.comp),
-        data=pad0(host.data),
-        num_layers=pad0(host.num_layers) + np.array([0] * J + [1] * pad,
-                                                    np.int32)))
+    return JobBatch(
+        src=pad0(batch.src), dst=pad0(batch.dst), comp=pad0(batch.comp),
+        data=pad0(batch.data),
+        num_layers=pad0(batch.num_layers) + np.array([0] * n + [1] * pad,
+                                                     np.int32))
 
 
 def _pad_dplan(dplan: SP.DedupePlan, u_to: int, d_to: int) -> SP.DedupePlan:
@@ -367,10 +373,9 @@ def greedy_route_windows(net: ComputeNetwork, batches: list[JobBatch],
         # host copies: per-window numpy indexing is free, while indexing
         # the device arrays with python ints / numpy masks would
         # implicitly stage the indices
-        orders, costs, assigns, q_nodes, q_links, num_layers, hops = (
-            telemetry.to_host((orders, costs, assigns, q_nodes, q_links,
-                               stacked.num_layers,
-                               hops if extract_paths else None)))
+        orders, costs, assigns, q_nodes, q_links, hops = telemetry.to_host(
+            (orders, costs, assigns, q_nodes, q_links,
+             hops if extract_paths else None))
     plans = []
     for w, batch in enumerate(batches):
         J = batch.num_jobs
@@ -379,7 +384,8 @@ def greedy_route_windows(net: ComputeNetwork, batches: list[JobBatch],
         paths = None
         if extract_paths:
             with telemetry.span("greedy.paths"):
-                paths = _round_paths(order_w, hops[w][keep], num_layers[w])
+                paths = _round_paths(order_w, hops[w][keep],
+                                     padded[w].num_layers)
         with telemetry.span("greedy.assemble"):
             plans.append(_assemble_plan(
                 batch, net.with_queues(*telemetry.to_device(

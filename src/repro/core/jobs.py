@@ -9,7 +9,10 @@ inference-result size.
 
 For vmap-friendly multi-job routing, jobs are padded to a common max layer
 count in :class:`JobBatch`; padded layers have zero compute and zero data and
-are masked out of every cost term.
+are masked out of every cost term.  A batch's leaves live either on the
+device (:func:`batch_jobs`) or on the host (:func:`batch_jobs_host`, the
+serving scheduler's copy); :func:`host_batch` and :func:`device_batch` move
+one to the other only when it is not already there.
 """
 from __future__ import annotations
 
@@ -69,7 +72,7 @@ class InferenceJob:
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class JobBatch:
-    """Padded batch of J jobs (a JAX pytree)."""
+    """Padded batch of J jobs (a JAX pytree; jax or numpy leaves)."""
 
     src: jax.Array        # [J] int32
     dst: jax.Array        # [J] int32
@@ -86,9 +89,10 @@ class JobBatch:
         return self.comp.shape[1]
 
 
-def batch_jobs(jobs: Sequence[InferenceJob], *, pad_to: int | None = None) -> JobBatch:
+def batch_jobs_host(jobs: Sequence[InferenceJob], *,
+                    pad_to: int | None = None) -> JobBatch:
     """Pad jobs to a common layer count (``pad_to`` pins the padded width so
-    batches of varying composition share one jit shape)."""
+    batches of varying composition share one jit shape); numpy leaves."""
     if not jobs:
         raise ValueError("empty job list")
     lmax = max(j.num_layers for j in jobs)
@@ -111,8 +115,29 @@ def batch_jobs(jobs: Sequence[InferenceJob], *, pad_to: int | None = None) -> Jo
         # data entry stays 0 so transfers of padded layers are free and the
         # true final transfer d_L is handled by the masked DP epilogue.
         src[i], dst[i], nl[i] = j.src, j.dst, L
-    return telemetry.to_device(
-        JobBatch(src=src, dst=dst, comp=comp, data=data, num_layers=nl))
+    return JobBatch(src=src, dst=dst, comp=comp, data=data, num_layers=nl)
+
+
+def batch_jobs(jobs: Sequence[InferenceJob], *, pad_to: int | None = None) -> JobBatch:
+    """:func:`batch_jobs_host`, uploaded."""
+    return telemetry.to_device(batch_jobs_host(jobs, pad_to=pad_to))
+
+
+def host_batch(batch: JobBatch) -> JobBatch:
+    """``batch`` with numpy leaves: itself when it has them, else one
+    fetch, counted as ``batch_fetches`` (a served batch never needs it)."""
+    if isinstance(batch.data, np.ndarray):
+        return batch
+    telemetry.count("batch_fetches")
+    return telemetry.to_host(batch)
+
+
+def device_batch(batch: JobBatch) -> JobBatch:
+    """``batch`` with device leaves: itself when it has them, else one
+    upload (for code that indexes or jits the batch directly)."""
+    if isinstance(batch.data, np.ndarray):
+        return telemetry.to_device(batch)
+    return batch
 
 
 def synthetic_job(

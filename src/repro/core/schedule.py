@@ -49,8 +49,9 @@ import dataclasses
 import numpy as np
 
 from .network import ComputeNetwork
+from . import jobs as J
 from .jobs import JobBatch
-from . import routing, telemetry
+from . import routing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,11 +86,12 @@ def replay_solution(net: ComputeNetwork, batch: JobBatch, assign, order=None):
 
     assign, order = _as_assign_order(assign, order)
     assign = jnp.asarray(assign, jnp.int32)
-    J = batch.num_jobs
-    bounds = np.zeros((J,), np.float64)
+    batch = J.device_batch(batch)
+    n = batch.num_jobs
+    bounds = np.zeros((n,), np.float64)
     paths: dict[int, list[list[tuple[int, int]]]] = {}
     cur = net
-    for p in range(J):
+    for p in range(n):
         j = int(order[p])
         args = (batch.comp[j], batch.data[j], batch.src[j], batch.dst[j],
                 batch.num_layers[j])
@@ -116,12 +118,14 @@ def job_stages(batch: JobBatch, assign,
     comes before layer l+1's output hops — so layer k's transfer cannot
     start (and its bytes cannot occupy a link) before layer k's compute
     completes.  This is the precedence structure both the one-shot
-    simulator and the incremental committed-work drain honour.
+    simulator and the incremental committed-work drain honour.  Reads the
+    host copy of a host batch (the serving scheduler's) and fetches a
+    device batch once (``batch_fetches``).
     """
-    comp, data, nl = telemetry.to_host(
-        (batch.comp, batch.data, batch.num_layers))
-    comp = np.asarray(comp, np.float64)
-    data = np.asarray(data, np.float64)
+    host = J.host_batch(batch)
+    comp = np.asarray(host.comp, np.float64)
+    data = np.asarray(host.data, np.float64)
+    nl = host.num_layers
     a = np.asarray(assign)
     stages: dict[int, list[Stage]] = {}
     for j in range(batch.num_jobs):
@@ -316,10 +320,10 @@ def simulate(net: ComputeNetwork, batch: JobBatch, assign, order=None,
 
     mu_node = np.asarray(net.mu_node, np.float64)
     mu_link = np.asarray(net.mu_link, np.float64)
-    J = batch.num_jobs
     prio_of = {int(order[p]): p for p in range(len(order))}
     stages = job_stages(batch, assign, paths)
-    tasks = [TaskRun(stages=stages[j], prio=prio_of[j]) for j in range(J)]
+    tasks = [TaskRun(stages=stages[j], prio=prio_of[j])
+             for j in range(batch.num_jobs)]
     run_event_loop(tasks, mu_node, mu_link, engine=engine)
     completion = np.array([task.completion for task in tasks], np.float64)
     return SimResult(completion=completion, makespan=float(np.max(completion)))

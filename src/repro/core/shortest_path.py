@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
-from . import telemetry
+from . import jobs as J, telemetry
 from .network import INF, ComputeNetwork, link_invrate, link_wait
 
 
@@ -116,7 +116,7 @@ def dedupe_data(batch) -> tuple[jax.Array, jax.Array]:
 
 
 def _dedupe_rows(batch) -> tuple[np.ndarray, np.ndarray]:
-    data = np.asarray(telemetry.to_host(batch.data))
+    data = J.host_batch(batch).data
     uniq, inv = np.unique(data, axis=0, return_inverse=True)
     return uniq, inv.reshape(-1).astype(np.int32)
 
@@ -152,7 +152,7 @@ def dedupe_plan(batch) -> DedupePlan:
 
 def dedupe_plan_host(batch) -> DedupePlan:
     """:func:`dedupe_plan` with numpy leaves, for staging that pads it on
-    the host before the one transfer."""
+    the host before the one transfer (from a host batch, with no fetch)."""
     uniq, inv = _dedupe_rows(batch)
     d_vals, d_idx = np.unique(uniq, return_inverse=True)
     return DedupePlan(uniq=uniq, inv=inv, d_vals=d_vals,

@@ -28,6 +28,7 @@ from typing import Callable, Protocol, runtime_checkable
 
 from .network import ComputeNetwork
 from .state import QueueState, Topology
+from . import jobs as J
 from .jobs import JobBatch
 from .plan import Plan
 from .shortest_path import closure_build_count
@@ -85,6 +86,11 @@ def solve(net: ComputeNetwork | Topology, batch: JobBatch,
     builds the solve triggered (``meta["closure_builds"]`` — the hot-spot
     metric the closure-reuse pipeline minimizes) on top of whatever the
     solver itself reports.
+
+    ``batch`` may hold host leaves (``jobs.batch_jobs_host``, the serving
+    scheduler's copy): whatever is registered as ``"greedy"`` is handed it
+    as it is (the fused greedy stages it with no fetch), and any other
+    method gets it through one explicit upload.
     """
     if isinstance(net, Topology):
         net = net.view(state)
@@ -94,6 +100,8 @@ def solve(net: ComputeNetwork | Topology, batch: JobBatch,
     n0 = closure_build_count()
     t0 = time.perf_counter()
     with telemetry.span("solve"):
+        if method != "greedy":   # the fused greedy stages a host batch
+            batch = J.device_batch(batch)
         plan = fn(net, batch, **opts)
     if not isinstance(plan, Plan):
         raise TypeError(f"solver {method!r} returned {type(plan).__name__}, "

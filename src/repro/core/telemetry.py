@@ -25,7 +25,9 @@ nothing is written anywhere until then.
   transfers, counted as ``h2d`` and ``d2h`` (one per call, which may carry
   a pytree; a ``d2h`` is one wait for the device), and their bytes as
   ``h2d_bytes`` and ``d2h_bytes`` (the summed ``nbytes`` of the pytree's
-  array leaves).
+  array leaves).  ``batch_fetches`` counts the fetches of a job batch that
+  had no host copy (``jobs.host_batch``): 0 on the served path, whose
+  batches are built on the host.
 """
 from __future__ import annotations
 

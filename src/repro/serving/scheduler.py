@@ -380,7 +380,7 @@ class RoutedScheduler:
 
     # Solvers that can fill plan.paths during the solve, reusing each
     # round's closures (greedy.greedy_route(extract_paths=True)).  For any
-    # other method _ledger_commit falls back to a full replay_solution.
+    # other method _commit_plan falls back to a full replay_solution.
     _PATH_SOLVERS = ("greedy", "greedy_ref", "lazy")
 
     def _solve_opts(self, method: str) -> dict:
@@ -400,17 +400,26 @@ class RoutedScheduler:
         :meth:`_commit_windows` (``pre_state`` = the queue state that
         window was solved against) or from :meth:`replan_last`.
         """
-        if plan.net is None:  # e.g. the exact solver reports no queue state
+        logged = self.ledger is not None or self.commit_log is not None
+        if plan.net is None or (logged and plan.paths is None):
+            # The exact solver reports no queue state, and only the
+            # _PATH_SOLVERS fill paths: one replay against the solve-time
+            # queues gives both, the hops the plan's bounds charged
+            # (Alg. 1 / Alg. 2 semantics).
+            from repro.core import schedule
+            _, paths, net = schedule.replay_solution(
+                topo.view(pre_state), batch, plan.assign, plan.order)
             plan = dataclasses.replace(
-                plan, net=plan.commit(topo.view(pre_state), batch))
+                plan, net=net if plan.net is None else plan.net,
+                paths=paths if logged else plan.paths)
         if self.ledger is None:
             # Committed backlogs come from the plan; the clock is ours to
             # keep.  (In exact mode the ledger sync below is authoritative,
             # so the fluid commit would be a dead store.)
             self.state = self.state.with_queues(plan.net.q_node,
                                                 plan.net.q_link)
-        if self.ledger is not None or self.commit_log is not None:
-            plan = self._ledger_commit(topo, batch, plan, pre_state, names)
+        if logged:
+            self._ledger_commit(topo, batch, plan, names)
         self.last_plan = plan
         # Fused multi-window plans carry the shared dispatch's wall in
         # solve_s and their per-window share in solve_share_s; accumulate
@@ -421,17 +430,9 @@ class RoutedScheduler:
         return plan
 
     def _ledger_commit(self, topo: Topology, batch: J.JobBatch, plan: Plan,
-                       pre_state: QueueState,
-                       names: list[str] | None) -> Plan:
+                       names: list[str] | None) -> None:
         """Record the committed plan's work items (exact ledger and/or the
-        ground-truth commit log)."""
-        from repro.core import schedule
-        if plan.paths is None:
-            # Paths against the solve-time queue state — exactly the hops
-            # the plan's bounds charged (Alg. 1 / Alg. 2 semantics).
-            _, paths, _ = schedule.replay_solution(
-                topo.view(pre_state), batch, plan.assign, plan.order)
-            plan = dataclasses.replace(plan, paths=paths)
+        ground-truth commit log); ``plan`` carries its paths."""
         if self.ledger is not None:
             self.ledger = self.ledger.commit(batch, plan, names=names,
                                              at=self._now)
@@ -446,16 +447,18 @@ class RoutedScheduler:
             self.commit_log = self.commit_log.commit(batch, plan,
                                                      names=names,
                                                      at=self._now)
-        return plan
 
     def _solve(self, windows: list[list[J.InferenceJob]], *,
                pad_to: int | None, method: str
                ) -> tuple[list[J.JobBatch], list[Plan]]:
         """Pure solve of W >= 1 windows, each against the previous one's
         committed queues: one window through ``solvers.solve`` (the
-        registry is read at call time), several in one fused dispatch."""
+        registry is read at call time), several in one fused dispatch.
+        The batches stay on the host for the whole decision: the solver
+        uploads what it runs, and the ledger reads them with no fetch."""
         with telemetry.span("sched.batch"):
-            batches = [J.batch_jobs(jobs, pad_to=pad_to) for jobs in windows]
+            batches = [J.batch_jobs_host(jobs, pad_to=pad_to)
+                       for jobs in windows]
         opts, topo = self._solve_opts(method), self._effective_topology()
         if len(batches) == 1:
             return batches, [solvers.solve(topo, batches[0], method=method,
@@ -591,13 +594,14 @@ class RoutedScheduler:
         cyc = list(itertools.islice(itertools.cycle(sample_jobs), sizes[-1]))
         compiles = 0
         for size in sizes:
-            plan = solvers.solve(topo, J.batch_jobs(cyc[:size], pad_to=pad_to),
+            plan = solvers.solve(topo,
+                                 J.batch_jobs_host(cyc[:size], pad_to=pad_to),
                                  method=self.method, state=self.state, **opts)
             compiles += int(plan.meta.get("jit_compiled", False))
         for w in window_counts:
             if w < 2:
                 continue
-            batches = [J.batch_jobs(cyc[: sizes[-1]], pad_to=pad_to)
+            batches = [J.batch_jobs_host(cyc[: sizes[-1]], pad_to=pad_to)
                        for _ in range(w)]
             plans = solvers.solve_fused(topo, batches, state=self.state,
                                         pad_to=pad_to, **opts)
@@ -608,7 +612,7 @@ class RoutedScheduler:
         # taken again if an unseen shape slipped in.
         for _ in range(2):
             t1 = time.perf_counter()
-            plan = solvers.solve(topo, J.batch_jobs(cyc, pad_to=pad_to),
+            plan = solvers.solve(topo, J.batch_jobs_host(cyc, pad_to=pad_to),
                                  method=self.method, state=self.state, **opts)
             warm = time.perf_counter() - t1
             if not plan.meta.get("jit_compiled", False):

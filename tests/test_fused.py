@@ -10,9 +10,11 @@ import numpy as np
 import pytest
 
 from repro.core import greedy, jobs as J, network as N, solvers, telemetry
-from repro.core import shortest_path as SP
+from repro.core import schedule, shortest_path as SP
 from repro.scenarios import make_scenario
 from repro.serving.online import OnlineScheduler
+from repro.serving.scheduler import RoutedScheduler
+from repro.serving.stream import StreamConfig, StreamingPipeline
 from util import random_instance
 
 
@@ -341,3 +343,145 @@ def test_warmup_is_pure_and_caches():
     # warmed shapes: a second warmup compiles nothing
     again = s.warmup(sample, pad_to=sc.max_layers, window_counts=(2,))
     assert again["compiles"] == 0
+
+
+# ---------------------------------------------------------------------------
+# The scheduler's host batch: staged and committed with no fetch
+# ---------------------------------------------------------------------------
+
+def _moved(keys, fn):
+    before = {k: telemetry.counter(k) for k in keys}
+    out = fn()
+    return out, {k: telemetry.counter(k) - v for k, v in before.items()}
+
+
+@pytest.mark.parametrize("size", [1, 4, 3], ids=["one", "full", "ragged"])
+@pytest.mark.parametrize("scenario,kw", [
+    ("us-backbone:paper", {}), ("fat-tree:paper", {"k": 4})],
+    ids=["usnet", "fat-tree-k4"])
+def test_host_batch_solves_as_its_device_copy(scenario, kw, size):
+    """``greedy_route`` on a host batch and on its device copy gives
+    bit-identical order, assign, bounds, paths and post-commit queues, at
+    a queued state, for a one-job, a full and a ragged window (3 jobs,
+    padded to 4 on the host).  The host batch is staged with no fetch:
+    its solve waits once, for the scan's results.  ``job_stages`` reads
+    the same stages from either copy."""
+    sc = make_scenario(scenario, seed=0, **kw)
+    rng = np.random.default_rng(60 + size)
+    first = J.batch_jobs(sc.sample_jobs(rng, 4), pad_to=sc.max_layers)
+    net = greedy.greedy_route(sc.topology.view(), first).net
+    host = J.batch_jobs_host(sc.sample_jobs(rng, size), pad_to=sc.max_layers)
+    device = telemetry.to_device(host)
+    ref = greedy.greedy_route(net, device, extract_paths=True)
+    with jax.transfer_guard("disallow"):
+        plan, moved = _moved(
+            ("d2h", "batch_fetches"),
+            lambda: greedy.greedy_route(net, host, extract_paths=True))
+    assert moved == {"d2h": 1, "batch_fetches": 0}
+    _assert_plans_bitwise(plan, ref, paths=True)
+    assert set(plan.paths) == set(range(size))
+    stages, moved = _moved(("d2h", "batch_fetches"), lambda: (
+        schedule.job_stages(host, plan.assign, plan.paths)))
+    assert moved == {"d2h": 0, "batch_fetches": 0}
+    assert stages == schedule.job_stages(device, ref.assign, ref.paths)
+
+
+@pytest.mark.parametrize("wrapped", [False, True], ids=["plain", "wrapped"])
+def test_a_recorded_pipeline_fetches_no_batch(wrapped, monkeypatch):
+    """The served path (``StreamingPipeline`` over an exact-drain
+    ``OnlineScheduler``) in ragged 3-job windows: the recorder counts no
+    ``batch_fetches`` and one wait per batch, also with ``"greedy"``
+    re-registered as a plain wrapper, as the benchmark times it."""
+    if wrapped:
+        fused = solvers.get("greedy")
+        monkeypatch.setitem(solvers._REGISTRY, "greedy",
+                            lambda net, batch, **opts: fused(net, batch,
+                                                             **opts))
+    sc = make_scenario("us-backbone:paper", seed=0)
+    rng = np.random.default_rng(70)
+    pipe = StreamingPipeline(
+        OnlineScheduler(sc.topology, method="greedy", drain="exact"),
+        StreamConfig(window_s=0.0, max_batch=3, solve_mode="batched",
+                     solver_latency=0.0))
+    gap = 3 / sc.nominal_rate(0.8)
+
+    def run(t0, n):
+        epochs = [(t0 + gap * (i + 1), sc.sample_jobs(rng, 3))
+                  for i in range(n)]
+        assert not pipe.run(epochs, pad_to=sc.max_layers).shed
+
+    run(0.0, 2)                      # compiles every program the path runs
+    telemetry.enable()
+    try:
+        run(3 * gap, 3)
+    finally:
+        telemetry.disable()
+    rec = telemetry.snapshot()
+    batches = sum(s[0] == "sched.submit_window" for s in rec["spans"])
+    assert batches == 3
+    assert rec["counters"].get("batch_fetches", 0) == 0
+    assert rec["counters"]["d2h"] == batches
+
+
+def _spy_batch_uploads(monkeypatch) -> list:
+    """Record the type of each pytree ``telemetry.to_device`` uploads."""
+    uploads, to_device = [], telemetry.to_device
+
+    def spy(x):
+        uploads.append(type(x))
+        return to_device(x)
+
+    monkeypatch.setattr(telemetry, "to_device", spy)
+    return uploads
+
+
+@pytest.mark.parametrize("method", ["greedy_ref", "lazy"])
+def test_a_method_that_jits_the_batch_gets_one_upload(method, monkeypatch):
+    """The scheduler holds host batches; a method that indexes or jits
+    the batch itself gets its device copy through one explicit upload in
+    ``solvers.solve``.  The ledger reads the host copy; the one batch
+    fetch is the reference solver's own dedupe of its device copy."""
+    sc = make_scenario("us-backbone:paper", seed=0)
+    seen, solver = [], solvers.get(method)
+
+    def watched(net, batch, **opts):
+        seen.append(isinstance(batch.data, jax.Array))
+        return solver(net, batch, **opts)
+
+    monkeypatch.setitem(solvers._REGISTRY, method, watched)
+    sched = RoutedScheduler(sc.topology, method=method, drain="exact")
+    uploads = _spy_batch_uploads(monkeypatch)
+    jobs = sc.sample_jobs(np.random.default_rng(80), 3)
+    _, moved = _moved(("batch_fetches",), lambda: sched.schedule_jobs(
+        jobs, pad_to=sc.max_layers))
+    assert seen == [True]
+    assert uploads.count(J.JobBatch) == 1
+    assert moved == {"batch_fetches": 1}
+    assert sched.ledger.jobs and sched.last_plan.paths is not None
+
+
+def test_a_jitting_method_runs_under_the_transfer_guard(monkeypatch):
+    """A registered method that jits the batch it is handed runs from the
+    scheduler under ``transfer_guard("disallow")``: the host batch reaches
+    it through ``solvers.solve``'s one counted upload, never an implicit
+    transfer."""
+    sc = make_scenario("us-backbone:paper", seed=0)
+    probe = jax.jit(lambda b: b.comp.sum())
+
+    def jit_probe(net, batch, **opts):
+        probe(batch)
+        return greedy.greedy_route(net, batch, **opts)
+
+    monkeypatch.setitem(solvers._REGISTRY, "jit_probe", jit_probe)
+    sched = RoutedScheduler(sc.topology, method="jit_probe")
+    rng = np.random.default_rng(90)
+    sched.schedule_jobs(sc.sample_jobs(rng, 2), pad_to=sc.max_layers)
+    uploads = _spy_batch_uploads(monkeypatch)
+    with jax.transfer_guard("disallow"):
+        placed, moved = _moved(("batch_fetches",), lambda: (
+            sched.schedule_jobs(sc.sample_jobs(rng, 2),
+                                pad_to=sc.max_layers)))
+    assert len(placed) == 2
+    assert uploads.count(J.JobBatch) == 1
+    # the fused greedy stages the device copy it was handed: one fetch
+    assert moved == {"batch_fetches": 1}

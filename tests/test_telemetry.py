@@ -147,14 +147,15 @@ def test_every_transfer_is_counted(served, monkeypatch):
 
 def test_a_healthy_batch_moves_only_its_own_data(served):
     """After warm-up a healthy window builds no effective topology and
-    fetches no rate or queue the host holds: per batch 3 waits (the
-    staging's and the ledger's fetches of the batch, the scan's one fetch
-    of its results and hops) and 5 uploads (the batch, the dedupe plan,
-    two queue syncs, the clock)."""
+    fetches nothing the host holds (rates, queues, the batch): per batch
+    one wait (the scan's one fetch of its results and hops) and 4 uploads
+    (the staged batch with its dedupe plan and mask, two queue syncs, the
+    clock)."""
     n = 3
     counters = _recorded(served, n)["counters"]
     assert counters.get("topology_builds", 0) == 0
-    assert (counters["d2h"], counters["h2d"]) == (3 * n, 5 * n)
+    assert counters.get("batch_fetches", 0) == 0
+    assert (counters["d2h"], counters["h2d"]) == (1 * n, 4 * n)
 
 
 def _tree():
@@ -191,25 +192,31 @@ def _batch(sc, n, seed=0):
                         pad_to=sc.max_layers)
 
 
-def test_a_solve_with_paths_is_one_program_and_one_fetch():
+@pytest.mark.parametrize("on_host", [True, False], ids=["host", "device"])
+def test_a_solve_with_paths_is_one_program_and_one_fetch(on_host):
     """``greedy_route(extract_paths=True)`` on a k=4 fat-tree runs one
-    device program, uploads once (the dedupe plan and the mask) and waits
-    twice: the staging's fetch of the batch's data, then one fetch of the
-    round outputs, hops included, and the layer counts.  The bytes fetched
-    are exactly those leaves'."""
+    device program and uploads once (the padded batch, the dedupe plan and
+    the mask).  A host batch costs one wait, the fetch of the round
+    outputs with their hops, and fetches exactly those bytes; a device
+    batch (the fallback) costs one fetch of the batch more, counted in
+    ``batch_fetches``."""
     sc = make_scenario("fat-tree:paper", seed=0, k=4)
-    net, batch = sc.topology.view(), _batch(sc, 4)
+    host = J.batch_jobs_host(sc.sample_jobs(np.random.default_rng(0), 4),
+                             pad_to=sc.max_layers)
+    net, batch = sc.topology.view(), host if on_host else _batch(sc, 4)
     greedy.greedy_route(net, batch, extract_paths=True)
     rounds, _, _ = jax.eval_shape(greedy._fused_solve, net,
-                                  *greedy._stage_window(batch))
-    fetched = (batch.data, batch.num_layers) + tuple(rounds)
+                                  *greedy._stage_window(host))
+    fetched = tuple(rounds) if on_host else (host,) + tuple(rounds)
     n0 = telemetry.counter("fused_dispatches")
-    before = {k: telemetry.counter(k) for k in ("h2d", "d2h", "d2h_bytes")}
+    before = {k: telemetry.counter(k)
+              for k in ("h2d", "d2h", "d2h_bytes", "batch_fetches")}
     with jax.transfer_guard("disallow"):
         plan = greedy.greedy_route(net, batch, extract_paths=True)
     moved = {k: telemetry.counter(k) - v for k, v in before.items()}
     assert telemetry.counter("fused_dispatches") - n0 == 1
-    assert (moved["h2d"], moved["d2h"]) == (1, 2)
+    assert (moved["h2d"], moved["d2h"]) == (1, 1 if on_host else 2)
+    assert moved["batch_fetches"] == (0 if on_host else 1)
     assert moved["d2h_bytes"] == sum(
         np.dtype(x.dtype).itemsize * int(np.prod(x.shape))
         for x in jax.tree_util.tree_leaves(fetched))
