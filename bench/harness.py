@@ -307,7 +307,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         from bench import trace as TR
         try:
             view.trace = TR.reduce(tracer["dir"],
-                                   programs=("_fused_solve", "_walk_paths"))
+                                   programs=("_fused_solve",))
         finally:
             shutil.rmtree(tracer["dir"], ignore_errors=True)
         view.trace_window_s = tracer["t1"] - tracer["t0"]

@@ -1,6 +1,6 @@
 """Fused solver (``core/greedy.py``): wall of the program's span
-``greedy.paths`` per traced batch — the path post-pass: its operands'
-round trip, the ``_walk_paths`` dispatch and its fetch, ms."""
+``greedy.paths`` per traced batch: formatting the layer paths on the
+host from the hops the fused solve fetched with its results, ms."""
 from bench.metrics import _program as P
 
 

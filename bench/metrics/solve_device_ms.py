@@ -1,6 +1,6 @@
-"""Device: time of the programs ``_fused_solve`` and ``_walk_paths`` per
-batch in the traced part of the window, from the device trace, ms.
-Nothing to read without a device in the trace."""
+"""Device: time of the program ``_fused_solve`` per batch in the traced
+part of the window, from the device trace, ms.  Nothing to read without a
+device in the trace."""
 
 
 def read(run):

@@ -4,17 +4,22 @@ It imports nothing of the program.  Three parts, each the straightforward
 form of what the program computes:
 
 * **Algorithm 1's cost model** (arXiv:2111.07006 §III).  For a data size
-  ``d`` the edge weight of link (u, v) is ``(d + Q_uv) / mu_uv``; its
-  min-plus closure ``T`` is taken by Floyd-Warshall.  A job's bound on a
-  route is the transfer of ``data[0]`` from its source to the first layer's
-  node, then per layer the node's wait ``Q_u / mu_u`` (charged on entering
-  a node, not for staying), its compute ``comp[l] / mu_u`` and the transfer
-  of that layer's output to the next node, and last the transfer of
-  ``data[L]`` to the destination.  :func:`optimal_costs` is the layer
-  dynamic program over that objective; :func:`route_cost` prices one given
-  route hop by hop; :func:`commit` adds a routed job's work to the queues;
-  :func:`greedy` is Algorithm 1 itself (each round commits the remaining
-  job of least bound).
+  ``d`` the weight of link (u, v) is ``(d + Q_uv) / mu_uv``.  A job's
+  bound on a route is the transfer of ``data[0]`` from its source to the
+  first layer's node, then per layer the node's wait ``Q_u / mu_u``
+  (charged on entering a node, not for staying), its compute
+  ``comp[l] / mu_u`` and the transfer of that layer's output to the next
+  node, and last the transfer of ``data[L]`` to the destination.
+  :func:`optimal_costs` is the layer dynamic program over that objective.
+  Each of its transfer steps, ``g'[v] = min_u g[u] + dist(u, v)``, is a
+  shortest path from every node at once with ``g`` as the start
+  potentials, so :func:`relax` takes it by Bellman-Ford sweeps over the
+  directed link list until nothing changes: no all-pairs closure is
+  formed, and the work scales with the links, not with V³.
+  :func:`route_cost` prices one given route hop by hop; :func:`commit`
+  adds a routed job's work to the queues; :func:`greedy` is Algorithm 1
+  itself (each round commits the remaining job of least bound), with each
+  transfer's hops from :func:`route_paths`.
 * **Preempt-resume strict-priority service** (:class:`Timelines`).  Jobs
   are served in priority order: a job never waits for one of lower
   priority, so each job's stages are laid into the free time that the
@@ -74,12 +79,38 @@ class Job:
         return int(self.comp.shape[0])
 
 
+@dataclasses.dataclass(frozen=True)
+class Links:
+    """Directed links grouped by the node each enters, for :func:`relax`.
+
+    Link ``i`` leaves node ``src[i]``; ``order[i]`` is its index in
+    :class:`Net`'s link order, in which weights are kept.  The links that
+    enter node ``v`` are ``ptr[v]:ptr[v + 1]``, ordered by ``src``;
+    ``starts`` and ``nodes`` list the groups that are not empty."""
+
+    src: np.ndarray
+    order: np.ndarray
+    ptr: np.ndarray
+    starts: np.ndarray
+    nodes: np.ndarray
+
+    @classmethod
+    def grouped(cls, src, dst, order, V: int) -> "Links":
+        """From links already sorted by ``(dst, src)``."""
+        ptr = np.searchsorted(dst, np.arange(V + 1))
+        nodes = np.flatnonzero(np.diff(ptr))
+        return cls(src, order, ptr, ptr[nodes], nodes)
+
+
 class Net:
-    """Capacities in one precision, with their reciprocals."""
+    """Capacities in one precision, with their reciprocals, and the
+    directed links (``mu_link > 0``, no self-loop) in two groupings:
+    ``fwd`` by the node a link enters, and ``rev``, the links reversed,
+    by the node it leaves."""
 
     def __init__(self, mu_node, mu_link, prec: Prec = F64):
         self.prec = prec
-        self.V = int(np.asarray(mu_node).shape[0])
+        self.V = V = int(np.asarray(mu_node).shape[0])
         mu_node = np.asarray(mu_node, np.float64)
         mu_link = np.asarray(mu_link, np.float64)
         with np.errstate(divide="ignore"):
@@ -94,6 +125,15 @@ class Net:
         self.mu_link = prec.r(mu_link)
         self.inv_link = prec.r(inv_l)
         self.inv_node = prec.r(inv_n)
+        tail, head = np.nonzero((mu_link > 0) & ~np.eye(V, dtype=bool))
+        by_head = np.lexsort((tail, head))
+        self.tail, self.head = tail[by_head], head[by_head]
+        self.inv_e = self.inv_link[self.tail, self.head]
+        self.fwd = Links.grouped(self.tail, self.head,
+                                 np.arange(self.tail.shape[0]), V)
+        by_tail = np.lexsort((self.head, self.tail))
+        self.rev = Links.grouped(self.head[by_tail], self.tail[by_tail],
+                                 by_tail, V)
 
     def node_wait(self, q_node):
         r = self.prec.r
@@ -101,19 +141,59 @@ class Net:
                         r(q_node / np.where(self.mu_node > 0,
                                             self.mu_node, 1)), 0.0)
 
-    def closures(self, d_vals, q_link):
-        """[D, V, V] min-plus closures of the weights of the data sizes
-        ``d_vals`` under link queues ``q_link``."""
+    def link_weights(self, d_vals, q_link):
+        """[D, E] link weights ``(d + Q_uv) * inv_uv`` for the data sizes
+        ``d_vals`` under link queues ``q_link``, in link order."""
         r = self.prec.r
         d = r(np.asarray(d_vals, np.float64))
-        with np.errstate(invalid="ignore"):
-            w = r(r(d[:, None, None] + q_link[None]) * self.inv_link[None])
-        # (d + 0) * 0 on the diagonal and (d + 0) * inf off the graph
-        w = np.where(np.isnan(w), np.inf, w)
-        t = w.copy()
-        for k in range(self.V):
-            t = np.minimum(t, r(t[:, :, k:k + 1] + t[:, k:k + 1, :]))
-        return w, t
+        q = q_link[self.tail, self.head]
+        return r(r(d[:, None] + q[None]) * self.inv_e[None])
+
+    def points(self, at) -> np.ndarray:
+        """[n, V] potentials: 0 at node ``at[j]`` of row ``j``, else inf."""
+        g = np.full((len(at), self.V), np.inf, self.prec.dtype)
+        g[np.arange(len(at)), np.asarray(at, np.int64)] = 0.0
+        return g
+
+    def toward(self, w, at) -> np.ndarray:
+        """[n, V] distance from each node to node ``at[j]`` under row
+        ``j`` of the weights ``w [n, E]``: a relaxation over the reversed
+        links."""
+        return relax(self.points(at), w[:, self.rev.order], self.rev,
+                     self.prec.r)[0]
+
+
+def relax(g, w, links: Links, r, *, origin: bool = False):
+    """``h[j, v] = min_u g[j, u] + dist_j(u, v)``, with ``dist_j`` the
+    shortest path under row ``j`` of the link weights ``w [J, E]`` (one
+    per link of ``links``, in that order) and ``dist_j(v, v) = 0``.
+
+    Vectorised Bellman-Ford: each sweep relaxes every link from the
+    previous sweep's ``h`` (one ``minimum.reduceat`` over the links that
+    enter each node), until a sweep changes nothing.  Every add is rounded
+    by ``r``; an add never lowers a value, so no cycle improves a path and
+    the sweeps end.  With ``origin`` also ``u``, the node each ``h[j, v]``
+    starts from (``v`` itself where nothing improved on ``g``)."""
+    h = np.array(g)
+    J, V = h.shape
+    org = (np.broadcast_to(np.arange(V), (J, V)).copy() if origin
+           else None)
+    size = np.diff(links.ptr)[links.nodes]
+    at = np.arange(links.src.shape[0])
+    rows = np.arange(J)[:, None]
+    while True:
+        cand = r(h[:, links.src] + w)                           # [J, E]
+        best = np.minimum.reduceat(cand, links.starts, axis=1)  # [J, H]
+        better = best < h[:, links.nodes]
+        if not better.any():
+            return h, org
+        if origin:
+            hit = cand == np.repeat(best, size, axis=1)
+            first = np.minimum.reduceat(np.where(hit, at, at.shape[0]),
+                                        links.starts, axis=1)
+            org[:, links.nodes] = np.where(
+                better, org[rows, links.src[first]], org[:, links.nodes])
+        h[:, links.nodes] = np.where(better, best, h[:, links.nodes])
 
 
 def _data_index(jobs: list[Job]):
@@ -128,13 +208,13 @@ def _data_index(jobs: list[Job]):
 def optimal_costs(net: Net, q_node, q_link, jobs: list[Job],
                   *, want_routes: bool = False):
     """Least bound of each job under the given queues (the layer dynamic
-    program, all jobs at once); with ``want_routes`` also each job's node
-    per layer."""
+    program, all jobs at once, each transfer one :func:`relax`); with
+    ``want_routes`` also each job's node per layer."""
     r = net.prec.r
     vals, idx = _data_index(jobs)
-    _, t = net.closures(vals, q_link)
+    w = net.link_weights(vals, q_link)                      # [D, E]
     nw = net.node_wait(q_node)
-    J, V = len(jobs), net.V
+    J = len(jobs)
     nl = np.array([j.num_layers for j in jobs])
     lmax = int(nl.max())
     di = np.zeros((J, lmax + 1), np.int64)
@@ -144,22 +224,23 @@ def optimal_costs(net: Net, q_node, q_link, jobs: list[Job],
         comp[k, :job.num_layers] = job.comp
     comp = r(comp)
     rows = np.arange(J)
-    src = np.array([j.src for j in jobs])
-    dst = np.array([j.dst for j in jobs])
-    g = r(t[di[:, 0], src, :] + nw[None])                   # [J, V]
+    src = [j.src for j in jobs]
+    dst = [j.dst for j in jobs]
+    g, _ = relax(net.points(src), w[di[:, 0]], net.fwd, r)
+    g = r(g + nw[None])                                     # [J, V]
     bps = []
     for l in range(1, lmax + 1):
-        cand = r(g[:, :, None] + t[di[:, l - 1]])           # [J, from, to]
-        move_bp = np.argmin(cand, axis=1)                    # [J, V]
-        moved = r(np.take_along_axis(cand, move_bp[:, None, :], 1)[:, 0]
-                  + nw[None])
+        h, move_bp = relax(g, w[di[:, l - 1]], net.fwd, r,
+                           origin=want_routes)
+        moved = r(h + nw[None])
         stay = g <= moved
         new = r(np.minimum(g, moved)
                 + r(comp[:, l - 1, None] * net.inv_node[None]))
         active = (l <= nl)[:, None]
         g = np.where(active, new, g)
-        bps.append(np.where(active & ~stay, move_bp, -1))
-    total = r(g + t[di[rows, nl], :, dst])                  # [J, V]
+        if want_routes:
+            bps.append(np.where(active & ~stay, move_bp, -1))
+    total = r(g + net.toward(w[di[rows, nl]], dst))         # [J, V]
     best = np.argmin(total, axis=1)
     out = [float(x) for x in total[rows, best]]
     if not want_routes:
@@ -175,19 +256,34 @@ def optimal_costs(net: Net, q_node, q_link, jobs: list[Job],
     return out, routes
 
 
-def shortest_path(w, t, a: int, b: int) -> list[tuple[int, int]]:
-    """Hops from ``a`` to ``b`` along the closure: each next hop minimises
-    edge weight plus remaining distance."""
+def shortest_path(net: Net, w, to_b, a: int,
+                  b: int) -> list[tuple[int, int]]:
+    """Hops from ``a`` to ``b`` under link weights ``w [E]``, with
+    ``to_b [V]`` each node's distance to ``b``: each next hop minimises
+    link weight plus remaining distance (ties to the lower node)."""
+    rv = net.rev
     hops, cur = [], a
-    for _ in range(w.shape[0]):
+    for _ in range(net.V):
         if cur == b:
             break
-        cand = w[cur] + t[:, b]
-        cand[cur] = np.inf
-        nxt = int(np.argmin(cand))
+        out = slice(rv.ptr[cur], rv.ptr[cur + 1])           # cur's links
+        cand = w[rv.order[out]] + to_b[rv.src[out]]
+        nxt = int(rv.src[out][np.argmin(cand)])
         hops.append((cur, nxt))
         cur = nxt
     return hops
+
+
+def route_paths(net: Net, q_link, job: Job, assign) -> list:
+    """The hops of each of ``job``'s ``L+1`` transfers on the route
+    ``assign``: one relaxation toward every transfer's end, then
+    :func:`shortest_path` along it."""
+    vals, idx = _data_index([job])
+    w = net.link_weights(vals, q_link)[idx[0]]              # [L+1, E]
+    nodes = [job.src] + [int(a) for a in assign] + [job.dst]
+    to_b = net.toward(w, nodes[1:])
+    return [shortest_path(net, w[l], to_b[l], nodes[l], nodes[l + 1])
+            for l in range(len(nodes) - 1)]
 
 
 def route_cost(net: Net, q_node, q_link, job: Job, assign, paths) -> float:
@@ -243,12 +339,7 @@ def greedy(net: Net, q_node, q_link, jobs: list[Job]):
                                       want_routes=True)
         k = int(np.argmin(costs))
         i, job, assign = left[k], jobs[left[k]], routes[k]
-        vals, idx = _data_index([job])
-        w, t = net.closures(vals, q_link)
-        nodes = [job.src] + assign + [job.dst]
-        paths = [shortest_path(w[idx[0][l]], t[idx[0][l]], nodes[l],
-                               nodes[l + 1])
-                 for l in range(job.num_layers + 1)]
+        paths = route_paths(net, q_link, job, assign)
         rounds.append((i, costs[k], assign, paths))
         for l in range(job.num_layers):
             q_node[assign[l]] = r(q_node[assign[l]] + job.comp[l])

@@ -43,6 +43,6 @@ def test_traced_run_reads_transfer_bytes():
     out = U.run(CELL, U.small_spec(CELL, per_epoch=2), trace=True)
     assert out["result"]["correct"] is True
     metrics = out["result"]["metrics"]
-    assert metrics["transfer_mb_per_batch"]["value"] > 1.0
+    assert metrics["transfer_mb_per_batch"]["value"] > 0.46
     assert metrics["transfer_mb_per_batch"]["unit"] == "MB/batch"
-    assert metrics["host_syncs_per_batch"]["value"] == 6.0
+    assert metrics["host_syncs_per_batch"]["value"] == 1.0
